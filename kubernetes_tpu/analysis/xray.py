@@ -193,7 +193,7 @@ def _canon_param(v: Any) -> str:
     """Canonical text for one eqn param value (sub-jaxprs recurse;
     callables print by name — a pure_callback's ``callback=<function at
     0x...>`` repr would otherwise bake a memory address in)."""
-    from jax import core as jax_core
+    from jax.extend import core as jax_core
     if isinstance(v, jax_core.ClosedJaxpr):
         return "{" + canonical_jaxpr(v.jaxpr) + "}"
     if isinstance(v, jax_core.Jaxpr):
@@ -224,7 +224,7 @@ def canonical_jaxpr(jaxpr: Any) -> str:
     inner = getattr(jaxpr, "jaxpr", None)
     if inner is not None:
         jaxpr = inner
-    from jax import core as jax_core
+    from jax.extend import core as jax_core
     names: dict = {}
     lines: list[str] = []
 
@@ -500,13 +500,10 @@ def build_context() -> Context:
     # caps are compile-time constants baked into the jaxprs, and a
     # KUBE_MAX_PD_VOLS leak in some earlier test of the same process
     # must not make the committed manifest look drifted.  The fused
-    # scan body and the XLA select kernel are pinned the same way
-    # (KT_FUSED=0 or a TPU backend's Pallas select in the running
+    # scan body is pinned the same way (KT_FUSED=0 in the running
     # process must not move the committed surface).
-    from kubernetes_tpu.engine import fused as fused_mod
     import jax.numpy as jnp
     solver = sv.Solver(eng.policy, fused=True)
-    solver._select = fused_mod.select_xla
     solver._half_dtype = jnp.float16  # canonical, backend-independent
     solver.extra = {"max_ebs": DEFAULT_MAX_EBS_VOLUMES,
                     "max_gce": DEFAULT_MAX_GCE_PD_VOLUMES}

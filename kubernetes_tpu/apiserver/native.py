@@ -1,114 +1,60 @@
-"""Locate/build the native apiserver binary (native/apiserver.cpp).
+"""Build the native apiserver binary (native/apiserver.cpp).
 
 The C++ core implements the same storage/watch/bind contract as the
 Python apiserver (see the header comment in native/apiserver.cpp); the
-perf rig prefers it because the measured wire ceiling of the Python
-server is its GIL.  ``native_binary()`` returns the binary path, building
-it with make on first use, or None when no toolchain is available (the
-caller falls back to ``python -m kubernetes_tpu.apiserver``).
+perf rigs use it because the measured wire ceiling of the Python server
+is its GIL.  The binary is never committed: ``native_binary()`` builds it
+through ``native/Makefile`` (which also generates ``kinds.inc`` from
+``api/types.py``) whenever it is missing or older than its sources, and
+raises ``NativeBuildError`` when that fails — the caller chooses its
+server explicitly (``toolchain_available()``), nothing falls back to the
+Python apiserver on its own.
 """
 
 from __future__ import annotations
 
 import os
+import shutil
 import subprocess
-from typing import Optional
+import sys
+import threading
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 _BINARY = os.path.join(_NATIVE_DIR, "kube-apiserver-native")
 
-
-# Probe results keyed by (path, mtime): one spawn per distinct build.
-_probed: dict[tuple[str, float], bool] = {}
-
-
-def _machine_tag() -> str:
-    """The axis exec-compatibility actually varies on: the loader/libc."""
-    try:
-        return os.confstr("CS_GNU_LIBC_VERSION") or "unknown"
-    except (OSError, ValueError):
-        return "unknown"
+_lock = threading.Lock()
+_built = False
 
 
-def _binary_runs(path: str) -> bool:
-    """True when the binary actually executes on THIS machine.  A binary
-    built elsewhere can be newer than every source and still die at exec
-    (dynamic loader: GLIBC version mismatch) — mtime comparison cannot
-    see that.  Probe: a healthy server keeps running on an ephemeral
-    port; a broken one exits immediately.  Positive results persist in a
-    sidecar marker (keyed by mtime + libc version) so only the first
-    process after a rebuild — or after moving to a different libc — pays
-    the probe spawn."""
-    try:
-        key = (path, os.path.getmtime(path))
-    except OSError:
-        return False
-    cached = _probed.get(key)
-    if cached is not None:
-        return cached
-    marker, stamp = path + ".probe_ok", f"{key[1]} {_machine_tag()}"
-    try:
-        with open(marker) as f:
-            if f.read().strip() == stamp:
-                _probed[key] = True
-                return True
-    except OSError:
-        pass
-    try:
-        proc = subprocess.Popen([path, "--port", "0"],
-                                stdout=subprocess.DEVNULL,
-                                stderr=subprocess.DEVNULL)
-    except OSError:
-        _probed[key] = False
-        return False
-    try:
-        # Loader failures exit within milliseconds; a healthy binary
-        # pays this wait once per process (result cached by mtime).
-        proc.wait(timeout=0.15)
-        ok = False  # exited at once: loader/startup failure
-    except subprocess.TimeoutExpired:
-        ok = True   # it serves; that's the probe
-        proc.terminate()
-        try:
-            proc.wait(timeout=5)
-        except subprocess.TimeoutExpired:  # pragma: no cover
-            proc.kill()
-            proc.wait()
-    _probed[key] = ok
-    if ok:
-        try:
-            with open(marker, "w") as f:
-                f.write(stamp)
-        except OSError:  # read-only checkout: per-process cache only
-            pass
-    return ok
+class NativeBuildError(RuntimeError):
+    """``make -C native`` failed or no C++ toolchain is installed."""
 
 
-def native_binary(build: bool = True) -> Optional[str]:
-    src = os.path.join(_NATIVE_DIR, "apiserver.cpp")
-    # The kind table is generated from types.py (one manifest for both
-    # servers), so a types.py edit must also trigger a rebuild.
-    types_py = os.path.join(_NATIVE_DIR, "..", "kubernetes_tpu", "api",
-                            "types.py")
-    fresh = os.path.exists(_BINARY) and os.path.exists(src) and \
-        os.path.getmtime(_BINARY) >= os.path.getmtime(src) and \
-        (not os.path.exists(types_py) or
-         os.path.getmtime(_BINARY) >= os.path.getmtime(types_py))
-    if fresh and _binary_runs(_BINARY):
+def toolchain_available() -> bool:
+    """True when this machine can build the native apiserver (make and
+    the Makefile's default compiler on PATH)."""
+    return shutil.which("make") is not None and \
+        shutil.which(os.environ.get("CXX", "g++")) is not None
+
+
+def native_binary() -> str:
+    """Path of the native apiserver, (re)built from source by make when
+    missing or out of date.  One make invocation per process."""
+    global _built
+    with _lock:
+        if _built:
+            return _BINARY
+        if not toolchain_available():
+            raise NativeBuildError(
+                "no C++ toolchain (make + g++) to build "
+                "native/kube-apiserver-native")
+        proc = subprocess.run(
+            ["make", "-C", _NATIVE_DIR, f"PYTHON={sys.executable}"],
+            capture_output=True, text=True, timeout=300)
+        if proc.returncode != 0 or not os.path.exists(_BINARY):
+            raise NativeBuildError(
+                f"make -C {_NATIVE_DIR} failed (rc {proc.returncode}):\n"
+                f"{proc.stdout[-2000:]}{proc.stderr[-2000:]}")
+        _built = True
         return _BINARY
-    if not build or not os.path.exists(src):
-        return None
-    # fresh-but-dead: a binary committed from a different libc — make
-    # would call it up to date, so force the rebuild (-B).  Never delete
-    # the tracked binary first: with no local toolchain the committed
-    # artifact (valid on other machines) must survive the attempt.
-    cmd = ["make", "-B", "-C", _NATIVE_DIR] if fresh else \
-        ["make", "-C", _NATIVE_DIR]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except Exception:  # noqa: BLE001 — no toolchain: Python fallback
-        return None
-    if os.path.exists(_BINARY) and _binary_runs(_BINARY):
-        return _BINARY
-    return None

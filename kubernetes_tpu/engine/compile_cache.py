@@ -1,15 +1,19 @@
 """Persistent XLA compilation cache configuration.
 
-Every scheduler start used to pay the full XLA compile tax (19.6 s cold,
-3.8-10.9 s "warm" per BENCH_r05) because jit executables lived only in
-process memory.  This module points JAX's persistent compilation cache at
-a per-machine directory so the cost is paid once per (machine, jaxlib,
-program) and every later start deserializes the executables instead of
-re-running XLA:
+Every scheduler start used to pay the full XLA compile tax because jit
+executables lived only in process memory.  This module turns on JAX's
+persistent compilation cache so the cost is paid once per (machine,
+jaxlib, program) and every later start deserializes the executables
+instead of re-running XLA.
 
-* default location: ``~/.cache/kubernetes_tpu/xla``
-* ``KT_COMPILE_CACHE=<dir>`` overrides the directory
-* ``KT_COMPILE_CACHE=0`` (or ``off``/``none``/``disabled``) disables it
+Where the cache lives is decided from OUTSIDE the program:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself at import and
+  this module sets no directory at all;
+* unset: ``<checkout>/.jax_cache`` — a fixed path next to the package
+  (ignored by git), never derived from ``$HOME``, a temp name, a pid or
+  the time: the path is part of the cache key, so a directory that moves
+  never hits.
 
 The cache thresholds are dropped to zero so *every* executable persists —
 the drain path's small shapes (the stream bucket ladder, the explain-pass
@@ -28,50 +32,36 @@ import threading
 from typing import Optional
 
 DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "kubernetes_tpu", "xla")
-
-_DISABLED_VALUES = ("0", "off", "none", "disabled", "false")
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _lock = threading.Lock()
 _configured = False
 _dir: Optional[str] = None
 
 
-def configure() -> Optional[str]:
-    """Point JAX's persistent compilation cache at the per-machine
-    directory (created on demand).  Returns the directory, or None when
-    disabled via ``KT_COMPILE_CACHE=0`` or when the runtime lacks the
-    cache knobs.  Safe to call from any thread, any number of times; the
-    environment is read ONCE — like the stream bucket floor, a mid-run
-    change must not silently split state between two directories."""
+def configure() -> str:
+    """Turn on JAX's persistent compilation cache and return the
+    directory it uses.  Safe to call from any thread, any number of
+    times; the environment is read ONCE — like the stream bucket floor,
+    a mid-run change must not silently split state between two
+    directories."""
     global _configured, _dir
     with _lock:
         if _configured:
             return _dir
-        _configured = True
-        from kubernetes_tpu.utils import knobs
-        raw = knobs.get("KT_COMPILE_CACHE")
-        if raw.lower() in _DISABLED_VALUES:
-            return None
-        path = raw or DEFAULT_CACHE_DIR
-        try:
-            os.makedirs(path, exist_ok=True)
-            import jax
-            jax.config.update("jax_compilation_cache_dir", path)
-        except Exception:  # noqa: BLE001 — cache is an optimization only
-            return None
+        import jax
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir",
+                              DEFAULT_CACHE_DIR)
         # Persist everything: the bucket-ladder scans and explain-pass
         # shapes each compile below the default 1 s floor but together
         # are the warm-start stall this cache exists to kill.
-        for knob, value in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", 0)):
-            try:
-                jax.config.update(knob, value)
-            except Exception:  # noqa: BLE001 — older jaxlib: best effort
-                pass
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         _register_hit_miss_listener()
-        _dir = path
+        _dir = jax.config.jax_compilation_cache_dir
+        _configured = True
         return _dir
 
 
@@ -83,30 +73,27 @@ def _register_hit_miss_listener() -> None:
     events: a hit is a jit executable deserialized from the persistent
     cache, a miss one that re-paid the full XLA compile.  Without them
     the multi-second \"warm\" start is undiagnosable — the counters say
-    exactly which restarts still compile (ROADMAP item 3)."""
+    exactly which restarts still compile."""
     global _listener_registered
     if _listener_registered:
         return
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        from kubernetes_tpu.utils.metrics import (COMPILE_CACHE_HITS,
-                                                  COMPILE_CACHE_MISSES)
+    from kubernetes_tpu.utils.metrics import (COMPILE_CACHE_HITS,
+                                              COMPILE_CACHE_MISSES)
 
-        def _on_event(event: str, **kw) -> None:
-            if event == "/jax/compilation_cache/cache_hits":
-                COMPILE_CACHE_HITS.inc()
-            elif event == "/jax/compilation_cache/cache_misses":
-                COMPILE_CACHE_MISSES.inc()
+    def _on_event(event: str, **kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            COMPILE_CACHE_HITS.inc()
+        elif event == "/jax/compilation_cache/cache_misses":
+            COMPILE_CACHE_MISSES.inc()
 
-        monitoring.register_event_listener(_on_event)
-        _listener_registered = True
-    except Exception:  # noqa: BLE001 — observability only, never fatal
-        pass
+    monitoring.register_event_listener(_on_event)
+    _listener_registered = True
 
 
 def cache_dir() -> Optional[str]:
-    """The active cache directory (None = disabled or not configured)."""
+    """The active cache directory (None = not configured yet)."""
     with _lock:
         return _dir
 

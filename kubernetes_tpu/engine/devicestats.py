@@ -97,6 +97,19 @@ def transfer_snapshot() -> dict[str, int]:
     return out
 
 
+# -- the device itself -------------------------------------------------------
+
+def device_info() -> dict:
+    """The device this process computes on, as JAX reports it.  Unlike
+    the accounting below this raises when no backend initializes: a
+    process asked to name its device must not invent one."""
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
 # -- HBM accounting ----------------------------------------------------------
 
 def _backend_memory_stats() -> dict | None:

@@ -1,21 +1,11 @@
-"""Fused selectHost kernels for the solve scan's inner step.
+"""Fused selectHost for the solve scan's inner step.
 
 The per-step mask -> score -> tie-break -> select chain is the floor of
 the sequential solve's cost once the score planes are template-factored
 (engine/solver.py ``_solve_scan``): four reduction passes over the node
-axis per pod.  This module provides that chain as ONE fused unit with
-two interchangeable implementations sharing exact semantics:
-
-* ``select_xla`` — jnp ops arranged for XLA's fuser (three reductions:
-  max, one cumsum that also yields the tie count, argmax).  This is the
-  CPU/GPU path and the fallback everywhere.
-* ``select_pallas`` — a Pallas kernel computing the whole chain over a
-  VMEM-resident row (PAPER.md's "native layer"); used on TPU, and in
-  interpret mode by the CPU parity tests so tier-1 exercises the same
-  code path.
-
-Selection happens once at import/engine init through :func:`impl`
-(KT_PALLAS knob: auto / interpret / off) — never per drain (ktlint D04).
+axis per pod.  ``select_xla`` provides that chain as ONE unit of jnp ops
+arranged for XLA's fuser (three reductions: max, one cumsum that also
+yields the tie count, argmax) — the one select on every backend.
 
 Semantics (generic_scheduler.go:124-141 selectHost): among the feasible
 max-score nodes, pick the ``counter % n_ties``-th in node-index order;
@@ -27,15 +17,7 @@ whole per-pod decision input.
 
 from __future__ import annotations
 
-from typing import Callable, Tuple
-
-import jax
 import jax.numpy as jnp
-
-from kubernetes_tpu.utils import knobs
-
-SelectFn = Callable[[jnp.ndarray, jnp.ndarray],
-                    Tuple[jnp.ndarray, jnp.ndarray]]
 
 
 def select_xla(masked: jnp.ndarray, counter: jnp.ndarray
@@ -44,7 +26,10 @@ def select_xla(masked: jnp.ndarray, counter: jnp.ndarray
 
     ``masked`` [N] f32 with -inf at infeasible nodes; ``counter`` uint32
     round-robin state.  Three node-axis passes: max, cumsum (whose last
-    element is the tie count — no separate sum pass), argmax."""
+    element is the tie count — no separate sum pass), argmax.  The
+    round-robin modulo runs in uint32: an int32 cast would go negative
+    past 2^31 cumulative placements and the negative remainder would
+    mark every pod unschedulable."""
     mx = jnp.max(masked)
     ties = (masked == mx) & jnp.isfinite(mx)
     rank = jnp.cumsum(ties.astype(jnp.int32))  # 1-based among ties
@@ -54,52 +39,3 @@ def select_xla(masked: jnp.ndarray, counter: jnp.ndarray
         .astype(jnp.int32)
     choice = jnp.argmax(ties & (rank == ix + 1)).astype(jnp.int32)
     return jnp.where(any_feasible, choice, -1), any_feasible
-
-
-def _pallas_kernel(counter_ref, masked_ref, out_ref) -> None:
-    """The same chain over a [1, N] VMEM row; scalar I/O in SMEM.  The
-    round-robin modulo runs in uint32 like select_xla/the legacy body:
-    an int32 cast would go negative past 2^31 cumulative placements and
-    the negative remainder would mark every pod unschedulable."""
-    m = masked_ref[...]                          # [1, N]
-    mx = jnp.max(m)
-    ties = (m == mx) & jnp.isfinite(mx)
-    rank = jnp.cumsum(ties.astype(jnp.int32), axis=1)
-    n_raw = rank[0, -1]
-    ix = (counter_ref[0] %
-          jnp.maximum(n_raw, 1).astype(jnp.uint32)).astype(jnp.int32)
-    pick = ties & (rank == ix + 1)
-    col = jax.lax.broadcasted_iota(jnp.int32, m.shape, 1)
-    choice = jnp.max(jnp.where(pick, col, -1))
-    out_ref[0] = jnp.where(n_raw > 0, choice, -1)
-    out_ref[1] = n_raw
-
-
-def select_pallas(masked: jnp.ndarray, counter: jnp.ndarray,
-                  interpret: bool = False
-                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Pallas form of :func:`select_xla` — one kernel launch per step,
-    the whole row resident in VMEM.  ``interpret=True`` runs the same
-    kernel body on CPU (the parity-test path)."""
-    from jax.experimental import pallas as pl
-    n = masked.shape[-1]
-    out = pl.pallas_call(
-        _pallas_kernel,
-        out_shape=jax.ShapeDtypeStruct((2,), jnp.int32),
-        interpret=interpret,
-    )(counter.astype(jnp.uint32)[None], masked.reshape(1, n))
-    return out[0], out[1] > 0
-
-
-def impl() -> SelectFn:
-    """The select implementation for THIS process's backend, resolved
-    once (KT_PALLAS: '' = auto, 'interpret' = Pallas interpret mode,
-    '0' = force the XLA path)."""
-    mode = knobs.get_str("KT_PALLAS")
-    if mode == "0":
-        return select_xla
-    if mode == "interpret":
-        return lambda m, c: select_pallas(m, c, interpret=True)
-    if mode == "" and jax.default_backend() != "tpu":
-        return select_xla
-    return lambda m, c: select_pallas(m, c)

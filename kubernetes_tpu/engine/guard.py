@@ -1,12 +1,12 @@
-"""Guarded device execution: fault taxonomy, recovery policy, and the
-post-solve sanity gate.
+"""Guarded device execution: fault classification, recovery policy, and
+the post-solve sanity gate.
 
 Every solve site (one-shot, stream chunk, joint, single-pod, preemption
 victim kernel) runs inside this layer so an accelerator fault is a
 POLICY DECISION instead of a stalled drain loop:
 
 * **Classification.**  ``classify()`` buckets a device exception into
-  the four-fault taxonomy — ``oom`` (HBM ``RESOURCE_EXHAUSTED``),
+  four fault kinds — ``oom`` (HBM ``RESOURCE_EXHAUSTED``),
   ``compile`` (XLA compilation failure), ``lost`` (device in an error
   state / runtime gone), or None (not a device fault: re-raised
   untouched so real bugs keep crashing loudly).  Classified faults
@@ -98,7 +98,7 @@ def _is_device_error(exc: BaseException) -> bool:
 
 
 def classify(exc: BaseException) -> str | None:
-    """The fault taxonomy: oom / compile / lost, or None when the
+    """The fault kind: oom / compile / lost, or None when the
     exception is not a device fault."""
     if isinstance(exc, DeviceFault):
         return exc.kind
@@ -486,9 +486,15 @@ class DeviceGuard:
             return self._host_mode_s + extra
 
     def report(self) -> dict:
-        """The /debug/vars + soak-artifact payload."""
+        """The /debug/vars + soak-artifact payload: the fault-policy
+        state plus the device (platform, kind, count) it guards."""
+        from kubernetes_tpu.engine import devicestats
+        device = devicestats.device_info()
         with self._lock:
             return {
+                "platform": device["platform"],
+                "deviceKind": device["kind"],
+                "deviceCount": device["count"],
                 "enabled": self.enabled,
                 "mode": self._mode,
                 "bucketCap": self._bucket_cap,

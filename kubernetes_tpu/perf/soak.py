@@ -616,6 +616,19 @@ def run_ha_wave(n_nodes: int = 800, n_shards: int = 8,
     import socket
     import subprocess
 
+    import jax
+    if processes and jax.default_backend() == "tpu":
+        # One process holds a chip.  This driver has initialised JAX, so
+        # the scheduler processes it would start could never acquire the
+        # TPU — and N of them cannot share one chip in any case.  The
+        # layout that can hold the wave is one process driving one
+        # incarnation per device (ROADMAP Reach 9).
+        raise RuntimeError(
+            f"HA wave: {n_incarnations} scheduler processes cannot share "
+            f"the TPU this process holds (one process per chip); run "
+            f"the wave on the CPU backend (JAX_PLATFORMS=cpu) or "
+            f"in-process (processes=False)")
+
     t_start = time.monotonic()
     store = MemStore()
     from kubernetes_tpu.apiserver.server import serve

@@ -173,9 +173,10 @@ def make_services(n: int, namespace: str = "default") -> list[api.Service]:
 
 
 def make_rig(n_nodes: int, n_pods: int, profile: str = "mixed",
-             n_zones: int = 4, n_services: int = 4):
+             n_zones: int = 4, n_services: int = 4, policy=None):
     """Assembled scheduler + pending pods — the mustSetupScheduler analogue
-    (util.go:46-74).  Returns (scheduler, pods)."""
+    (util.go:46-74).  Returns (scheduler, pods).  ``policy`` None = the
+    default provider."""
     from kubernetes_tpu.cache.scheduler_cache import SchedulerCache
     from kubernetes_tpu.engine.generic_scheduler import GenericScheduler, Listers
 
@@ -183,5 +184,6 @@ def make_rig(n_nodes: int, n_pods: int, profile: str = "mixed",
     for nd in make_nodes(n_nodes, profile=profile, n_zones=n_zones):
         cache.add_node(nd)
     sched = GenericScheduler(
-        cache=cache, listers=Listers(services=make_services(n_services)))
+        policy=policy, cache=cache,
+        listers=Listers(services=make_services(n_services)))
     return sched, make_pods(n_pods, profile=profile, n_services=n_services)

@@ -357,6 +357,14 @@ def main(argv=None) -> int:
     except ValueError as err:
         raise SystemExit(f"--feature-gates: {err}")
     featuregate.set_default(gates)
+    # Initialize the backend NOW and name it: with JAX_PLATFORMS pinned,
+    # a missing or busy chip fails start-up here instead of surfacing as
+    # a classified `lost` fault (and a silent host-engine run) on the
+    # first drain.
+    from kubernetes_tpu.engine import devicestats
+    device = devicestats.device_info()
+    log.info("engine device: platform=%s kind=%s count=%d",
+             device["platform"], device["kind"], device["count"])
     policy = load_policy(opts)
     configz = {
         "apiServer": opts.api_server or "(in-process)",

@@ -480,9 +480,11 @@ def make_handler(core: ExtenderCore):
         def do_GET(self):
             path, _, query = self.path.partition("?")
             if path == "/configz":
+                from kubernetes_tpu.engine import devicestats
                 cfg = {"predicates": [p.name for p in core.policy.predicates],
                        "priorities": [(s.name, s.weight)
-                                      for s in core.policy.priorities]}
+                                      for s in core.policy.priorities],
+                       "device": devicestats.device_info()}
                 self._send(200, json.dumps(cfg).encode())
                 return
             # healthz / metrics / debug tree: the shared daemon routes.
@@ -585,8 +587,15 @@ def main() -> None:
         with open(opts.policy_config_file) as f:
             policy = policy_from_json(f.read())
         validate_policy(policy)
+    # Initialize the backend before the socket opens: with JAX_PLATFORMS
+    # pinned, a missing or busy chip fails start-up, not the first verb
+    # (whose wire contract would fold it into an `error` field).
+    from kubernetes_tpu.engine import devicestats
+    device = devicestats.device_info()
     server = serve(opts.port, policy, opts.host)
-    print(f"tpu-scheduler extender listening on {opts.host}:{opts.port}")
+    print(f"tpu-scheduler extender listening on {opts.host}:{opts.port} "
+          f"(device: {device['platform']} {device['kind']} "
+          f"x{device['count']})", flush=True)
     server.serve_forever()
 
 

@@ -619,7 +619,7 @@ POST_PREWARM_COMPILES = register(Counter(
     "(the bench ratchet fails on any in the density run)",
     labelnames=("path",)))
 # Device fault-tolerance plane (engine/guard.py): the guarded-execution
-# layer's taxonomy, recovery ladder, and sanity gate.  A control plane
+# layer's fault kinds, recovery ladder, and sanity gate.  A control plane
 # that trusts a TPU with its decisions must keep scheduling when the TPU
 # misbehaves — these count every step of that story.
 DEVICE_FAULTS = register(Counter(

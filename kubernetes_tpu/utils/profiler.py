@@ -20,10 +20,11 @@ shape: always-on, sampling, low single-digit-percent overhead):
   are parked in ``wait()`` (a stack sampled in an idle thread carries
   zero weight);
 * the module-prefix -> component classifier folds stacks into the fixed
-  taxonomy ``watch_decode`` / ``handler_dispatch`` / ``feature_build`` /
-  ``serialize`` / ``apiserver`` / ``solve_host`` / ``commit_bind`` /
-  ``other`` — the same component names the bench ``profile`` section and
-  the ``check_bench.check_profile`` ratchet speak;
+  component set ``watch_decode`` / ``handler_dispatch`` /
+  ``feature_build`` / ``serialize`` / ``apiserver`` / ``solve_host`` /
+  ``commit_bind`` / ``other`` — the same component names the bench
+  ``profile`` section and the ``check_bench.check_profile`` ratchet
+  speak;
 * results export three ways: ``process_cpu_fraction{component=}`` /
   ``process_thread_cpu_seconds_total{thread=}`` into the default metrics
   registry (and through it the telemetry ring + dashboard), a bounded

@@ -3,10 +3,44 @@ table-driven tests construct in memory (predicates_test.go, priorities_test.go).
 
 from __future__ import annotations
 
+import contextlib
 import json
-from typing import Optional
+import os
+from typing import Iterator, Optional
 
 from kubernetes_tpu.api import types as api
+
+
+@contextlib.contextmanager
+def compile_cache_at(path: str) -> Iterator[None]:
+    """Run a block with the persistent compile cache at ``path``, placed
+    the way a deployment places it — ``JAX_COMPILATION_CACHE_DIR``.  JAX
+    reads that variable only at import, so mid-process the helper also
+    applies it to ``jax.config`` and drops JAX's already-opened cache
+    object; ``engine.compile_cache`` itself must set no directory.  The
+    previous placement is restored on exit."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+
+    from kubernetes_tpu.engine import compile_cache
+    prev_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    prev_dir = jax.config.jax_compilation_cache_dir
+
+    def place(env: Optional[str], directory: Optional[str]) -> None:
+        if env is None:
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+        else:
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = env
+        jax.config.update("jax_compilation_cache_dir", directory)
+        jcc.reset_cache()
+        compile_cache._reset_for_tests()
+
+    place(path, path)
+    try:
+        yield
+    finally:
+        place(prev_env, prev_dir)
+        compile_cache.configure()
 
 
 def make_node(name: str, milli_cpu: int = 4000, memory: int = 16 * 1024**3,

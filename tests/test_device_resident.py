@@ -453,28 +453,51 @@ class TestDeferredReadbackFaults:
 
 
 class TestCompileCache:
-    def test_configure_is_idempotent_and_env_gated(self, monkeypatch,
-                                                   tmp_path):
-        from kubernetes_tpu.engine import compile_cache as cc
-        monkeypatch.setenv("KT_COMPILE_CACHE", str(tmp_path / "xla"))
-        cc._reset_for_tests()
-        try:
-            d = cc.configure()
-            assert d == str(tmp_path / "xla")
-            import os
-            assert os.path.isdir(d)
-            # Idempotent: a later env change does not re-point the cache.
-            monkeypatch.setenv("KT_COMPILE_CACHE", "/elsewhere")
-            assert cc.configure() == d
-            assert cc.cache_dir() == d
-            # Disabled forms.
-            for off in ("0", "off", "none"):
-                cc._reset_for_tests()
-                monkeypatch.setenv("KT_COMPILE_CACHE", off)
-                assert cc.configure() is None
-        finally:
-            # Leave the process configured with the real default so later
-            # tests in the suite see a consistent state.
-            cc._reset_for_tests()
-            monkeypatch.delenv("KT_COMPILE_CACHE", raising=False)
-            cc.configure()
+    """Where the persistent compile cache lives is decided from outside
+    (engine/compile_cache.py).  JAX reads JAX_COMPILATION_CACHE_DIR at
+    import, so each case is a fresh interpreter."""
+
+    _PROBE = (
+        "import jax\n"
+        "updates = []\n"
+        "real = jax.config.update\n"
+        "def spy(name, value):\n"
+        "    updates.append(name)\n"
+        "    real(name, value)\n"
+        "jax.config.update = spy\n"
+        "from kubernetes_tpu.engine import compile_cache as cc\n"
+        "d = cc.configure()\n"
+        "assert cc.configure() == d == cc.cache_dir()\n"
+        "assert d == jax.config.jax_compilation_cache_dir\n"
+        "assert jax.config.jax_persistent_cache_min_compile_time_secs == 0\n"
+        "import json\n"
+        "print(json.dumps({'dir': d, 'set_dir': "
+        "'jax_compilation_cache_dir' in updates}))\n")
+
+    def _probe(self, cwd, cache_env=None) -> dict:
+        import json
+        import os
+        import subprocess
+        import sys
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, PYTHONPATH=repo)
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if cache_env is not None:
+            env["JAX_COMPILATION_CACHE_DIR"] = cache_env
+        out = subprocess.run([sys.executable, "-c", self._PROBE],
+                             cwd=str(cwd), env=env, capture_output=True,
+                             text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    def test_env_places_the_cache_and_code_sets_no_directory(self,
+                                                             tmp_path):
+        got = self._probe(tmp_path, cache_env=str(tmp_path / "xla"))
+        assert got == {"dir": str(tmp_path / "xla"), "set_dir": False}
+
+    def test_unset_env_means_the_checkout_from_any_cwd(self, tmp_path):
+        import os
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = {"dir": os.path.join(repo, ".jax_cache"), "set_dir": True}
+        assert self._probe(tmp_path) == want
+        assert self._probe(repo) == want

@@ -53,10 +53,11 @@ def base(request):
         cmd = [sys.executable, "-m", "kubernetes_tpu.apiserver",
                "--port", str(port)]
     else:
-        from kubernetes_tpu.apiserver.native import native_binary
+        from kubernetes_tpu.apiserver.native import (native_binary,
+                                                     toolchain_available)
+        if not toolchain_available():
+            pytest.skip("no C++ toolchain")
         binary = native_binary()
-        if binary is None:
-            pytest.skip("no C++ toolchain / native build failed")
         cmd = [binary, "--port", str(port)]
     proc = subprocess.Popen(cmd, stdout=subprocess.DEVNULL,
                             stderr=subprocess.DEVNULL)

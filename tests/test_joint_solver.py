@@ -9,7 +9,7 @@ import numpy as np
 from kubernetes_tpu.engine.generic_scheduler import GenericScheduler
 from kubernetes_tpu.perf import synth
 
-from helpers import make_node, make_pod
+from helpers import compile_cache_at, make_node, make_pod
 
 
 def _placed_load(sched, pods, placements):
@@ -81,8 +81,7 @@ def test_joint_on_synthetic_rig():
     assert sum(1 for g in got if g is not None) >= 195  # ample capacity
 
 
-def test_joint_warm_start_reuses_persistent_compile_cache(tmp_path,
-                                                          monkeypatch):
+def test_joint_warm_start_reuses_persistent_compile_cache(tmp_path):
     """The ~77 s joint wall-clock was compile tax: the pipeline's
     host-side glue (argsort + ~75 per-field jnp.take permutes) lived
     OUTSIDE any jit, so nothing the persistent compilation cache stored
@@ -100,9 +99,7 @@ def test_joint_warm_start_reuses_persistent_compile_cache(tmp_path,
     from kubernetes_tpu.utils.metrics import (COMPILE_CACHE_HITS,
                                               COMPILE_CACHE_MISSES)
 
-    monkeypatch.setenv("KT_COMPILE_CACHE", str(tmp_path))
-    compile_cache._reset_for_tests()
-    try:
+    with compile_cache_at(str(tmp_path)):
         assert compile_cache.configure() == str(tmp_path)
 
         def build():
@@ -129,16 +126,9 @@ def test_joint_warm_start_reuses_persistent_compile_cache(tmp_path,
             "warm joint solve recompiled instead of hitting the " \
             "persistent cache"
         assert warm_s < cold_s, (warm_s, cold_s)
-    finally:
-        # Re-latch onto the environment's default cache directory so
-        # later tests don't persist into the deleted tmp dir.
-        compile_cache._reset_for_tests()
-        monkeypatch.delenv("KT_COMPILE_CACHE", raising=False)
-        compile_cache.configure()
 
 
-def test_prewarm_covers_the_single_pod_path_and_scatter(tmp_path,
-                                                        monkeypatch):
+def test_prewarm_covers_the_single_pod_path_and_scatter(tmp_path):
     """ISSUE 8 warm-start audit: after ``prewarm()`` NO post-warm-up
     decision path may mint a fresh XLA compile on the clock.  Measured
     before the fix, the single-pod path (evaluate/masks/select_hosts at
@@ -158,9 +148,7 @@ def test_prewarm_covers_the_single_pod_path_and_scatter(tmp_path,
     from kubernetes_tpu.utils.metrics import (COMPILE_CACHE_HITS,
                                               COMPILE_CACHE_MISSES)
 
-    monkeypatch.setenv("KT_COMPILE_CACHE", str(tmp_path))
-    compile_cache._reset_for_tests()
-    try:
+    with compile_cache_at(str(tmp_path)):
         assert compile_cache.configure() == str(tmp_path)
 
         def build() -> Scheduler:
@@ -209,7 +197,3 @@ def test_prewarm_covers_the_single_pod_path_and_scatter(tmp_path,
         assert COMPILE_CACHE_MISSES.value == misses0, \
             "warm prewarm recompiled instead of hitting the persistent " \
             "cache"
-    finally:
-        compile_cache._reset_for_tests()
-        monkeypatch.delenv("KT_COMPILE_CACHE", raising=False)
-        compile_cache.configure()

@@ -17,15 +17,15 @@ import urllib.request
 
 import pytest
 
-from kubernetes_tpu.apiserver.native import native_binary
+from kubernetes_tpu.apiserver.native import (native_binary,
+                                             toolchain_available)
 
 
 @pytest.fixture(scope="module")
 def binary():
-    b = native_binary()
-    if b is None:
-        pytest.skip("no C++ toolchain / native build failed")
-    return b
+    if not toolchain_available():
+        pytest.skip("no C++ toolchain")
+    return native_binary()
 
 
 @pytest.fixture()

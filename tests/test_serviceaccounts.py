@@ -255,7 +255,8 @@ class TestSecretsConfigMapsKinds:
         import socket
         import subprocess
 
-        from kubernetes_tpu.apiserver.native import native_binary
+        from kubernetes_tpu.apiserver.native import (native_binary,
+                                                     toolchain_available)
 
         def drive(base):
             def req(method, path, body=None):
@@ -298,9 +299,9 @@ class TestSecretsConfigMapsKinds:
         finally:
             srv.shutdown()
 
-        binary = native_binary()
-        if binary is None:
+        if not toolchain_available():
             pytest.skip("no C++ toolchain")
+        binary = native_binary()
         with socket.socket() as s:
             s.bind(("127.0.0.1", 0))
             port = s.getsockname()[1]
