@@ -1,0 +1,111 @@
+"""BENCHMARK.json against the limits its contract refuses a file over,
+and against the files it names."""
+
+import json
+import os
+import re
+
+from conftest import BENCH, REPO
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+PATH = re.compile(r"^[A-Za-z0-9_./-]{1,200}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+def _bench():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def _line(text, limit=200):
+    return 1 <= len(text) <= limit and "\n" not in text and "\t" not in text
+
+
+def test_top_level_shape():
+    b = _bench()
+    assert set(b) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 * 1024
+    assert b["paths"] == ["benchmarks"] and b["command"][1].startswith(
+        "benchmarks/")
+    assert isinstance(b["run_seconds"], int) and 1 <= b["run_seconds"] <= 51
+    assert all(_line(w) for w in b["command"]) and len(b["command"]) <= 32
+
+
+def test_configs_and_cells():
+    b = _bench()
+    configs = {c["name"]: c for c in b["configs"]}
+    assert 1 <= len(configs) == len(b["configs"]) <= 24
+    for c in b["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and _line(c["source"]) and _line(c["why"])
+        assert PATH.match(c["file"]) and c["file"].startswith("benchmarks/")
+        with open(os.path.join(REPO, c["file"])) as f:
+            body = json.load(f)
+        assert body["name"] == c["name"] and body["source"] == c["source"]
+        assert body["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
+        for key in ("guarantees", "daemon", "nodes", "pods", "resident_cap",
+                    "judge", "limits", "assumed"):
+            assert key in body, (c["name"], key)
+    cells = b["workloads"]
+    assert 1 <= len(cells) <= 24
+    assert len({w["name"] for w in cells}) == len(cells)
+    assert len({(w["config"], w["traffic"]) for w in cells}) == len(cells)
+    assert sum(w["chips"] == 4 for w in cells) <= max(len(cells) // 2, 1)
+    for w in cells:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert w["config"] in configs and w["chips"] in (1, 4)
+        assert _line(w["why"])
+        with open(os.path.join(BENCH, "traffic", w["traffic"] + ".json")) as f:
+            kind = json.load(f)["kind"]
+        assert os.path.exists(os.path.join(BENCH, "generators", kind + ".py"))
+    assert {w["config"] for w in cells} == set(configs)
+
+
+def test_metrics():
+    b = _bench()
+    cells = {w["name"] for w in b["workloads"]}
+    e2e = {m["name"]: m for m in b["end_to_end"]}
+    assert "setup_s" in e2e and 1 <= len(e2e) <= 16
+    for m in b["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+        assert set(m.get("workloads", cells)) <= cells
+    assert 1 <= len(b["per_layer"]) <= 128
+    names = [m["name"] for m in b["end_to_end"] + b["per_layer"]]
+    assert len(set(names)) == len(names)
+    layers = set()
+    for m in b["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
+        assert _line(m["layer"]) and m["moves"] in e2e
+        layers.add(m["layer"])
+        reporting = set(e2e[m["moves"]].get("workloads", cells))
+        assert set(m.get("workloads", reporting)) <= reporting
+    # every cell: setup_s, one more end-to-end metric, one per-layer metric
+    for cell in cells:
+        mine = [m for m in b["end_to_end"]
+                if cell in m.get("workloads", cells)]
+        assert len(mine) >= 2
+        assert any(cell in m.get("workloads", cells) for m in b["per_layer"])
+    # PERF.md's list of layers names every layer, letter for letter
+    with open(os.path.join(REPO, "PERF.md")) as f:
+        perf = f.read()
+    for layer in layers:
+        assert layer in perf, layer
+
+
+def test_files_under_paths_are_named_from_name_characters():
+    for root, dirs, names in os.walk(BENCH):
+        dirs[:] = [d for d in dirs if d not in ("out", "__pycache__")]
+        for name in names:
+            rel = os.path.relpath(os.path.join(root, name), REPO)
+            assert re.match(r"^[A-Za-z0-9_./-]+$", rel), rel
