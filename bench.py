@@ -17,9 +17,6 @@ vs_baseline is against the reference's cluster-saturation SLO floor of
 absolute throughput number the reference publishes.
 
 Env knobs (for CPU smoke runs): BENCH_NODES, BENCH_PODS, BENCH_PROFILE.
-``--profile-dir DIR`` (or KT_PROFILE_DIR) wraps every device solve in the
-density and serving phases in a ``jax.profiler`` trace (viewable in
-TensorBoard/XProf); unset, the hook is a zero-overhead no-op.
 """
 
 import argparse
@@ -88,17 +85,8 @@ def _xray_summary():
         return None
 
 
-def build_parser() -> argparse.ArgumentParser:
-    p = argparse.ArgumentParser(description=__doc__)
-    p.add_argument("--profile-dir", default="",
-                   help="write jax.profiler device traces of every solve "
-                        "in the density and serving phases here (also "
-                        "KT_PROFILE_DIR; view with TensorBoard/XProf)")
-    return p
-
-
 def main(argv=None) -> int:
-    opts = build_parser().parse_args(argv)
+    argparse.ArgumentParser(description=__doc__).parse_args(argv)
     # Every phase that was run and failed: the JSON line is still
     # printed (it carries the phases that worked), then the exit code
     # says the run as a whole did not.
@@ -108,13 +96,6 @@ def main(argv=None) -> int:
         failures.append(f"{phase}: {err}")
         print(f"{phase} phase FAILED: {err}", file=sys.stderr)
 
-    if opts.profile_dir or os.environ.get("KT_PROFILE_DIR"):
-        # Wire utils/profiling.device_trace into every solve the bench
-        # phases run (the engine wraps its solve dispatches in it; the
-        # flag just arms the directory).
-        from kubernetes_tpu.utils.profiling import set_profile_dir
-        set_profile_dir(opts.profile_dir
-                        or os.environ.get("KT_PROFILE_DIR", ""))
     n_nodes = int(os.environ.get("BENCH_NODES", "5000"))
     n_pods = int(os.environ.get("BENCH_PODS", "30000"))
     profile = os.environ.get("BENCH_PROFILE", "mixed")

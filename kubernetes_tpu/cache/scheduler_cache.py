@@ -23,10 +23,53 @@ from typing import TYPE_CHECKING, Callable, Optional
 
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.features import compiler as fc
-from kubernetes_tpu.utils import locktrace
+from kubernetes_tpu.utils import locktrace, metrics, threadreg, trace
 
 if TYPE_CHECKING:  # jax-free at runtime: cache stays device-importless
     from kubernetes_tpu.engine.workloads.preemption import VictimTable
+
+class _CacheLock:
+    """The cache's reentrant lock with its contention counted where it is
+    taken.  The launch thread holds this lock through snapshot + feature
+    compile + transfer by design, so a handler's "cost" may be waiting:
+    a try-acquire goes first, and only a thread that has to block reads
+    the clock around the wait and adds it to
+    ``scheduler_cache_lock_wait_seconds_total{role}`` /
+    ``scheduler_cache_lock_contended_total{role}`` (``role`` = its
+    thread's name, instance suffixes collapsed).  The wait is also the
+    host event ``kt.cache_lock_wait`` of a live profiler session.
+    Uncontended: one branch, no clock."""
+
+    __slots__ = ("_inner",)
+
+    def __init__(self, inner: "threading.RLock | locktrace.TracedRLock"):
+        self._inner = inner
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if self._inner.acquire(False):
+            return True
+        if not blocking:
+            return False
+        t0 = _clock()
+        with trace.annotation("cache_lock_wait"):
+            got = self._inner.acquire(True, timeout)
+        role = threadreg.role(threading.current_thread().name)
+        metrics.CACHE_LOCK_WAIT_SECONDS.labels(role=role).inc(
+            _clock() - t0)
+        metrics.CACHE_LOCK_CONTENDED.labels(role=role).inc()
+        return got
+
+    def release(self) -> None:
+        self._inner.release()
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc) -> None:
+        self._inner.release()
+
+
+_clock = time.perf_counter  # read on contention only (pinned by a test)
+
 
 def _locked(fn):
     """Serialize public cache methods on self.lock (cache.go mutex)."""
@@ -66,8 +109,8 @@ class SchedulerCache:
         # snapshot/compile BY DESIGN (the snapshot must be consistent
         # against concurrent assumes), so its hold time is the compile
         # stage span, not a long-hold bug; order tracking stays on.
-        self.lock = locktrace.make_rlock("cache.SchedulerCache",
-                                         hold_ms=0)
+        self.lock = _CacheLock(locktrace.make_rlock(
+            "cache.SchedulerCache", hold_ms=0))
         self._nodes: dict[str, api.Node] = {}
         self._node_order: list[str] = []
         self._pod_states: dict[str, _PodState] = {}

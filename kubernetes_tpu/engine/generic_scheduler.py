@@ -40,7 +40,7 @@ from kubernetes_tpu.features import compiler as fc
 from kubernetes_tpu.features import padcap
 from kubernetes_tpu.features.volumes import compile_volsvc
 from kubernetes_tpu.utils.logging import get_logger
-from kubernetes_tpu.utils.trace import Trace, stage
+from kubernetes_tpu.utils.trace import Trace, record_stage, stage
 
 log = get_logger("engine")
 
@@ -207,7 +207,12 @@ class GenericScheduler:
         # and existing-pod arrays IN PLACE, so every read — snapshot,
         # volume/affinity pod lists, feature compilation, and the device
         # transfer itself — must see one consistent generation.
+        t_lock = time.perf_counter()
         with self.cache.lock:
+            # What this thread waited behind the handlers for the lock
+            # (the wait itself is counted where it is taken, by role:
+            # cache/scheduler_cache.py _CacheLock).
+            record_stage("lock_wait", start=t_lock)
             with stage("snapshot", pods=len(pods)):
                 nt, agg, ep, nodes = self.cache.snapshot()
                 # Tag for the device-aggregate handoff: the snapshot the
@@ -249,8 +254,11 @@ class GenericScheduler:
                 # device=False keeps the batch pytree on host (the chunked
                 # drain slices it in numpy and transfers fixed-shape
                 # chunks).
-                db = sv.device_batch(batch) if device \
-                    else sv.host_batch(batch)
+                if device:
+                    with stage("transfer.batch"):
+                        db = sv.device_batch(batch)
+                else:
+                    db = sv.host_batch(batch)
                 # Cluster state syncs through the device-resident mirror:
                 # dirty rows scatter into the resident arrays; the full
                 # snapshot transfer happens only on relist or capacity
@@ -461,10 +469,11 @@ class GenericScheduler:
             choices, counter = self.host_solver.solve_greedy(
                 hb, hc, int(self.last_node_index),
                 extra_mask=extra_mask, score_bias=score_bias)
-        choices = self.guard.checked_readback(
-            "host", choices, len(nt.names),
-            alloc=nt.alloc, requests=np.asarray(batch.request),
-            keys_fn=lambda: [p.key for p in pods])
+        with stage("gate"):
+            choices = self.guard.checked_readback(
+                "host", choices, len(nt.names),
+                alloc=nt.alloc, requests=np.asarray(batch.request),
+                keys_fn=lambda: [p.key for p in pods])
         self.last_node_index = np.uint32(counter)
         names = nt.names
         return [names[int(c)] if c >= 0 else None for c in choices]
@@ -524,25 +533,26 @@ class GenericScheduler:
                       len({getattr(p, "_tpl_key", None) for p in pods}),
                       sv.cluster_nodes(dc), joint, flags)
         self._agg_handoff = None
-        from kubernetes_tpu.utils.profiling import device_trace
         if joint:
             with devicestats.live_path("joint"), \
-                    device_trace("solve_joint"), \
                     self.guard.watch("joint"), \
                     stage("solve", pods=len(pods), mode="joint"):
                 choices, new_last, _ = self.solver.solve_joint(
                     db, dc, jnp.uint32(self.last_node_index), flags=flags,
                     extra_mask=extra_mask, score_bias=score_bias,
                     live=live)
-                choices.block_until_ready()
+                with stage("device_wait"):
+                    choices.block_until_ready()
             with stage("readback", pods=len(pods)):
                 with self.guard.watch("joint", inject=False):
                     choices_np = np.asarray(choices)
                 devicestats.record_transfer("readback", choices_np.nbytes)
-                choices_np = self.guard.checked_readback(
-                    "joint", choices_np, sv.cluster_nodes(dc), live=live_np,
-                    alloc=nt.alloc, requests=np.asarray(batch.request),
-                    keys_fn=lambda: [pd.key for pd in pods[:real_p]])
+                with stage("gate"):
+                    choices_np = self.guard.checked_readback(
+                        "joint", choices_np, sv.cluster_nodes(dc),
+                        live=live_np, alloc=nt.alloc,
+                        requests=np.asarray(batch.request),
+                        keys_fn=lambda: [pd.key for pd in pods[:real_p]])
                 rows = choices_np[:real_p].tolist()
             self.last_node_index = np.uint32(new_last)
         else:
@@ -551,7 +561,6 @@ class GenericScheduler:
             # aggregates.
             p, n = len(pods), sv.cluster_nodes(dc)
             with devicestats.live_path("oneshot"), \
-                    device_trace("solve_sequential"), \
                     self.guard.watch("oneshot"), \
                     stage("solve", pods=p, mode="sequential"):
                 host_dev = self.solver.solve_sequential_packed(
@@ -560,15 +569,17 @@ class GenericScheduler:
                     live=live)
                 # Block here so the solve stage measures device compute
                 # and readback measures only the D2H copy.
-                host_dev.block_until_ready()
+                with stage("device_wait"):
+                    host_dev.block_until_ready()
             with stage("readback", pods=p):
                 with self.guard.watch("oneshot", inject=False):
                     host = np.asarray(host_dev)
                 devicestats.record_transfer("readback", host.nbytes)
-            choices_np = self.guard.checked_readback(
-                "oneshot", host[:p], n, live=live_np, alloc=nt.alloc,
-                requests=np.asarray(batch.request),
-                keys_fn=lambda: [pd.key for pd in pods[:real_p]])
+            with stage("gate"):
+                choices_np = self.guard.checked_readback(
+                    "oneshot", host[:p], n, live=live_np, alloc=nt.alloc,
+                    requests=np.asarray(batch.request),
+                    keys_fn=lambda: [pd.key for pd in pods[:real_p]])
             rows = choices_np[:real_p].tolist()
             self.last_node_index = np.uint32(host[p])
             # Device-aggregate handoff: the scan's final requested/nonzero
@@ -835,31 +846,37 @@ class GenericScheduler:
         def emit(start: int, choices) -> tuple[list, list]:
             with stage("readback", chunk_at=start):
                 with self.guard.watch("stream", inject=False):
-                    rows = np.asarray(choices)  # blocks on this chunk
+                    # The wait for this chunk's scan, apart from the
+                    # copy: readback - device_wait is the D2H transfer.
+                    with stage("device_wait"):
+                        choices.block_until_ready()
+                    rows = np.asarray(choices)
                 devicestats.record_transfer("readback", rows.nbytes)
             stop = min(start + chunk_size, p)
             chunk_pods = pods[start:stop]
             # Post-solve sanity gate: a corrupt chunk readback requeues
             # the chunk (DeviceFault through the commit worker) instead
             # of binding garbage.
-            rows = self.guard.checked_readback(
-                "stream", rows, n,
-                live=live_np[start:start + chunk_size],
-                alloc=nt.alloc,
-                requests=np.asarray(hb.request)[start:start + chunk_size],
-                keys_fn=lambda: [pd.key for pd in chunk_pods])
+            with stage("gate"):
+                rows = self.guard.checked_readback(
+                    "stream", rows, n,
+                    live=live_np[start:start + chunk_size],
+                    alloc=nt.alloc,
+                    requests=np.asarray(hb.request)[
+                        start:start + chunk_size],
+                    keys_fn=lambda: [pd.key for pd in chunk_pods])
             placements = [nt.names[int(c)] if c >= 0 else None
                           for c in rows[: stop - start]]
             return chunk_pods, placements
 
-        from kubernetes_tpu.utils.profiling import device_trace
         debug_t = self._stream_debug
         for start in range(0, padded, chunk_size):
             t0 = time.perf_counter() if debug_t else 0.0
             # Host-slice (free numpy views), then one batched device_put of
             # the fixed [chunk_size, ...] shapes: slicing ON DEVICE minted
             # a dynamic_slice program per distinct drain length.
-            with stage("transfer", chunk_at=start):
+            with stage("transfer", chunk_at=start), \
+                    stage("transfer.batch"):
                 db_k = jax.device_put(
                     sv.slice_pod_axis(hb, start, start + chunk_size))
                 live = jnp.asarray(live_np[start:start + chunk_size])
@@ -871,7 +888,6 @@ class GenericScheduler:
             # chunk's readback, which is what keeps the pipeline
             # overlapped — this stage measures dispatch only.
             with devicestats.live_path("stream"), \
-                    device_trace("solve_stream_chunk"), \
                     self.guard.watch("stream"), \
                     stage("solve", chunk_at=start, mode="stream"):
                 choices_k, counter, carry = self.solver._solve_scan(

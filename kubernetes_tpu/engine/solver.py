@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kubernetes_tpu.utils import knobs
+from kubernetes_tpu.utils.trace import stage
 from kubernetes_tpu.engine import fused as fused_mod
 from kubernetes_tpu.api.policy import (DEFAULT_MAX_EBS_VOLUMES,
                                        DEFAULT_MAX_GCE_PD_VOLUMES, Policy,
@@ -515,16 +516,18 @@ class ResidentCluster:
             # device-side copy of the cluster arrays per scatter,
             # HBM-to-HBM, micro-seconds at 5k nodes — still nothing like
             # the host->device transfer this mirror exists to avoid.
-            def scatter(c: "DeviceCluster | NarrowCluster",
-                        idx: jnp.ndarray,
-                        rows: "DeviceCluster | NarrowCluster"
-                        ) -> "DeviceCluster | NarrowCluster":
+            # Named for the profiler: its ``XLA Modules`` line reads
+            # ``jit_kt_scatter_rows`` beside ``jit__solve_scan``.
+            def kt_scatter_rows(c: "DeviceCluster | NarrowCluster",
+                                idx: jnp.ndarray,
+                                rows: "DeviceCluster | NarrowCluster"
+                                ) -> "DeviceCluster | NarrowCluster":
                 return type(c)(*[arr.at[idx].set(new)
                                  for arr, new in zip(c, rows)])
 
             # kt-xray: no-donate(prior DeviceCluster may be aliased by an
             # in-flight drain; see the comment above)
-            self._scatter = jax.jit(scatter)
+            self._scatter = jax.jit(kt_scatter_rows)
         return self._scatter
 
     @staticmethod
@@ -593,9 +596,11 @@ class ResidentCluster:
         sig = self.signature(nt, space, policy)
         if self.dc is None or self._sig != sig or self._epoch != epoch \
                 or len(dirty) * self.FULL_FRACTION >= max(n, 1):
-            host = _host_cluster(nt, agg, space)
-            self.dc = jax.device_put(
-                host if policy is None else narrow_cluster(host, policy))
+            with stage("transfer.full"):
+                host = _host_cluster(nt, agg, space)
+                self.dc = jax.device_put(
+                    host if policy is None
+                    else narrow_cluster(host, policy))
             self._sig = sig
             self._epoch = epoch
             self.stats["full_syncs"] += 1
@@ -612,6 +617,24 @@ class ResidentCluster:
             return self.dc
         if not dirty:
             return self.dc
+        with stage("transfer.rows"):
+            idx, rows = self._gather_rows(nt, agg, space, dirty, policy)
+        with stage("transfer.scatter"):
+            idx_d, rows_d = jax.device_put((idx, rows))
+            self.dc = self._scatter_fn()(self.dc, idx_d, rows_d)
+        self.stats["row_syncs"] += 1
+        self.stats["rows_scattered"] += len(dirty)
+        # Only the gathered rows crossed the wire (idx + padded rows).
+        devicestats.record_transfer(
+            "scatter", idx.nbytes + devicestats.nbytes(rows))
+        return self.dc
+
+    @staticmethod
+    def _gather_rows(nt: NodeTensors, agg: NodeAggregates,
+                     space: FeatureSpace, dirty: set[int],
+                     policy: Optional[DtypePolicy]) -> tuple:
+        """``(idx, rows)`` on the host: the dirty rows in the wire form,
+        padded to their pow2 bucket."""
         idx = np.fromiter(dirty, np.int32, len(dirty))
         # Gather the dirty rows directly (fancy indexing copies), padding
         # and deriving only the k gathered rows — assembling the full
@@ -646,14 +669,7 @@ class ResidentCluster:
             rows = type(rows)(*[
                 np.concatenate([arr, np.repeat(arr[:1], extra, axis=0)])
                 for arr in rows])
-        idx_d, rows_d = jax.device_put((idx, rows))
-        self.dc = self._scatter_fn()(self.dc, idx_d, rows_d)
-        self.stats["row_syncs"] += 1
-        self.stats["rows_scattered"] += len(dirty)
-        # Only the gathered rows crossed the wire (idx + padded rows).
-        devicestats.record_transfer(
-            "scatter", idx.nbytes + devicestats.nbytes(rows))
-        return self.dc
+        return idx, rows
 
 
 def _predicate_mask(name: str, b: DeviceBatch, c: DeviceCluster,

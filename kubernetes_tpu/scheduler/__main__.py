@@ -22,7 +22,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from kubernetes_tpu.api import types as api
-from kubernetes_tpu.utils import threadreg
+from kubernetes_tpu.utils import knobs, threadreg
 from kubernetes_tpu.api.policy import (cluster_autoscaler_provider,
                                        default_provider, policy_from_json)
 from kubernetes_tpu.scheduler.factory import ConfigFactory
@@ -67,9 +67,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--leader-elect-retry-period", type=float, default=2.0)
     p.add_argument("--v", type=int, default=None,
                    help="log verbosity (glog-style; also KT_LOG_V)")
-    p.add_argument("--profile-dir", default="",
-                   help="write jax.profiler device traces of every solve "
-                        "here (also KT_PROFILE_DIR; view with XProf)")
+    p.add_argument("--profile-dir", default=knobs.get("KT_PROFILE_DIR"),
+                   help="where GET /debug/pprof/trace?seconds=N writes "
+                        "its windowed jax.profiler traces (also "
+                        "KT_PROFILE_DIR; empty = a new temporary "
+                        "directory per trace; view with XProf)")
     p.add_argument("--config", default="",
                    help="KubeSchedulerConfiguration JSON file "
                         "(componentconfig/types.go:426-457); explicit "
@@ -162,10 +164,35 @@ def _decisions_route(daemon, query: str) -> tuple[int, bytes, str]:
         limit=limit, tenant=tenant)).encode(), "application/json")
 
 
-def _status_mux(factory: ConfigFactory, configz: dict, port: int
-                ) -> ThreadingHTTPServer:
+def _trace_route(profile_dir: str, query: str) -> tuple[int, bytes, str]:
+    """/debug/pprof/trace?seconds=N (net/http/pprof's trace endpoint):
+    one windowed ``jax.profiler`` session of the live daemon — device
+    operations, PjRt's host events and the ``kt.*`` stages in one file.
+    Answers when the profiler's stop has finished, which on a TPU host
+    takes 20-33 s of this process's CPU (PERF.md section 6), so traffic
+    stalls behind it; 409 while another session is live."""
+    from urllib.parse import parse_qs
+    from kubernetes_tpu.utils import profiling
+    try:
+        seconds = float(parse_qs(query).get("seconds", ["1"])[0])
+    except ValueError:
+        return (400, b'{"error": "seconds must be a number"}',
+                "application/json")
+    try:
+        written = profiling.trace_window(profile_dir, seconds)
+    except profiling.TraceBusy as err:
+        return (409, json.dumps({"error": str(err)}).encode(),
+                "application/json")
+    return 200, json.dumps(written).encode(), "application/json"
+
+
+def _status_mux(factory: ConfigFactory, configz: dict, port: int,
+                profile_dir: str = "") -> ThreadingHTTPServer:
     """The daemon's own HTTP surface (server.go:93-109)."""
-    from kubernetes_tpu.utils import telemetry
+    from kubernetes_tpu.utils import gcstats, telemetry
+    # The collector's pauses are the daemon's own to count, from its
+    # first launch on (utils/gcstats.py).
+    gcstats.install()
     # Self-scrape ring: the daemon-scoped metric set (queue depth, batch
     # size, attempts) rides the ring next to the default registry so the
     # dashboard's queue/stage/SLO sparklines have their sources.
@@ -216,6 +243,9 @@ def _status_mux(factory: ConfigFactory, configz: dict, port: int
                 # handlers, as the reference's mux does (server.go:96).
                 if not configz.get("enableProfiling", True):
                     self._send(404, b"profiling disabled")
+                    return
+                if path == "/debug/pprof/trace":
+                    self._send(*_trace_route(profile_dir, query))
                     return
                 from kubernetes_tpu.utils.profiling import thread_stacks
                 self._send(200, thread_stacks().encode())
@@ -348,9 +378,6 @@ def _status_mux(factory: ConfigFactory, configz: dict, port: int
 def main(argv=None) -> int:
     opts = apply_component_config(build_parser(), argv)
     configure(v=opts.v)
-    if opts.profile_dir:
-        from kubernetes_tpu.utils.profiling import set_profile_dir
-        set_profile_dir(opts.profile_dir)
     from kubernetes_tpu.utils import featuregate
     try:
         gates = featuregate.FeatureGate.parse(opts.feature_gates)
@@ -400,7 +427,8 @@ def main(argv=None) -> int:
                             scheduler_name=opts.scheduler_name,
                             qps=opts.kube_api_qps,
                             burst=opts.kube_api_burst)
-    mux = _status_mux(factory, configz, opts.port)
+    mux = _status_mux(factory, configz, opts.port,
+                      profile_dir=opts.profile_dir)
     log.info("status http on :%d (healthz, metrics, configz)",
              mux.server_address[1])
 

@@ -38,12 +38,35 @@ from typing import Optional
 
 from kubernetes_tpu.engine import guard as guard_mod
 from kubernetes_tpu.engine.guard import DeviceFault
-from kubernetes_tpu.scheduler.batchformer import BatchFormer, FormedBatch
+from kubernetes_tpu.scheduler.batchformer import (BatchFormer, FormedBatch,
+                                                  first_seen)
+from kubernetes_tpu.utils import metrics as metrics_mod
 from kubernetes_tpu.utils import trace as trace_mod
 from kubernetes_tpu.utils.logging import get_logger
 from kubernetes_tpu.utils.trace import Trace
 
 log = get_logger("pipeline")
+
+
+def _count_pod_waits(pods: list) -> None:
+    """One pass over a formed batch at its hand-off to the solve: how
+    long each pod has waited for a launch since it was first seen."""
+    now = time.perf_counter()
+    total = longest = 0.0
+    counted = 0
+    for pod in pods:
+        seen = first_seen(pod)
+        if seen is not None:
+            waited = now - seen
+            total += waited
+            counted += 1
+            if waited > longest:
+                longest = waited
+    if counted:
+        metrics_mod.POD_QUEUE_WAIT_SECONDS.inc(total)
+        metrics_mod.POD_QUEUE_WAIT_PODS.inc(counted)
+        if longest > metrics_mod.POD_QUEUE_WAIT_MAX.value:
+            metrics_mod.POD_QUEUE_WAIT_MAX.set(longest)
 
 
 class DrainPipeline:
@@ -91,7 +114,11 @@ class DrainPipeline:
         pods popped (scheduled or failed) — the daemon's
         ``schedule_pending`` contract."""
         daemon = self.daemon
-        batch = self.former.form(wait_first=wait_first, timeout=timeout)
+        # The queue_wait span is backdated below (the batch exists only
+        # at the wait's end); in a profiler's trace the wait is here.
+        with trace_mod.annotation("queue_wait"):
+            batch = self.former.form(wait_first=wait_first,
+                                     timeout=timeout)
         pods = batch.pods
         if not pods:
             return 0
@@ -115,11 +142,15 @@ class DrainPipeline:
         trace_mod.record_stage("queue_wait", start=batch.t_wait,
                                pods=len(pods))
         daemon.config.metrics.batch_size.set(len(pods))
+        _count_pod_waits(pods)
         tr = Trace(f"Scheduling batch of {len(pods)} pods")
         tr.start = batch.t_wait
         tr.step("Queue drained")
         try:
-            return self._solve(batch, tr=tr, trace_id=root.trace_id)
+            # One launch is one host interval of a profiler's trace (the
+            # root span is backdated, so it cannot be one itself).
+            with trace_mod.annotation("launch", pods=len(pods)):
+                return self._solve(batch, tr=tr, trace_id=root.trace_id)
         except Exception:  # noqa: BLE001 — HandleCrash analogue
             # The pods were already popped: requeue each through the
             # backoff path (condition + event + delayed retry) so a
@@ -148,6 +179,11 @@ class DrainPipeline:
             return len(pods)
         finally:
             root.end()
+            # The whole the stages are parts of: the root's duration.
+            trace_mod.observe_stage(
+                "launch_total",
+                (time.perf_counter() - batch.t_wait) * 1e6,
+                root.trace_id or None)
             # The reference's 20 ms slow-log (generic_scheduler.go:79-85),
             # now fed by the batched drain too; a slow batch also records
             # as a span with the step breakdown.
@@ -391,7 +427,6 @@ class DrainPipeline:
                        tr: Optional[Trace], trace_id: str,
                        host: bool = False) -> int:
         from kubernetes_tpu.engine.workloads import gang as gang_mod
-        from kubernetes_tpu.utils import metrics as metrics_mod
         daemon = self.daemon
         start = time.perf_counter()
         if host:

@@ -434,10 +434,16 @@ class Scheduler:
                     pod, "SchedulingError",
                     "placement from a sanity-gate-rejected solve refused",
                     result="error")
+        cache = self.config.algorithm.cache
         with stage("assume", pods=len(placed)):
-            skipped = set(self.config.algorithm.cache.assume_pods(
-                placed, strict=False,
-                agg_handoff=self.config.algorithm.take_agg_handoff()))
+            # The commit's wait behind the handlers (and the next
+            # launch's compile) for the cache lock, apart from the work.
+            t_lock = time.perf_counter()
+            with cache.lock:
+                trace_mod.record_stage("assume.lock_wait", start=t_lock)
+                skipped = set(cache.assume_pods(
+                    placed, strict=False,
+                    agg_handoff=self.config.algorithm.take_agg_handoff()))
         if skipped:
             placed = [(pod, dest) for pod, dest in placed
                       if pod.key not in skipped]

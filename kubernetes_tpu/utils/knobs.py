@@ -66,7 +66,8 @@ _knob("KT_TRACE_SAMPLE", "1", "float",
 _knob("KT_LOG_V", "0", "int",
       "Log verbosity (glog -v shape): <=1 INFO, <5 DEBUG, >=5 VERBOSE")
 _knob("KT_PROFILE_DIR", "", "str",
-      "jax.profiler trace dir for device solves (empty = no-op hook)")
+      "Default of the daemon's --profile-dir: where /debug/pprof/trace "
+      "writes its windowed traces (empty = a temp dir per trace)")
 _knob("KT_TELEMETRY_RING", "720", "int",
       "Self-scrape time-series ring capacity in samples")
 _knob("KT_TELEMETRY_PERIOD", "5", "float",

@@ -763,6 +763,50 @@ LOCK_LONG_HOLDS = register(Counter(
     "Traced-lock holds longer than KT_LOCKTRACE_HOLD_MS (default "
     "100 ms): a lock held across device work or I/O is a latency "
     "cliff for every thread queued behind it"))
+# The cache lock's contention, counted where it is taken
+# (cache/scheduler_cache.py _CacheLock): the launch thread holds the
+# lock through snapshot + compile + transfer, so this is how much of a
+# handler's wall clock is waiting.  Hold time needs no counter: it is
+# those three stages.
+CACHE_LOCK_WAIT_SECONDS = register(Counter(
+    "scheduler_cache_lock_wait_seconds_total",
+    "Seconds threads spent blocked on the scheduler cache's lock, by "
+    "the waiting thread's role (thread name, instance suffixes "
+    "collapsed); only contended acquisitions read the clock",
+    labelnames=("role",)))
+CACHE_LOCK_CONTENDED = register(Counter(
+    "scheduler_cache_lock_contended_total",
+    "Acquisitions of the scheduler cache's lock that had to block, by "
+    "the waiting thread's role",
+    labelnames=("role",)))
+# The daemon's cyclic collector (utils/gcstats.py, installed at daemon
+# start): every Python thread stands still for a collection.
+GC_PAUSE_SECONDS = register(Counter(
+    "scheduler_gc_pause_seconds_total",
+    "Wall seconds the cyclic garbage collector ran, by generation "
+    "(gc.callbacks start -> stop)",
+    labelnames=("generation",)))
+GC_COLLECTIONS = register(Counter(
+    "scheduler_gc_collections_total",
+    "Collections of the cyclic garbage collector, by generation",
+    labelnames=("generation",)))
+GC_PAUSE_MAX = register(Gauge(
+    "scheduler_gc_pause_max_seconds",
+    "The longest single collection since the daemon started"))
+# A pod's wait for a launch (scheduler/pipeline.py, one pass per formed
+# batch): with scheduler_e2e_decision_latency (first seen -> bind ack)
+# it splits the daemon's part of submit -> bind into waited-for-a-launch
+# and in-a-launch.
+POD_QUEUE_WAIT_SECONDS = register(Counter(
+    "scheduler_pod_queue_wait_seconds_total",
+    "Summed seconds from a pod's first admission to the hand-off of the "
+    "batch that holds it to the solve"))
+POD_QUEUE_WAIT_PODS = register(Counter(
+    "scheduler_pod_queue_wait_pods_total",
+    "Pods counted into scheduler_pod_queue_wait_seconds_total"))
+POD_QUEUE_WAIT_MAX = register(Gauge(
+    "scheduler_pod_queue_wait_max_seconds",
+    "The longest such wait of one pod since the daemon started"))
 # Server-side capacity validation at bind (apiserver/memstore.py): the
 # apiserver rejects a bind that would overcommit the target node's
 # allocatable (watch-lagged schedulers absorb the 409 via forget +
@@ -782,13 +826,17 @@ BIND_FAILURES = register(Counter(
     "Bind attempts lost to transport faults or timeouts (non-conflict); "
     "each forgets the assumed pod and requeues with backoff"))
 
-# The hot loop's named stages (utils/trace.stage): queue_wait, snapshot,
-# compile, transfer, solve, readback, assume, bind.  Registered here (not
-# per-daemon) because the recording sites span the engine and the daemon.
+# The hot loop's named stages (utils/trace.stage): queue_wait, lock_wait,
+# snapshot, compile, transfer, solve, readback, gate, assume, bind, and
+# the whole (launch_total); a dotted name or device_wait is a part of
+# the stage that holds it.  Registered here (not per-daemon) because the
+# recording sites span the engine and the daemon.
 STAGE_LATENCY = register(Histogram(
     "scheduler_batch_stage_latency_microseconds",
     "Per-stage wall time of the batched scheduling pipeline "
-    "(queue_wait/snapshot/compile/transfer/solve/readback/assume/bind)",
+    "(queue_wait/lock_wait/snapshot/compile/transfer/solve/readback/"
+    "gate/assume/bind, their parts transfer.batch|rows|scatter|full, "
+    "device_wait, assume.lock_wait, and launch_total = the batch root)",
     exponential_buckets(100, 2, 18), labelnames=("stage",)))
 
 # Apiserver request latency by verb/resource/code (the reference's

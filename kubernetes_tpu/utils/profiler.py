@@ -52,7 +52,6 @@ from __future__ import annotations
 
 import json
 import os
-import re
 import sys
 import threading
 import time
@@ -146,10 +145,6 @@ _PATH_RULES: tuple[tuple[str, str], ...] = (
 
 _MAX_STACK_DEPTH = 48
 _MAX_THREAD_LABELS = 24
-
-# Collapse per-instance numeric suffixes ("bind-worker-17") so thread
-# label cardinality stays bounded by ROLE, not by instance count.
-_NUM_SUFFIX = re.compile(r"[-_]?\d+$")
 
 
 def classify_frame(filename: str, func: str) -> Optional[str]:
@@ -366,7 +361,8 @@ class Profiler:
         self._export_locked()
 
     def _note_thread_locked(self, name: str, dcpu: float) -> None:
-        label = _NUM_SUFFIX.sub("", name) or name
+        # bounded by ROLE, not by instance count ("bind-worker-17")
+        label = threadreg.role(name)
         if label not in self._thread_cpu and \
                 len(self._thread_cpu) >= _MAX_THREAD_LABELS:
             label = "other"

@@ -19,9 +19,14 @@ joins are owned by the spawning batch.
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 from typing import Callable, Iterable, Optional
+
+# "bind-worker-17", "chunk-commit_0", "Thread-12 (run)": the instance
+# part of a thread's name, so label cardinality follows roles.
+_INSTANCE_SUFFIX = re.compile(r"[-_]?\d+(?: \(.*\))?$| \(.*\)$")
 
 _lock = threading.Lock()
 _registry: list[tuple[str, threading.Thread, float]] = []
@@ -42,6 +47,13 @@ def spawn(target: Callable, *, name: str, args: tuple = (),
     if start:
         t.start()
     return t
+
+
+def role(name: str) -> str:
+    """A thread's name with its per-instance suffix collapsed — the label
+    kt-prof's ``process_thread_cpu_seconds_total{thread}`` and the cache
+    lock's ``{role}`` counters share."""
+    return _INSTANCE_SUFFIX.sub("", name, count=1) or name
 
 
 def register(thread: threading.Thread,

@@ -17,6 +17,15 @@ full span tracer for the batched control plane:
 * ``stage(name)`` is a span *and* a labeled histogram observation
   (``scheduler_batch_stage_latency_microseconds{stage=...}``) — the hot
   loop's named stages feed both the trace view and /metrics.
+* Spans opened where they happen (``span``/``begin_span`` without a
+  backdate, ``stage``) are ALSO host events ``kt.<name>`` in a live
+  ``jax.profiler`` session (``jax.profiler.TraceAnnotation``): the
+  program's stages sit on the device trace's clock beside PjRt's own
+  events, so an idle gap of the device reads as the stage that covered
+  it.  Only a process that has already imported JAX pays for it (the
+  apiserver never does), and without a live session a TraceMe records
+  nothing.  Backdated spans cannot be host events; ``annotation()`` wraps
+  the place where their time is really spent.
 * The off path costs one branch: ``KT_TRACE=0`` disables span recording
   entirely (``span()`` checks one module bool and yields), and
   ``KT_TRACE_SAMPLE`` (0.0-1.0) samples at trace granularity — the
@@ -34,6 +43,7 @@ import json
 import logging
 import os
 import random
+import sys
 import threading
 import time
 from typing import Iterator
@@ -98,6 +108,34 @@ def _record(name: str, trace_id: str, span_id: str, parent_id: str,
             ring = _ring
     ring.append((name, trace_id, span_id, parent_id, ts_us, dur_us,
                  threading.get_ident(), attrs))
+
+
+# -- host events in the profiler's trace -----------------------------------
+
+_NO_ANNOTATION = contextlib.nullcontext()
+_trace_annotation = None   # jax.profiler.TraceAnnotation once JAX is loaded
+
+
+def _annotation_class() -> type | None:
+    """``jax.profiler.TraceAnnotation`` if this process has imported JAX
+    (resolved once), else None: tracing never imports JAX itself."""
+    global _trace_annotation
+    if _trace_annotation is None and "jax" in sys.modules:
+        from kubernetes_tpu.utils import profiling
+        _trace_annotation = profiling.annotation_class()
+    return _trace_annotation
+
+
+def annotation(name: str,
+               **attrs: object) -> contextlib.AbstractContextManager:
+    """A context manager that is the host event ``kt.<name>`` in a live
+    profiler session — for time no span is opened around (the former's
+    wait behind the backdated ``queue_wait``, a contended lock, a
+    collection).  A shared no-op with ``KT_TRACE=0`` or without JAX."""
+    if not _enabled:
+        return _NO_ANNOTATION
+    cls = _annotation_class()
+    return _NO_ANNOTATION if cls is None else cls("kt." + name, **attrs)
 
 
 # -- context ---------------------------------------------------------------
@@ -168,7 +206,7 @@ class _SpanHandle:
     ``attrs`` may be amended while the span is open."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "_ts", "_t0", "_prev", "_done")
+                 "_ts", "_t0", "_prev", "_done", "_annotation")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: str, attrs: dict,
@@ -182,11 +220,13 @@ class _SpanHandle:
         self._t0 = t0
         self._prev = prev
         self._done = False
+        self._annotation = _NO_ANNOTATION
 
     def end(self, **attrs) -> None:
         if self._done:
             return
         self._done = True
+        self._annotation.__exit__(None, None, None)
         _tls.ctx = self._prev
         if attrs:
             self.attrs.update(attrs)
@@ -257,6 +297,9 @@ def begin_span(name: str, start: float | None = None,
     if start is not None:
         h._t0 = start
         h._ts -= (t0 - start) * 1e6
+    else:
+        h._annotation = annotation(name)
+        h._annotation.__enter__()
     return h
 
 
@@ -310,11 +353,11 @@ def stage(name: str, **attrs: object) -> Iterator[object]:
             yield h
         finally:
             h.end()
-            _observe_stage(name, (time.perf_counter() - t0) * 1e6,
+            observe_stage(name, (time.perf_counter() - t0) * 1e6,
                            h.trace_id or None)
     else:
         yield _NOOP
-        _observe_stage(name, (time.perf_counter() - t0) * 1e6, None)
+        observe_stage(name, (time.perf_counter() - t0) * 1e6, None)
 
 
 def record_stage(name: str, start: float, end: float | None = None,
@@ -328,11 +371,13 @@ def record_stage(name: str, start: float, end: float | None = None,
         h = begin_span(name, start=start, **attrs)
         h.end()
         tid = h.trace_id or None
-    _observe_stage(name, (end - start) * 1e6, tid)
+    observe_stage(name, (end - start) * 1e6, tid)
 
 
-def _observe_stage(name: str, us: float, trace_id: str | None = None
-                   ) -> None:
+def observe_stage(name: str, us: float, trace_id: str | None = None
+                  ) -> None:
+    """One observation of the per-stage histogram with no span: for a
+    whole that is already a span (``launch_total`` = the batch root)."""
     from kubernetes_tpu.utils import metrics
     metrics.STAGE_LATENCY.labels(stage=name).observe(us,
                                                      exemplar=trace_id)

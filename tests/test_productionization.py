@@ -215,23 +215,47 @@ class TestPolicySchemaCompat:
         assert back_n.is_ready() == node.is_ready()
 
 class TestObservability:
-    def test_device_trace_writes_profile(self, tmp_path):
-        """--profile-dir captures a jax.profiler device trace per solve
-        (the TPU pprof analogue, SURVEY §5 tracing row)."""
-        from kubernetes_tpu.engine.generic_scheduler import GenericScheduler
+    def test_trace_window_writes_profile(self, tmp_path, monkeypatch):
+        """A windowed jax.profiler session over running solves (the TPU
+        pprof analogue, SURVEY §5 tracing row): one .xplane.pb that
+        holds the solves' own stages as host events."""
+        import types
+        from jax.profiler import ProfileData
         from kubernetes_tpu.utils import profiling
         from helpers import make_node, make_pod
         eng = GenericScheduler()
         for i in range(4):
             eng.cache.add_node(make_node(f"n{i}"))
-        profiling.set_profile_dir(str(tmp_path))
+        eng.schedule_batch([make_pod("p1"), make_pod("p2")])   # compiles
+        solved, stop = threading.Event(), threading.Event()
+
+        def solve_on():
+            while not stop.is_set():
+                eng.schedule_batch([make_pod("p1"), make_pod("p2")])
+                solved.set()
+
+        def until_a_whole_solve_ran(_seconds: float) -> None:
+            for _ in range(2):      # the first may have begun before
+                solved.clear()
+                assert solved.wait(30.0)
+
+        monkeypatch.setattr(profiling, "time", types.SimpleNamespace(
+            sleep=until_a_whole_solve_ran, perf_counter=time.perf_counter))
+        worker = threading.Thread(target=solve_on)
+        worker.start()
         try:
-            eng.schedule_batch([make_pod("p1"), make_pod("p2")])
+            written = profiling.trace_window(str(tmp_path), 0.1)
         finally:
-            profiling.set_profile_dir("")
-        written = list(tmp_path.rglob("*"))
-        assert any(p.is_file() for p in written), \
-            f"no profile artifacts under {tmp_path}"
+            stop.set()
+            worker.join(30.0)
+        assert not worker.is_alive()
+        assert written["dir"] == str(tmp_path)
+        found = list(tmp_path.rglob("*.xplane.pb"))
+        assert len(found) == 1, f"profile artifacts under {tmp_path}"
+        names = {e.name for plane in ProfileData.from_file(
+            str(found[0])).planes for line in plane.lines
+            for e in line.events}
+        assert {"kt.snapshot", "kt.compile", "kt.solve"} <= names
 
     def test_thread_stacks_dump(self):
         from kubernetes_tpu.utils.profiling import thread_stacks

@@ -286,34 +286,45 @@ class TestTransferAccounting:
         del keep
 
 
-# -- profiling hook (satellite: --profile-dir wiring) ------------------------
+# -- profiling hook (the windowed trace and its --profile-dir) ---------------
 
 class TestProfilingHook:
     def test_noop_path_is_zero_overhead(self):
-        from kubernetes_tpu.utils.profiling import (device_trace,
-                                                    set_profile_dir)
-        set_profile_dir("")
-        t0 = time.perf_counter()
-        for _ in range(100_000):
-            with device_trace("solve"):
-                pass
-        elapsed = time.perf_counter() - t0
-        assert elapsed < 1.0, f"no-op device_trace cost {elapsed:.2f}s"
+        """With KT_TRACE=0 a host event is the one shared no-op object:
+        nothing is built per stage."""
+        from kubernetes_tpu.utils import trace
+        was = trace.enabled()
+        trace.set_enabled(False)
+        try:
+            noop = trace.annotation("solve")
+            t0 = time.perf_counter()
+            for _ in range(100_000):
+                with trace.annotation("solve") as a:
+                    assert a is None
+            elapsed = time.perf_counter() - t0
+            assert trace.annotation("launch", pods=3) is noop
+        finally:
+            trace.set_enabled(was)
+        assert elapsed < 1.0, f"no-op annotation cost {elapsed:.2f}s"
 
-    def test_bench_flag_arms_the_profile_dir(self, tmp_path):
-        import importlib.util
-        import os
-        spec = importlib.util.spec_from_file_location(
-            "bench", os.path.join(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__))), "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        opts = bench.build_parser().parse_args(
+    def test_daemon_flag_names_the_trace_directory(self, tmp_path,
+                                                   monkeypatch):
+        """--profile-dir (default KT_PROFILE_DIR) is where the daemon's
+        /debug/pprof/trace writes; a second session is refused whoever
+        holds the first."""
+        from kubernetes_tpu.scheduler import __main__ as daemon
+        from kubernetes_tpu.utils import profiling
+        opts = daemon.build_parser().parse_args(
             ["--profile-dir", str(tmp_path)])
         assert opts.profile_dir == str(tmp_path)
-        from kubernetes_tpu.utils import profiling
-        profiling.set_profile_dir(opts.profile_dir)
+        monkeypatch.setenv("KT_PROFILE_DIR", str(tmp_path / "env"))
+        assert daemon.build_parser().parse_args([]).profile_dir == \
+            str(tmp_path / "env")
+        assert profiling._session.acquire(blocking=False)
         try:
-            assert profiling._PROFILE_DIR[0] == str(tmp_path)
+            status, body, _ctype = daemon._trace_route(
+                opts.profile_dir, "seconds=0")
         finally:
-            profiling.set_profile_dir("")
+            profiling._session.release()
+        assert status == 409, body
+        assert not list(tmp_path.rglob("*.xplane.pb"))
