@@ -22,7 +22,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from kubernetes_tpu.api import types as api
-from kubernetes_tpu.utils import knobs, threadreg
+from kubernetes_tpu.utils import gcstats, knobs, threadreg
 from kubernetes_tpu.api.policy import (cluster_autoscaler_provider,
                                        default_provider, policy_from_json)
 from kubernetes_tpu.scheduler.factory import ConfigFactory
@@ -189,9 +189,10 @@ def _trace_route(profile_dir: str, query: str) -> tuple[int, bytes, str]:
 def _status_mux(factory: ConfigFactory, configz: dict, port: int,
                 profile_dir: str = "") -> ThreadingHTTPServer:
     """The daemon's own HTTP surface (server.go:93-109)."""
-    from kubernetes_tpu.utils import gcstats, telemetry
+    from kubernetes_tpu.utils import telemetry
     # The collector's pauses are the daemon's own to count, from its
-    # first launch on (utils/gcstats.py).
+    # first launch on (utils/gcstats.py); main() also makes it tenure
+    # the long-lived heap once start-up is over.
     gcstats.install()
     # Self-scrape ring: the daemon-scoped metric set (queue depth, batch
     # size, attempts) rides the ring next to the default registry so the
@@ -437,6 +438,13 @@ def main(argv=None) -> int:
     def shutdown(*_):
         stop.set()
 
+    def tenure_heap() -> None:
+        # Start-up is over: jax, the compiled programs, the nodes and
+        # the listed resident pods leave the cyclic collector's pass,
+        # and so do the survivors of every full collection after it.
+        log.info("heap tenured: %d objects",
+                 gcstats.install().baseline())
+
     signal.signal(signal.SIGTERM, shutdown)
     signal.signal(signal.SIGINT, shutdown)
 
@@ -454,18 +462,19 @@ def main(argv=None) -> int:
             renew_deadline=opts.leader_elect_renew_deadline,
             retry_period=opts.leader_elect_retry_period,
             on_started_leading=lambda: (log.info("leading as %s", identity),
-                                        factory.run()),
+                                        factory.run(started=tenure_heap)),
             on_stopped_leading=lambda: (log.warning("lost lease; exiting"),
                                         stop.set()))
         elector.run()
         log.info("leader election: candidate %s", identity)
     else:
-        factory.run()
+        factory.run(started=tenure_heap)
         log.info("scheduler loop running (no leader election)")
 
     stop.wait()
     factory.stop()
     mux.shutdown()
+    gcstats.uninstall()
     return 0
 
 

@@ -20,7 +20,7 @@ wire through a QPS/Burst rate-limited client (factory.go:77-91)."""
 from __future__ import annotations
 
 import threading
-from typing import Optional, Union
+from typing import Callable, Optional, Union
 
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.api.policy import Policy
@@ -485,8 +485,12 @@ class ConfigFactory:
 
     # -- lifecycle -------------------------------------------------------
 
-    def run(self) -> "ConfigFactory":
-        """f.Run (factory.go:387-416) + scheduler.Run."""
+    def run(self, started: Callable[[], object] | None = None
+            ) -> "ConfigFactory":
+        """f.Run (factory.go:387-416) + scheduler.Run.  ``started`` is
+        called once start-up is over (reflectors synced, prewarm and
+        recovery done) and before the scheduling loop starts: the
+        daemon's entry point tenures the heap there."""
         specs = [
             # The reference's two fielded pod informers (factory.go:
             # 128-149, 466-469): the queue side never sees assigned-pod
@@ -537,6 +541,8 @@ class ConfigFactory:
             self.last_recovery = recovery.reconcile(
                 self.daemon, self.store,
                 scheduler_name=self.daemon.config.scheduler_name)
+        if started is not None:
+            started()
         slo_period = knobs.get_float("KT_SLO_PERIOD")
         if slo_period > 0:
             # Multi-window SLO burn: one cheap bucket read per tick

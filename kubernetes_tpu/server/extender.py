@@ -20,7 +20,6 @@ Run: ``python -m kubernetes_tpu.server.extender --port 12346``.
 from __future__ import annotations
 
 import argparse
-import gc
 import hashlib
 import json
 import threading
@@ -32,6 +31,7 @@ from kubernetes_tpu.api import types as api
 from kubernetes_tpu.api.policy import Policy, default_provider, policy_from_json
 from kubernetes_tpu.cache.scheduler_cache import SchedulerCache
 from kubernetes_tpu.engine.generic_scheduler import GenericScheduler, Listers
+from kubernetes_tpu.utils import gcstats
 from kubernetes_tpu.utils.metrics import SchedulerMetrics
 
 
@@ -552,8 +552,7 @@ def _freeze_baseline_heap() -> None:
     if _heap_frozen:
         return
     _heap_frozen = True
-    gc.collect()
-    gc.freeze()
+    gcstats.tenure()
 
 
 def _refreeze_heap() -> None:
@@ -562,8 +561,7 @@ def _refreeze_heap() -> None:
     since the last freeze is reclaimed, not immortalized.  Refcounting
     still frees frozen objects when dropped — freeze only exempts them
     from gen-2 scans, which is exactly what keeps verb tails flat."""
-    gc.collect()
-    gc.freeze()
+    gcstats.tenure()
 
 
 def serve_in_thread(port: int = 0, policy: Policy | None = None,

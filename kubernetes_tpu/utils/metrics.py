@@ -793,6 +793,21 @@ GC_COLLECTIONS = register(Counter(
 GC_PAUSE_MAX = register(Gauge(
     "scheduler_gc_pause_max_seconds",
     "The longest single collection since the daemon started"))
+# Tenuring (utils/gcstats.py): survivors of a full collection leave the
+# collector's reach; a major collection walks the whole heap again.
+GC_TENURES = register(Counter(
+    "scheduler_gc_tenures_total",
+    "Full collections whose survivors were moved to the permanent "
+    "generation (gc.freeze)"))
+GC_MAJOR_COLLECTIONS = register(Counter(
+    "scheduler_gc_major_collections_total",
+    "Full collections over the un-tenured heap: the baseline at the end "
+    "of start-up, then one each time the tenured count has doubled"))
+GC_TENURED_OBJECTS = register(Gauge(
+    "scheduler_gc_tenured_objects",
+    "gc.get_freeze_count() as the doubling rule last read it: at a "
+    "major collection, and whenever the objects tenured since could "
+    "have doubled the count"))
 # A pod's wait for a launch (scheduler/pipeline.py, one pass per formed
 # batch): with scheduler_e2e_decision_latency (first seen -> bind ack)
 # it splits the daemon's part of submit -> bind into waited-for-a-launch

@@ -294,6 +294,11 @@ class Profiler:
         now = time.monotonic()
         frames = sys._current_frames()
         me = threading.get_ident()
+        # Its own entry is this very frame, whose local `frames` is the
+        # dict: a cycle that would pin every thread's stack (a launch's
+        # pods and device arrays among its locals) until the cyclic
+        # collector finds it, 19 times a second.  No reader wants it.
+        frames.pop(me, None)
         threads = {t.ident: t for t in threading.enumerate()
                    if t.ident is not None}
         per_thread: dict[int, float] = {}
