@@ -19,6 +19,31 @@ so that a later cell can bring a PUBLISHED mix as a data file (the
 numbers ``perf/synth.py`` uses for it are synth's inventions and stand in
 no configuration); the benchmark's own tests run it at a tiny size.
 
+THE INTERFACE OF A SHAPES MODULE (``shapes/<word>.py`` for a
+configuration that names ``"shapes": "<word>"``; this file where it names
+none).  ``run.py``, ``loadgen.py``, the traffic kinds, ``judge.py`` and
+``refsched.py`` use nothing else; every object is made from the
+configuration's ``nodes`` / ``pods`` specs and the seed alone, so that the
+runner, the judge and ``refsched.py`` (another process) each make the
+same ones:
+
+  ``Nodes(spec, seed)``            ``n``; ``to_json()`` -> the v1 node
+                                   objects, node ``i`` named ``node-<i>``
+  ``Pods(spec, seed, nodes_spec)`` an endless population: ``len()``,
+                                   ``grow(n)`` (thread-safe, at least ``n``
+                                   pods), ``json_bytes(i)`` (pod ``p-<i>``
+                                   as the v1 JSON a create sends, ending
+                                   in ``}}`` of ``spec`` so that
+                                   ``run.prefill`` can add a ``nodeName``),
+                                   ``list_body(start, stop)`` (a v1
+                                   ``List`` of those)
+
+plus whatever per-node and per-pod arrays the configuration's REFERENCE
+reads (here ``alloc_cpu``, ``alloc_mem``, ``alloc_pods``, ``pool``,
+``zone`` per node and ``cpu``, ``mem``, ``sel``, ``aff`` per pod, every
+pod array ``len()`` long).  A shapes file adds arrays of its own (a label
+group, a topology domain); nothing but its own reference reads them.
+
 Node profile parameters (``configs/<name>.json`` ``nodes``):
   count, profile ("uniform" | "mixed"), milli_cpu, memory, pods, n_zones,
   n_pools, capacity_scales (one entry per equal share of the fleet).
@@ -118,11 +143,11 @@ class Pods:
     (required pool, -1 none) and ``aff[i]`` (preferred zone, -1 none)
     exist for every ``i < len``; ``grow`` extends them."""
 
-    def __init__(self, spec: dict, seed: int, n_pools: int = 4,
-                 n_zones: int = 4):
+    def __init__(self, spec: dict, seed: int, nodes_spec: dict | None = None):
         self.spec = spec
         self.rng = np.random.RandomState((seed + 1) % (2 ** 32))
-        self.n_pools, self.n_zones = n_pools, n_zones
+        self.n_pools = int((nodes_spec or {}).get("n_pools", 4))
+        self.n_zones = int((nodes_spec or {}).get("n_zones", 4))
         self.cpu = np.zeros(0, np.int64)
         self.mem = np.zeros(0, np.int64)
         self.sel = np.zeros(0, np.int64)

@@ -54,8 +54,8 @@ class _GcWatch:
 
     def __init__(self):
         self.started = None
-        self.total_s = self.longest_s = self.full_s = 0.0
-        self.collections = self.full_collections = 0
+        self.total_s = self.longest_s = 0.0
+        self.collections = 0
 
     def __call__(self, phase: str, info: dict) -> None:
         if phase == "start":
@@ -66,14 +66,10 @@ class _GcWatch:
             self.total_s += took
             self.collections += 1
             self.longest_s = max(self.longest_s, took)
-            if info.get("generation") == 2:
-                self.full_s += took
-                self.full_collections += 1
 
     def snapshot(self) -> dict:
         return {"gc_pause_s": self.total_s, "gc_pause_max_s": self.longest_s,
-                "gc_full_s": self.full_s, "gc_collections": self.collections,
-                "gc_full_collections": self.full_collections}
+                "gc_collections": self.collections}
 
 
 def _control(ctl_dir: str) -> None:

@@ -43,6 +43,7 @@ import loadgen
 
 _BLOCK = 8192
 TICK_S = 0.002
+PAUSE_S = 0.05
 
 
 class Generator(loadgen.Traffic):
@@ -54,6 +55,15 @@ class Generator(loadgen.Traffic):
         self.due: list = []           # monotonic due time of create i (pod first_pod + i)
         self.sent: list = []          # monotonic time pod i was written
         self.t_stopped = None         # when the creator stopped writing
+        # the creator's own longest stalls inside the window, so that a
+        # run whose GENERATOR ran late says where: in a write the
+        # apiserver did not take, in making the next block, or in a loop
+        # pass that lost the CPU; and how many passes took over
+        # PAUSE_S (a block build takes ~25 ms; the chip machines pause
+        # everything for ~110 ms now and then, and a window's p95 follows
+        # how often: PERF.md, PR 29)
+        self.stall_s = {"send": 0.0, "extend": 0.0, "pass": 0.0}
+        self.pauses = 0
         self.requests: list = []
         self.warm = sorted(self.launch_buckets, reverse=True)
         if self.warm:
@@ -120,12 +130,16 @@ class Generator(loadgen.Traffic):
             sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             tick = 0
             while self.creating:
+                t_pass = time.monotonic()
                 if len(self.due) - i < _BLOCK // 2:
                     self._extend()
-                j = bisect.bisect_right(self.due, time.monotonic(), i)
+                t_made = time.monotonic()
+                j = bisect.bisect_right(self.due, t_made, i)
+                t_sent = t_made
                 if j > i:
                     sock.sendall(b"".join(self.requests[i:j]))
-                    self.sent.extend([time.monotonic()] * (j - i))
+                    t_sent = time.monotonic()
+                    self.sent.extend([t_sent] * (j - i))
                     self.requests[i:j] = [None] * (j - i)
                     i = j
                 tick += 1
@@ -138,6 +152,14 @@ class Generator(loadgen.Traffic):
                     except BlockingIOError:
                         pass
                 time.sleep(TICK_S)
+                t_close = self.t_close      # set after t_open, so read first
+                if t_close is not None and self.t_open <= t_pass < t_close:
+                    stall = self.stall_s
+                    stall["extend"] = max(stall["extend"], t_made - t_pass)
+                    stall["send"] = max(stall["send"], t_sent - t_made)
+                    took = time.monotonic() - t_pass
+                    stall["pass"] = max(stall["pass"], took)
+                    self.pauses += took > PAUSE_S
             self.t_stopped = time.monotonic()
             sock.settimeout(5.0)
             while self.book.n_created < self.first_pod + i \
@@ -186,4 +208,9 @@ class Generator(loadgen.Traffic):
                 "submit_to_bind_p99_ms": float(np.percentile(lat, 99)),
                 "submit_to_bind_max_ms": float(lat.max()),
                 "late_ms_p99": float(np.percentile(late, 99)),
+                "late_ms_max": float(late.max()),
+                "creator_send_max_ms": self.stall_s["send"] * 1e3,
+                "creator_extend_max_ms": self.stall_s["extend"] * 1e3,
+                "creator_pass_max_ms": self.stall_s["pass"] * 1e3,
+                "creator_pauses": float(self.pauses),
                 "latency_samples": len(pods)}

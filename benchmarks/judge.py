@@ -1,5 +1,7 @@
-"""What decides ``correct``: the window's answers against the plain
-reference (``reference.py``) and the configuration's guarantees.
+"""What decides ``correct``: the window's answers against the
+configuration's plain reference (``reference.py``, or the
+``references/<word>.py`` it names; the interface is in ``reference.py``'s
+docstring) and the configuration's guarantees.
 
 Everything is read from the client's side — the observer's record of
 binds and deletes in watch (resourceVersion) order, the acknowledged
@@ -8,9 +10,11 @@ how it solved.  Run after the window has closed and the daemon has been
 stopped.
 
 Exact numbers (limit 0), over EVERY pod of the run:
-  lost_pods, never_bound, double_binds, unknown_node_binds,
-  selector_violations, over_allocatable, list_mismatch, client_errors,
-  and the account's counters.
+  lost_pods, never_bound, double_binds, unknown_node_binds, each name of
+  the reference's ``GUARANTEES`` (``reference.py``: selector_violations,
+  over_allocatable; asked of ``broken`` at every bind of the record
+  before it is added, and again of every pod in the list at close),
+  list_mismatch, client_errors, and the account's counters.
 
 Decision numbers, over a seed-drawn sample of the binds seen inside the
 window: ``gap`` = reference's best score among fitting nodes minus the
@@ -36,26 +40,31 @@ import bisect
 
 import numpy as np
 
-import reference
 import rig
 from loadgen import BIND
 
 
-def replay(nodes, pods, events: list, sample_at: set, judge_cfg: dict
+def _bind(ref, state, counts: dict, pod: int, node: int) -> None:
+    """Ask the reference which guarantees the bind breaks, then add it."""
+    for name, value in ref.broken(state, pod, node).items():
+        counts[name] += value
+    state.add(pod, node)
+
+
+def replay(ref, nodes, pods, events: list, sample_at: set, judge_cfg: dict
            ) -> dict:
-    """One pass over the observer's record."""
+    """One pass over the observer's record, the cluster kept in the
+    configuration's reference ``State`` throughout.  Each name of
+    ``ref.GUARANTEES`` is counted over EVERY bind, asked before the bind
+    is added."""
     n = nodes.n
-    cnt, cpu, mem = [0] * n, [0] * n, [0] * n
-    a_cnt, a_cpu, a_mem = (nodes.alloc_pods.tolist(), nodes.alloc_cpu.tolist(),
-                           nodes.alloc_mem.tolist())
-    pool = nodes.pool.tolist()
-    p_cpu, p_mem, p_sel = pods.cpu, pods.mem, pods.sel
+    state = ref.State(nodes, pods)
     node_of: dict = {}
     deleted: list = []
     deleted_t: list = []
     out = {"double_binds": 0, "unknown_node_binds": 0,
-           "selector_violations": 0, "over_allocatable": 0,
            "deletes_of_unbound": 0}
+    out.update({name: 0 for name in ref.GUARANTEES})
     gaps, gaps0, lags = [], [], []
     step, max_lag_s = int(judge_cfg["lag_step"]), float(judge_cfg["max_lag_s"])
     for at, (kind, pod, node, t) in enumerate(events):
@@ -70,29 +79,19 @@ def replay(nodes, pods, events: list, sample_at: set, judge_cfg: dict
                 # deletes seen within max_lag_s before this bind
                 young = len(deleted) - bisect.bisect_left(
                     deleted_t, t - max_lag_s)
-                gap, gap0, lag = _gap(nodes, pods, cnt, cpu, mem, deleted,
-                                      node_of, pod, node, step, young)
+                gap, gap0, lag = _gap(ref, state, deleted, node_of, pod,
+                                      node, step, young)
                 gaps.append(gap)
                 gaps0.append(gap0)
                 lags.append(lag)
             node_of[pod] = node
-            c, m = int(p_cpu[pod]), int(p_mem[pod])
-            cnt[node] += 1
-            cpu[node] += c
-            mem[node] += m
-            if cnt[node] > a_cnt[node] or cpu[node] > a_cpu[node] \
-                    or mem[node] > a_mem[node]:
-                out["over_allocatable"] += 1
-            if p_sel[pod] >= 0 and pool[node] != p_sel[pod]:
-                out["selector_violations"] += 1
+            _bind(ref, state, out, pod, node)
         else:
             where = node_of.get(pod)
             if where is None or where < 0:
                 out["deletes_of_unbound"] += 1
                 continue
-            cnt[where] -= 1
-            cpu[where] -= int(p_cpu[pod])
-            mem[where] -= int(p_mem[pod])
+            state.add(pod, where, -1)
             node_of[pod] = -1 - where      # gone; remembers where it was
             deleted.append(pod)
             deleted_t.append(t)
@@ -109,40 +108,36 @@ def replay(nodes, pods, events: list, sample_at: set, judge_cfg: dict
     return out
 
 
-def _gap(nodes, pods, cnt, cpu, mem, deleted, node_of, pod, node,
-         step, young) -> tuple[float, float, int]:
+def _gap(ref, state, deleted, node_of, pod, node, step, young
+         ) -> tuple[float, float, int]:
     """``(least gap, gap with no lag, lag of the least)``; ``young`` = how
-    many of the last deletes the program may not have seen yet."""
-    state = reference.State(nodes)
-    state.cnt = np.array(cnt, np.int64)
-    state.cpu = np.array(cpu, np.int64)
-    state.mem = np.array(mem, np.int64)
-    args = (int(pods.cpu[pod]), int(pods.mem[pod]), int(pods.sel[pod]),
-            int(pods.aff[pod]))
-    gap0 = reference.score_gap(state, *args, node)
+    many of the last deletes the program may not have seen yet.  The
+    search puts them back, newest first, into a copy of ``state``."""
+    gap0 = ref.score_gap(state, pod, node)
+    if gap0 == 0 or young <= 0:
+        return gap0, gap0, 0
     best, best_lag = gap0, 0
     hi = len(deleted)
     oldest = hi - young
+    state = state.copy()
     while best != 0 and hi > oldest:
         lo = max(hi - step, oldest)
-        back = np.array(deleted[lo:hi], np.int64)
-        where = np.array([-1 - node_of[p] for p in deleted[lo:hi]], np.int64)
-        np.add.at(state.cnt, where, 1)
-        np.add.at(state.cpu, where, pods.cpu[back])
-        np.add.at(state.mem, where, pods.mem[back])
+        for back in deleted[lo:hi]:
+            state.add(back, -1 - node_of[back])
         hi = lo
-        gap = reference.score_gap(state, *args, node)
+        gap = ref.score_gap(state, pod, node)
         if gap < best:
             best, best_lag = gap, len(deleted) - hi
     return best, gap0, best_lag
 
 
-def judge(nodes, pods, book, n_offered: int, final_list: dict,
+def judge(ref, nodes, pods, book, n_offered: int, final_list: dict,
           window: tuple, seed: int, config: dict, account: dict,
           platform: str) -> tuple[bool, dict, dict]:
-    """``(correct, {name: [number, limit]}, info)``.  ``n_offered`` = pods the
-    generator wrote to the apiserver; ``final_list`` = the apiserver's
-    ``{pod: node or -1}`` at close."""
+    """``(correct, {name: [number, limit]}, info)``.  ``ref`` = the
+    configuration's reference module (``run.parts_of``); ``n_offered`` =
+    pods the generator wrote to the apiserver; ``final_list`` = the
+    apiserver's ``{pod: node or -1}`` at close."""
     events = book.events
     t_open, t_close = window
     in_window = [i for i, (kind, _p, _n, t) in enumerate(events)
@@ -151,7 +146,7 @@ def judge(nodes, pods, book, n_offered: int, final_list: dict,
     rng = np.random.RandomState((seed + 3) % (2 ** 32))
     if len(in_window) > want:
         in_window = rng.choice(in_window, want, replace=False).tolist()
-    rep = replay(nodes, pods, events, set(in_window), config["judge"])
+    rep = replay(ref, nodes, pods, events, set(in_window), config["judge"])
     node_of = rep.pop("node_of")
 
     # every acknowledged create: bound (resident or retired), never lost
@@ -172,14 +167,13 @@ def judge(nodes, pods, book, n_offered: int, final_list: dict,
             mismatch += 1
     mismatch += sum(1 for pod in final_list
                     if pod < 0 or pod >= max(n_offered, book.n_created))
-    # the list at close, recomputed: nothing over its allocatable
-    used = reference.State(nodes)
-    for pod, node in final_list.items():
+    # the list at close, recomputed through the same State: every pod
+    # it holds asked again, in name order, whatever the record said
+    used = ref.State(nodes, pods)
+    at_close = {name: 0 for name in ref.GUARANTEES}
+    for pod, node in sorted(final_list.items()):
         if 0 <= node < nodes.n and 0 <= pod < len(pods):
-            used.add(node, int(pods.cpu[pod]), int(pods.mem[pod]))
-    over_at_close = int(((used.cnt > nodes.alloc_pods)
-                         | (used.cpu > nodes.alloc_cpu)
-                         | (used.mem > nodes.alloc_mem)).sum())
+            _bind(ref, used, at_close, pod, node)
 
     limits = config["limits"]
     numbers = {
@@ -190,8 +184,8 @@ def judge(nodes, pods, book, n_offered: int, final_list: dict,
         "never_bound": [never_bound, 0],
         "double_binds": [rep["double_binds"], 0],
         "unknown_node_binds": [rep["unknown_node_binds"], 0],
-        "selector_violations": [rep["selector_violations"], 0],
-        "over_allocatable": [rep["over_allocatable"] + over_at_close, 0],
+        **{name: [rep[name] + at_close[name], 0]
+           for name in ref.GUARANTEES},
         "list_mismatch": [mismatch + rep["deletes_of_unbound"], 0],
         "client_errors": [len(book.errors), 0],
         "engine_not_device": [int(account["mode"] != "device"

@@ -1,8 +1,12 @@
 #!/usr/bin/env python3
 """The plain reference, put in the program's place: a one-thread
-scheduler process over ``reference.py`` that lists the nodes, watches
-pods, answers each pending pod with the reference's first best node and
-binds it.  No JAX, nothing of the program.
+scheduler process over the configuration's reference (``reference.py`` or
+the ``references/<word>.py`` it names) that watches pods, answers each
+pending pod with the reference's first best node and binds it.  No JAX,
+nothing of the program.  What a pod or a node IS it takes from the
+configuration's shapes by the index in ``p-<i>`` / ``node-<i>`` (made
+from the configuration and ``--seed``, as the runner and the judge make
+them), not from a parser of its own: a new shape needs no edit here.
 
 It exists for two things.  The benchmark's own tests drive a whole run
 of ``run.py`` against it at a tiny size (``RefSut`` below takes the
@@ -21,6 +25,15 @@ where the answer is produced:
                                the neighbour of a best node is as good a
                                choice, so "moved to the next node" would
                                be no fault at all)
+  ``--fault colocate``         ONE answer, the second of the run, goes to
+                               the node the first went to (breaks a
+                               guarantee BETWEEN pods, such as a required
+                               anti-affinity, where a configuration's
+                               reference states one; two pause pods of
+                               today's cells may share a node, so there
+                               it is no fault, and it falls in the ramp,
+                               so only a number counted over every bind
+                               can see it)
 
 And it documents what "the reference" decides, executable end to end.
 """
@@ -41,72 +54,45 @@ sys.path.insert(0, HERE)
 
 import numpy as np  # noqa: E402
 
-import cluster  # noqa: E402
-import reference  # noqa: E402
 import rig  # noqa: E402
+import run  # noqa: E402
 
-FAULTS = ("none", "wrong_policy", "state_unchanged", "half_batch", "altered")
-
-
-def _quantity(text: str) -> int:
-    return int(text[:-1]) if text.endswith("m") else int(text)
-
-
-class _Fleet:
-    """``cluster.Nodes``-shaped arrays from the apiserver's node list."""
-
-    def __init__(self, items: list):
-        self.n = len(items)
-        order = sorted(items, key=lambda it: int(
-            it["metadata"]["name"].split("-")[1]))
-        alloc = [it["status"]["allocatable"] for it in order]
-        labels = [it["metadata"].get("labels", {}) for it in order]
-        self.alloc_cpu = np.array([_quantity(a["cpu"]) for a in alloc])
-        self.alloc_mem = np.array([_quantity(a["memory"]) for a in alloc])
-        self.alloc_pods = np.array([int(a["pods"]) for a in alloc])
-        self.pool = np.array([int(lab.get(cluster.POOL_LABEL, "x--1")
-                                  .split("-", 1)[1]) for lab in labels])
-        self.zone = np.array([int(lab.get(cluster.ZONE_LABEL, "x--1")
-                                  .split("-", 1)[1]) for lab in labels])
+FAULTS = ("none", "wrong_policy", "state_unchanged", "half_batch", "altered",
+          "colocate")
+NAMESPACE = "default"
 
 
-def _pod_args(obj: dict) -> tuple:
-    req = obj["spec"]["containers"][0]["resources"]["requests"]
-    sel = obj["spec"].get("nodeSelector", {}).get(cluster.POOL_LABEL)
-    aff = -1
-    note = obj["metadata"].get("annotations", {}).get(
-        cluster.AFFINITY_ANNOTATION_KEY)
-    if note:
-        term = json.loads(note)["nodeAffinity"][
-            "preferredDuringSchedulingIgnoredDuringExecution"][0]
-        aff = int(term["preference"]["matchExpressions"][0]["values"][0]
-                  .split("-")[1])
-    return (_quantity(req["cpu"]), _quantity(req["memory"]),
-            int(sel.split("-")[1]) if sel else -1, aff)
+def _index(name: str) -> int:
+    return int(name.split("-")[1])
 
 
-def serve(api_url: str, fault: str) -> None:
+def serve(api_url: str, fault: str, config: dict, seed: int) -> None:
     host, port = api_url.rsplit("/", 1)[-1].split(":")
-    with urllib.request.urlopen(api_url + "/api/v1/nodes") as r:
-        fleet = _Fleet(json.loads(r.read())["items"])
-    state = reference.State(fleet)
-    where: dict = {}                  # pod name -> (node, cpu, mem)
-    pending: list = []
+    shapes, reference = run.parts_of(config)
+    fleet = shapes.Nodes(config["nodes"], seed)
+    pods = shapes.Pods(config["pods"], seed, config["nodes"])
+    state = reference.State(fleet, pods)
+    where: dict = {}                  # pod index -> node
+    pending: list = []                # pod indices, oldest first
     lock = threading.Lock()
+
+    def known(name: str) -> int:
+        pod = _index(name)
+        pods.grow(pod + 1)
+        return pod
 
     # The apiserver replays only its last 1,024 events to a new watcher:
     # list what is pending first, then watch from the list's version.
     with urllib.request.urlopen(api_url + "/api/v1/pods") as r:
         listed = json.loads(r.read())
     for obj in listed["items"]:
-        name, args = obj["metadata"]["name"], _pod_args(obj)
+        pod = known(obj["metadata"]["name"])
         bound_to = obj["spec"].get("nodeName")
         if not bound_to:
-            pending.append((name, args))
+            pending.append(pod)
         else:                       # what runs there already
-            node = int(bound_to.split("-")[1])
-            where[name] = (node, args[0], args[1])
-            state.add(node, args[0], args[1])
+            where[pod] = _index(bound_to)
+            state.add(pod, where[pod])
     sock = socket.create_connection((host, int(port)))
     sock.sendall(b"GET /api/v1/pods?watch=1&resourceVersion=%d HTTP/1.1"
                  b"\r\nHost: ref\r\n\r\n"
@@ -127,19 +113,20 @@ def serve(api_url: str, fault: str) -> None:
                     continue
                 ev = json.loads(line)
                 obj = ev["object"]
-                name = obj["metadata"]["name"]
+                pod = known(obj["metadata"]["name"])
                 with lock:
                     if ev["type"] == "ADDED" and \
                             not obj["spec"].get("nodeName"):
-                        pending.append((name, _pod_args(obj)))
-                    elif ev["type"] == "DELETED" and name in where:
-                        node, cpu, mem = where.pop(name)
+                        pending.append(pod)
+                    elif ev["type"] == "DELETED" and pod in where:
+                        node = where.pop(pod)
                         if fault != "state_unchanged":
-                            state.add(node, cpu, mem, -1)
+                            state.add(pod, node, -1)
 
     threading.Thread(target=watch, daemon=True).start()
     bind = socket.create_connection((host, int(port)))
-    seen = 0
+    seen = answered = 0
+    first_node = None
     while True:
         with lock:
             batch, pending[:] = pending[:256], pending[256:]
@@ -147,30 +134,33 @@ def serve(api_url: str, fault: str) -> None:
             time.sleep(0.002)
             continue
         out = []
-        for name, (cpu, mem, sel, aff) in batch:
+        for pod in batch:
             seen += 1
             if fault == "half_batch" and seen % 2:
                 continue
             with lock:
                 if fault == "wrong_policy":
-                    ok = reference.fits(state, cpu, mem, sel)
-                    sc = np.where(ok, reference.scores(state, cpu, mem, aff),
-                                  1 << 30)
+                    ok = reference.fits(state, pod)
+                    sc = np.where(ok, reference.scores(state, pod), 1 << 30)
                     best = np.flatnonzero(sc == sc.min()) if ok.any() else []
                 else:
-                    best = reference.best_nodes(state, cpu, mem, sel, aff)
+                    best = reference.best_nodes(state, pod)
                 if not len(best):
-                    pending.append((name, (cpu, mem, sel, aff)))
+                    pending.append(pod)
                     continue
                 node = int(best[0])
                 if fault == "altered" and seen % 5 == 0:
-                    node = int(np.flatnonzero(
-                        reference.fits(state, cpu, mem, sel))[0])
+                    node = int(np.flatnonzero(reference.fits(state, pod))[0])
+                if answered == 0:
+                    first_node = node
+                elif answered == 1 and fault == "colocate":
+                    node = first_node
+                answered += 1
                 if fault != "state_unchanged":
-                    state.add(node, cpu, mem)
-                where[name] = (node, cpu, mem)
-            body = json.dumps({"metadata": {"name": name,
-                                            "namespace": cluster.NAMESPACE},
+                    state.add(pod, node)
+                where[pod] = node
+            body = json.dumps({"metadata": {"name": f"p-{pod}",
+                                            "namespace": NAMESPACE},
                                "target": {"kind": "Node",
                                           "name": f"node-{node}"}}).encode()
             out.append(b"POST /api/v1/namespaces/default/bindings HTTP/1.1"
@@ -190,11 +180,16 @@ class RefSut:
     engine to fall back from), no counters and no device."""
 
     def __init__(self, api_url: str, config: dict, platform: str,
-                 out_dir: str, fault: str = "none"):
+                 out_dir: str, fault: str = "none", seed: int = 0):
+        """``seed`` is the run's: the shapes are made from it."""
         self.platform = platform
+        config_path = os.path.join(out_dir, "refsched.config.json")
+        with open(config_path, "w") as f:
+            json.dump(config, f)
         self.child = rig.Child(
             "refsched", [sys.executable, os.path.abspath(__file__),
-                         "--api-server", api_url, "--fault", fault],
+                         "--api-server", api_url, "--fault", fault,
+                         "--config", config_path, "--seed", str(seed)],
             out_dir, env=dict(os.environ))
 
     def wait_prewarmed(self, timeout_s: float) -> float:
@@ -230,8 +225,11 @@ def main() -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--api-server", required=True)
     p.add_argument("--fault", choices=FAULTS, default="none")
+    p.add_argument("--config", required=True,
+                   help="the configuration as the run has it, a JSON file")
+    p.add_argument("--seed", type=int, required=True)
     opts = p.parse_args()
-    serve(opts.api_server, opts.fault)
+    serve(opts.api_server, opts.fault, run.load_json(opts.config), opts.seed)
     return 0
 
 

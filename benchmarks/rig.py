@@ -239,6 +239,8 @@ class Daemon:
         # key); a directory the caller already chose is kept.
         env.setdefault("JAX_COMPILATION_CACHE_DIR",
                        os.path.join(REPO, ".jax_cache"))
+        self.lists_resident = int(config.get("resident_cap", 0)) > 0
+        self.lists_s = None       # daemon start -> its first lists are in
         self.started = time.monotonic()
         self.child = Child(
             "daemon",
@@ -254,10 +256,36 @@ class Daemon:
     def metrics(self) -> dict:
         return parse_metrics(http_get(self.port, "/metrics").decode())
 
+    def lists_delivered(self) -> bool:
+        """Whether the daemon's reflectors have handed their first lists
+        (the nodes, and the resident pods where the configuration has
+        any) to the handlers, read off ``/metrics`` alone: each counts
+        its list's events once, when the whole list is in."""
+        m = self.metrics()
+        return all(
+            (family_sum(m, "scheduler_handler_events_total",
+                        {"handler": kind}) or 0) > 0
+            for kind in ("nodes",) + (("pods",) if self.lists_resident
+                                      else ()))
+
     def wait_prewarmed(self, timeout_s: float) -> float:
         """healthz comes up BEFORE prewarm finishes; pods created
         mid-prewarm would compile on the clock.  Returns seconds from
-        daemon start."""
+        daemon start.
+
+        ``/debug/vars`` is NOT asked while the daemon lists the cluster:
+        its ``cachedNodes`` goes through ``cache.nodes()``, which builds
+        the cache's node tensors from whatever part of the list is in,
+        and every node after that is appended to them row by row — the
+        list of 5,000 nodes and 30,000 pods then takes 10-22 s for 2-4,
+        runs past the reflectors' 10 s sync waits, and prewarm traces a
+        partial cluster whose programs no cache holds (PERF.md section
+        6, PR 29: the yardstick's own poll was what made ``setup_s`` of
+        the 5,000-node cell swing between 37 and 140 s)."""
+        wait_until("the first lists (scheduler_handler_events_total)",
+                   self.lists_delivered, self.child, min(timeout_s, 300),
+                   period_s=0.5)
+        self.lists_s = time.monotonic() - self.started
         wait_until("prewarm", lambda: self.vars()["prewarmCacheStats"],
                    self.child, timeout_s, period_s=0.5)
         return time.monotonic() - self.started

@@ -52,6 +52,7 @@ sys.path.insert(0, HERE)
 
 import cluster  # noqa: E402
 import judge as judging  # noqa: E402
+import reference  # noqa: E402
 import rig  # noqa: E402
 
 SETTLE_S = 3.0            # steady state has to hold this long before the window
@@ -79,6 +80,18 @@ def load_module(directory: str, name: str):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def parts_of(config: dict) -> tuple:
+    """``(shapes, reference)`` modules of a configuration: the files its
+    optional keys ``"shapes"`` / ``"reference"`` name
+    (``shapes/<word>.py``, ``references/<word>.py``; interfaces in
+    ``cluster.py``'s and ``reference.py``'s docstrings), else
+    ``cluster.py`` and ``reference.py``."""
+    return (load_module("shapes", config["shapes"])
+            if "shapes" in config else cluster,
+            load_module("references", config["reference"])
+            if "reference" in config else reference)
 
 
 class Cell:
@@ -137,20 +150,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
     config = cell.config
+    shapes, ref = parts_of(config)
     api = sut = traffic = None
     try:
         api = rig.ApiServer(out_dir)
-        nodes = cluster.Nodes(config["nodes"], seed)
+        nodes = shapes.Nodes(config["nodes"], seed)
         items = nodes.to_json()
         for i in range(0, len(items), 1000):
             chunk = items[i:i + 1000]
             api.post_list("nodes", json.dumps(
                 {"kind": "List", "items": chunk}).encode(), len(chunk))
-        pods = cluster.Pods(config["pods"], seed,
-                            n_pools=int(config["nodes"].get("n_pools", 4)),
-                            n_zones=int(config["nodes"].get("n_zones", 4)))
+        pods = shapes.Pods(config["pods"], seed, config["nodes"])
         t_fill = time.monotonic()
-        resident = prefill(api, nodes, pods, int(config["resident_cap"]))
+        resident = prefill(api, ref, nodes, pods, int(config["resident_cap"]))
         prefill_s = time.monotonic() - t_fill
         log(f"apiserver up, {nodes.n} nodes and {len(resident)} resident "
             f"pods created")
@@ -160,8 +172,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         pods.grow(200_000)            # while the daemon prewarms
         ready_s = sut.wait_prewarmed(1100)
         account = sut.account()
+        lists_s = getattr(sut, "lists_s", None)
         log(f"daemon ready in {ready_s:.1f} s on {account['platform']} "
-            f"{account['kind']} x{account['count']}")
+            f"{account['kind']} x{account['count']}"
+            + (f" (its first lists were in after {lists_s:.1f} s)"
+               if lists_s is not None else ""))
         if account["platform"] != platform or account["count"] < cell.chips:
             raise rig.RunFailure(
                 f"the daemon runs on {account['platform']!r} x"
@@ -292,7 +307,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     # -- judge (the program is gone; the reference runs alone) ---------------
     t_j = time.monotonic()
     correct, numbers, info = judging.judge(
-        nodes, pods, traffic.book, traffic.n_offered(), final_list,
+        ref, nodes, pods, traffic.book, traffic.n_offered(), final_list,
         (t_open, t_close), seed, config, account, platform)
     numbers["ramp_not_steady"] = [int(ramp_not_steady), 0]
     correct = correct and not ramp_not_steady
@@ -307,6 +322,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         "setup_s": setup_s,
         "client_busy_pct": _percent(cpu_close - cpu_open, t_closed - t_open),
         "daemon_ready_s": ready_s,
+        "daemon_lists_s": lists_s,
         "prefill_s": prefill_s,
         "ramp_s": t_open - traffic.t_start,
         "pending_at_close": float(pending_at_close),
@@ -314,7 +330,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if "gc_pause_s" in gc_pauses:     # the daemon's collector, window only
         runner["gc_pause_pct"] = _percent(gc_pauses["gc_pause_s"], seconds)
         runner["gc_pause_max_ms"] = gc_pauses["gc_pause_max_s"] * 1e3
-        runner["gc_full_per_s"] = gc_pauses["gc_full_collections"] / seconds
 
     result = {"correct": bool(correct), "attempted": attempted,
               "failed": failed, "metrics": {}, "device": {
@@ -381,28 +396,26 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     return result
 
 
-def prefill(api, nodes, pods, count: int) -> list:
+def prefill(api, ref, nodes, pods, count: int) -> list:
     """The cluster as the window finds it: pods ``0..count-1`` already
-    bound where the plain reference scheduler puts them, created with
-    their ``nodeName`` before the daemon starts.  The window's occupancy is
-    then level from its first second, and the pods the daemon has to place
-    in a run are the traffic's alone.  Returns each one's node."""
-    import reference
+    bound where the configuration's plain reference scheduler ``ref``
+    puts them, created with their ``nodeName`` before the daemon starts.
+    The window's occupancy is then level from its first second, and the
+    pods the daemon has to place in a run are the traffic's alone.
+    Returns each one's node."""
     pods.grow(count)
-    state = reference.State(nodes)
+    state = ref.State(nodes, pods)
     placed = []
     for start in range(0, count, 1000):
         stop = min(start + 1000, count)
         items = []
         for i in range(start, stop):
-            cpu, mem = int(pods.cpu[i]), int(pods.mem[i])
-            best = reference.best_nodes(state, cpu, mem, int(pods.sel[i]),
-                                        int(pods.aff[i]))
+            best = ref.best_nodes(state, i)
             if not len(best):
                 raise rig.RunFailure(f"resident pod {i} fits nowhere")
             # spread over the tied best nodes, as upstream's round robin does
             node = int(best[i % len(best)])
-            state.add(node, cpu, mem)
+            state.add(i, node)
             placed.append(node)
             items.append(pods.json_bytes(i)[:-2]
                          + b',"nodeName":"node-%d"}}' % node)

@@ -94,7 +94,7 @@ DRIVE = """
 import functools, json, sys
 import run, rig, refsched
 cell = run.Cell(run.load_json(rig.REPO + "/BENCHMARK.json"), {cell!r})
-make = functools.partial(refsched.RefSut, fault={fault!r}) if {fault!r} else None
+make = functools.partial(refsched.RefSut, fault={fault!r}, seed={seed}) if {fault!r} else None
 flags = {flags!r}
 cell.config["daemon"]["flags"] += flags
 run.RAMP_TIMEOUT_S = 20.0      # a test does not wait 150 s for a ramp that never settles

@@ -16,7 +16,10 @@ With ``--fault <name>``: the plain reference scheduler in the daemon's
 place (``refsched.py``; it needs no chip but runs where the cell runs, at
 the cell's size) with that fault planted where the answer is produced:
 ``none`` (sound: the judge's lower reading from a second system),
-``wrong_policy``, ``state_unchanged``, ``half_batch``, ``altered``.
+``wrong_policy``, ``state_unchanged``, ``half_batch``, ``altered``,
+``colocate`` (``refsched.py`` says what each breaks; it drives the cell's
+configuration through its own shapes and reference files, so a fault can
+be planted against a new configuration's guarantee).
 
 Prints the numbers compared, each beside its limit, and exits 0 when the
 control failed at least one of them (1 when it passed for correct; with
@@ -50,7 +53,8 @@ def main() -> int:
                     opts.workload)
     make_sut = None
     if opts.fault:
-        make_sut = functools.partial(refsched.RefSut, fault=opts.fault)
+        make_sut = functools.partial(refsched.RefSut, fault=opts.fault,
+                                     seed=opts.seed)
     else:
         cell.config["daemon"]["flags"] = \
             cell.config["daemon"]["flags"] + CONTROL_FLAGS

@@ -20,16 +20,15 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import cluster  # noqa: E402
 import judge  # noqa: E402
+import run  # noqa: E402
 
 
 def rejudge(config: dict, seed: int, path: str, platform: str = "tpu"):
     rec = np.load(path)
-    nodes = cluster.Nodes(config["nodes"], seed)
-    pods = cluster.Pods(config["pods"], seed,
-                        n_pools=int(config["nodes"].get("n_pools", 4)),
-                        n_zones=int(config["nodes"].get("n_zones", 4)))
+    shapes, ref = run.parts_of(config)
+    nodes = shapes.Nodes(config["nodes"], seed)
+    pods = shapes.Pods(config["pods"], seed, config["nodes"])
     pods.grow(int(rec["n_offered"]) + 1)
     book = types.SimpleNamespace(
         events=list(zip(rec["kind"].tolist(), rec["pod"].tolist(),
@@ -37,7 +36,7 @@ def rejudge(config: dict, seed: int, path: str, platform: str = "tpu"):
         n_created=int(rec["n_created"]),
         errors=["client error"] * int(rec["n_errors"]))
     final_list = {int(p): int(n) for p, n in rec["listed"]}
-    return judge.judge(nodes, pods, book, int(rec["n_offered"]), final_list,
+    return judge.judge(ref, nodes, pods, book, int(rec["n_offered"]), final_list,
                        tuple(rec["window"]), seed, config,
                        json.loads(str(rec["account"])), platform)
 

@@ -48,6 +48,12 @@ def test_configs_and_cells():
         for key in ("guarantees", "daemon", "nodes", "pods", "resident_cap",
                     "judge", "limits", "assumed"):
             assert key in body, (c["name"], key)
+        # the optional plug points name files of their own directories
+        for key, directory in (("shapes", "shapes"),
+                               ("reference", "references")):
+            if key in body:
+                assert NAME.match(body[key]) and os.path.exists(os.path.join(
+                    BENCH, directory, body[key] + ".py")), (c["name"], key)
     cells = b["workloads"]
     assert 1 <= len(cells) <= 24
     assert len({w["name"] for w in cells}) == len(cells)

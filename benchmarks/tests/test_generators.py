@@ -74,6 +74,8 @@ g.first_pod = 0
 g.due = [10.0 + 0.1 * i for i in range(100)]       # 10.0 .. 19.9
 g.sent = [d + 0.001 for d in g.due[:60]]           # the last 40 never written
 g.t_stopped = 21.0
+g.stall_s = {"send": 0.25, "extend": 0.03, "pass": 0.26}
+g.pauses = 2
 g.t_open, g.t_close = 12.0, 18.0                   # pods 20 .. 79 are due
 g.book = loadgen.Book()
 g.book.bind_t = {p: g.due[p] + 0.05 for p in range(60)}
@@ -94,3 +96,8 @@ def test_a_due_pod_that_was_never_written_counts_as_offered_and_unbound(
     assert abs(rep["submit_to_bind_p50_ms"] - 50.0) < 1e-6   # 40 of 60 bound
     assert rep["submit_to_bind_p95_ms"] > 1000     # the unbound wait still
     assert rep["late_ms_p99"] > 1000               # and the unwritten are late
+    assert rep["late_ms_max"] >= rep["late_ms_p99"]
+    # where the generator itself stalled, for the run's info.json
+    assert (rep["creator_send_max_ms"], rep["creator_extend_max_ms"],
+            rep["creator_pass_max_ms"]) == (250.0, 30.0, 260.0)
+    assert rep["creator_pauses"] == 2.0

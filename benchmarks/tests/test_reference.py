@@ -1,5 +1,7 @@
 """The plain reference against hand-worked cases of upstream's integer
-arithmetic."""
+arithmetic (its functions of a pod's four attributes; the by-index
+interface over them is held to the same cases in
+``test_plug_points.py``)."""
 
 import numpy as np
 
@@ -14,9 +16,9 @@ def _fleet():
 
 def test_least_requested_and_balanced_integers():
     state = reference.State(_fleet())
-    state.add(0, 2000, 4 * 1024 ** 3)       # half full both ways
-    state.add(1, 3000, 1 * 1024 ** 3)       # lopsided
-    sc = reference.scores(state, 1000, 1024 ** 3, -1)
+    state.use(0, 2000, 4 * 1024 ** 3)       # half full both ways
+    state.use(1, 3000, 1 * 1024 ** 3)       # lopsided
+    sc = reference.score_points(state, 1000, 1024 ** 3, -1)
     # node 0: cpu (4000-3000)*10//4000 = 2, mem (8-5)*10//8 = 3 -> 2;
     #         balanced 10 - |0.75-0.625|*10 = 8.75 -> 8
     assert sc[0] == 2 + 8
@@ -30,28 +32,28 @@ def test_fit_counts_pods_cpu_memory_and_selector():
     nodes = _fleet()
     nodes.pool = np.array([0, 1, 0, 1])
     state = reference.State(nodes)
-    state.add(0, 100, 100)
-    state.add(0, 100, 100)                  # node 0 holds its 2 pods
-    state.add(2, 3950, 100)                 # node 2 has 50m left
-    assert reference.fits(state, 100, 100, -1).tolist() == \
+    state.use(0, 100, 100)
+    state.use(0, 100, 100)                  # node 0 holds its 2 pods
+    state.use(2, 3950, 100)                 # node 2 has 50m left
+    assert reference.fit_mask(state, 100, 100, -1).tolist() == \
         [False, True, False, True]
-    assert reference.fits(state, 50, 100, 0).tolist() == \
+    assert reference.fit_mask(state, 50, 100, 0).tolist() == \
         [False, False, True, False]
-    assert reference.best_nodes(state, 100, 100, 0, -1).size == 0
+    assert reference.best_of(state, 100, 100, 0, -1).size == 0
 
 
 def test_gap_is_zero_on_a_best_node_and_inf_where_nothing_fits():
     state = reference.State(_fleet())
-    state.add(0, 2000, 4 * 1024 ** 3)
-    best = reference.best_nodes(state, 1000, 1024 ** 3, -1, -1)
+    state.use(0, 2000, 4 * 1024 ** 3)
+    best = reference.best_of(state, 1000, 1024 ** 3, -1, -1)
     assert best.tolist() == [1, 2, 3]
-    assert reference.score_gap(state, 1000, 1024 ** 3, -1, -1, 2) == 0
-    assert reference.score_gap(state, 1000, 1024 ** 3, -1, -1, 0) == 5
-    assert reference.score_gap(state, 5000, 1, -1, -1, 1) == float("inf")
+    assert reference.gap_of(state, 1000, 1024 ** 3, -1, -1, 2) == 0
+    assert reference.gap_of(state, 1000, 1024 ** 3, -1, -1, 0) == 5
+    assert reference.gap_of(state, 5000, 1, -1, -1, 1) == float("inf")
 
 
 def test_zone_preference_adds_ten():
     nodes = _fleet()
     nodes.zone = np.array([0, 1, 2, 3])
     state = reference.State(nodes)
-    assert reference.best_nodes(state, 100, 100, -1, 2).tolist() == [2]
+    assert reference.best_of(state, 100, 100, -1, 2).tolist() == [2]
