@@ -22,6 +22,7 @@ from dataclasses import dataclass
 from typing import TYPE_CHECKING, Callable, Optional
 
 from kubernetes_tpu.api import types as api
+from kubernetes_tpu.features import affinity as fa
 from kubernetes_tpu.features import compiler as fc
 from kubernetes_tpu.utils import locktrace, metrics, threadreg, trace
 
@@ -125,6 +126,11 @@ class SchedulerCache:
         self._nt: Optional[fc.NodeTensors] = None
         self._agg: Optional[fc.NodeAggregates] = None
         self._ep: Optional[fc.ExistingPodTensors] = None
+        # The resident side of the inter-pod affinity tables, kept between
+        # launches: updated per attach / detach beside the aggregates,
+        # rebuilt from the attached pods when the node rows or their
+        # labels change (affinity_tables()).
+        self._aff = fa.ResidentAffinity(self._attached_pods)
         self._dirty_nodes = True
         self.generation = 0
         # Device-residency protocol: ``tensor_epoch`` bumps whenever row
@@ -146,7 +152,8 @@ class SchedulerCache:
 
     @_locked
     def add_node(self, node: api.Node) -> None:
-        known = node.name in self._nodes
+        old = self._nodes.get(node.name)
+        known = old is not None
         self._nodes[node.name] = node
         if node.name not in self._node_pods:
             self._node_pods[node.name] = {}
@@ -156,6 +163,8 @@ class SchedulerCache:
             # Duplicate ADDED (relist Replace): treat as update in place.
             idx = self._nt.name_to_idx[node.name]
             fc.update_node_row(self._nt, idx, node, self.space)
+            if old.labels != node.labels:
+                self._aff.invalidate()
             self._dirty_rows.add(idx)
             self.stats["incremental_node_updates"] += 1
             self.generation += 1
@@ -165,6 +174,7 @@ class SchedulerCache:
             # Capacity growth: the device mirror re-uploads (epoch bump).
             fc.append_node_row(self._nt, node, self.space)
             fc.append_aggregate_row(self._agg)
+            self._aff.invalidate()
             self._node_order.append(node.name)
             self.tensor_epoch += 1
             self.stats["incremental_node_updates"] += 1
@@ -172,6 +182,7 @@ class SchedulerCache:
 
     @_locked
     def update_node(self, node: api.Node) -> None:
+        old = self._nodes.get(node.name)
         self._nodes[node.name] = node
         if node.name not in self._node_pods:
             self._node_pods[node.name] = {}
@@ -188,6 +199,8 @@ class SchedulerCache:
             # snapshot + feature compile + the device transfer; after the
             # transfer the solver reads device copies, not these arrays.
             fc.update_node_row(self._nt, idx, node, self.space)
+            if old is None or old.labels != node.labels:
+                self._aff.invalidate()
             self._dirty_rows.add(idx)
             self.stats["incremental_node_updates"] += 1
             self.generation += 1
@@ -287,6 +300,8 @@ class SchedulerCache:
                     self._agg, idxs, pods, self.space)
             self._ep = fc.existing_pods_add_bulk(
                 self._ep, pods, idxs, self.space)
+            for pod, idx in zip(pods, idxs):
+                self._aff.add_pod(pod, idx)
             self._dirty_rows.update(idxs)
         self.generation += len(assignments)
         return skipped
@@ -401,6 +416,11 @@ class SchedulerCache:
         return len(self._pod_states)
 
     @_locked
+    def node_count(self) -> int:
+        """Nodes tracked, without building the node tensors."""
+        return len(self._nodes)
+
+    @_locked
     def nodes(self) -> list[api.Node]:
         self._ensure_tensors()
         return [self._nodes[n] for n in self._node_order]
@@ -447,6 +467,28 @@ class SchedulerCache:
         return [(p, self._nt.name_to_idx.get(p.node_name, -1))
                 for p in self._affinity_pods.values()]
 
+    @_locked
+    def affinity_tables(self) -> fa.ResidentAffinity:
+        """The kept resident side of the affinity tables, for
+        ``compile_affinity(..., resident=)`` in the locked section of
+        ``snapshot()``."""
+        self._ensure_tensors()
+        aff = self._aff
+        metrics.AFFINITY_RESIDENT_PODS.set(len(self._affinity_pods))
+        for family, planes in (("match", aff.match), ("decl", aff.decl),
+                               ("sym", aff.sym)):
+            metrics.AFFINITY_SIGNATURES.labels(family=family).set(
+                len(planes.rows))
+        return aff
+
+    def _attached_pods(self):
+        """(pod, node row) of every attached pod on a known node."""
+        for name, podmap in self._node_pods.items():
+            idx = self._nt.name_to_idx.get(name)
+            if idx is not None:
+                for pod in podmap.values():
+                    yield pod, idx
+
     # ---- tensor maintenance -------------------------------------------
 
     def _attach(self, pod: api.Pod, node_name: str) -> None:
@@ -465,6 +507,7 @@ class SchedulerCache:
                 return
             self._agg = fc.add_pod_to_aggregates(self._agg, idx, pod, self.space)
             self._ep = fc.existing_pods_add(self._ep, pod, idx, self.space)
+            self._aff.add_pod(pod, idx)
             self._dirty_rows.add(idx)
         self.generation += 1
 
@@ -482,6 +525,7 @@ class SchedulerCache:
                 self._agg = fc.remove_pod_from_aggregates(
                     self._agg, idx, pod, self.space, list(pods.values()))
                 self._ep = fc.existing_pods_remove(self._ep, pod.key)
+                self._aff.remove_pod(pod, idx)
                 self._dirty_rows.add(idx)
         self.generation += 1
 
@@ -497,20 +541,15 @@ class SchedulerCache:
         # Re-attach every tracked pod through the BULK paths: the per-pod
         # loop is O(pods x numpy-call overhead) — tens of seconds at 30k
         # attached pods, per node event, before this.
-        idxs: list[int] = []
-        pods: list[api.Pod] = []
-        for name, podmap in self._node_pods.items():
-            idx = self._nt.name_to_idx.get(name)
-            if idx is None:
-                continue
-            for pod in podmap.values():
-                idxs.append(idx)
-                pods.append(pod)
-        if pods:
+        attached = list(self._attached_pods())
+        if attached:
+            pods = [pod for pod, _ in attached]
+            idxs = [idx for _, idx in attached]
             self._agg = fc.add_pods_to_aggregates_bulk(
                 self._agg, idxs, pods, self.space)
             self._ep = fc.existing_pods_add_bulk(
                 self._ep, pods, idxs, self.space)
+        self._aff.invalidate()
         self._dirty_nodes = False
         # Relist/rebuild: row identity moved — the device mirror must
         # re-upload; any pending per-row deltas are subsumed.
@@ -641,19 +680,36 @@ class SchedulerCache:
         verifier diffs them against the live ``_agg`` rows."""
         self._ensure_tensors()
         agg = fc.empty_aggregates(len(self._node_order), self.space)
-        idxs: list[int] = []
-        pods: list[api.Pod] = []
-        for name, podmap in self._node_pods.items():
-            idx = self._nt.name_to_idx.get(name)
-            if idx is None:
-                continue
-            for pod in podmap.values():
-                idxs.append(idx)
-                pods.append(pod)
-        if pods:
-            agg = fc.add_pods_to_aggregates_bulk(agg, idxs, pods,
-                                                 self.space)
+        attached = list(self._attached_pods())
+        if attached:
+            agg = fc.add_pods_to_aggregates_bulk(
+                agg, [idx for _, idx in attached],
+                [pod for pod, _ in attached], self.space)
         return agg.requested, agg.nonzero
+
+    def affinity_planes_drift(self) -> list[str]:
+        """The kept affinity planes against a build from nothing out of
+        the attached pods (the verifier's ground truth, as
+        ``recompute_aggregates`` is for the aggregates): a description
+        per signature whose plane differs, [] when they agree or when
+        nothing is kept yet.  Under the lock it only copies the kept
+        rows and lists the attached pods; the build and the comparison
+        run outside it, and no cache state is touched."""
+        with self.lock:
+            kept = self._aff
+            if self._dirty_nodes or self._nt is None or not kept.valid:
+                return []
+            have = kept.planes()
+            fresh = kept.twin()
+            attached = list(self._attached_pods())
+        fresh.fill(attached)
+        want = fresh.planes()
+        out = []
+        for key in have.keys() | want.keys():
+            a, b = have.get(key), want.get(key)
+            if a is None or b is None or a[1] != b[1] or (a[0] != b[0]).any():
+                out.append(f"{key[0]} plane of {key[1]}")
+        return out
 
     @_locked
     def take_dirty_rows(self) -> set[int]:

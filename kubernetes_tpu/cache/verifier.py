@@ -12,7 +12,10 @@ instead of a wrong placement: a low-frequency background pass
 cross-checks
 
 * ``aggregates`` — the live aggregate rows vs a from-scratch recompute
-  out of the tracked pod set (the delta pipeline's ground truth);
+  out of the tracked pod set (the delta pipeline's ground truth), and
+  ``affinity_planes`` — the kept inter-pod affinity planes
+  (``features/affinity.py ResidentAffinity``) vs a build from nothing
+  out of the same pods;
 * ``device_row`` — a sampled row set read back from the device-resident
   tensors vs the host arrays, valid only when the mirror claims to be in
   sync (same epoch + shape signature) and the rows carry no pending
@@ -54,7 +57,7 @@ APISERVER_GRACE_S = 0.5
 
 @dataclass
 class Violation:
-    kind: str      # aggregates | device_row | apiserver | defrag
+    kind: str      # aggregates | affinity_planes | device_row | apiserver | defrag
     detail: str
 
     def __str__(self) -> str:  # pragma: no cover — logging sugar
@@ -111,6 +114,14 @@ class Verifier:
                     "aggregates",
                     f"{name} rows diverged from recompute at "
                     f"{len(bad)}+ node(s), e.g. {nodes}"))
+            # the kept inter-pod affinity planes ride the same attach /
+            # detach deltas: same ground truth, same lock
+            drift = self.cache.affinity_planes_drift()
+            if drift:
+                out.append(Violation(
+                    "affinity_planes",
+                    f"{len(drift)} kept plane(s) diverged from a build "
+                    f"from nothing, e.g. {drift[:3]}"))
         return out
 
     def _check_device_rows(self) -> list[Violation]:

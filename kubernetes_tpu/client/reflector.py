@@ -82,6 +82,10 @@ class Reflector:
 
     _DISPATCH_FLUSH_EVERY = 256
 
+    def knows(self, key: str) -> bool:
+        """Whether ``key`` is in the watched set as last delivered."""
+        return key in self._known
+
     # Back-compat alias (round-1 callers constructed with store=).
     @property
     def store(self):

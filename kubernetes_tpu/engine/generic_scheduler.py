@@ -236,7 +236,7 @@ class GenericScheduler:
                     pods, nt, self.cache.space, ep=ep, nodes=nodes,
                     spread_selectors=self.listers.spread_selectors,
                     controller_refs=self.listers.controller_refs,
-                    affinity_pods=self.cache.affinity_pods(),
+                    resident_affinity=self.cache.affinity_tables(),
                     hard_pod_affinity_weight=(
                         self.policy.hard_pod_affinity_symmetric_weight),
                     volsvc=volsvc)

@@ -35,13 +35,14 @@ failure-domain keys.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import NamedTuple, Optional, Sequence
+from typing import Callable, Iterable, NamedTuple, Optional, Sequence
 
 import numpy as np
 
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.features import compiler as fc
 from kubernetes_tpu.features.padcap import pad1 as _pad1, pow2 as _pow2
+from kubernetes_tpu.utils import metrics
 
 # Resolved namespace marker: () after resolution means "all namespaces".
 _ALL_NS = ()
@@ -251,6 +252,291 @@ def pod_has_affinity(pod: api.Pod) -> bool:
     return pod.affinity() is not None
 
 
+def _sig_order(sig: Sig) -> tuple:
+    """A total order on signatures (a nil selector sorts first), so that
+    the resident pods' decl / sym rows come out in one order whatever
+    order the pods were seen in."""
+    return (sig.namespaces, sig.selector is not None,
+            sig.selector or ((), ()), sig.key, sig.weight)
+
+
+def _term_sig(term: api.PodAffinityTerm, owner: api.Pod,
+              weight: int = 0) -> Sig:
+    return Sig(_resolve_ns(term, owner), _sel_sig(term.label_selector),
+               term.topology_key, weight)
+
+
+def _declared_sigs(pod: api.Pod, hard_pod_affinity_weight: int
+                   ) -> tuple[list[Sig], list[Sig]]:
+    """``(decl, sym)`` signatures a pod DECLARES toward other pods once it
+    is placed: its required anti-affinity terms, and one sym instance per
+    scored term (required affinity x hardPodAffinityWeight, preferred
+    affinity +w, preferred anti-affinity -w)."""
+    req_a, req_aa, pref_a, pref_aa = _pod_terms(pod)
+    decl = [_term_sig(t, pod) for t in req_aa]
+    sym = []
+    if hard_pod_affinity_weight > 0:
+        sym += [_term_sig(t, pod, hard_pod_affinity_weight) for t in req_a]
+    sym += [_term_sig(wt.pod_affinity_term, pod, wt.weight)
+            for wt in pref_a if wt.weight != 0]
+    sym += [_term_sig(wt.pod_affinity_term, pod, -wt.weight)
+            for wt in pref_aa if wt.weight != 0]
+    return decl, sym
+
+
+class _Planes:
+    """One signature family's resident planes: ``cnt[row]`` is an [N]
+    int32 count per node (pods whose topology reaches the node) and
+    ``total[row]`` the pods counted, for the signature ``rows`` maps to
+    ``row``.  Rows are reused; the arrays double when they run out."""
+
+    def __init__(self, n: int):
+        self.rows: dict[Sig, int] = {}
+        self.cnt = np.zeros((4, n), np.int32)
+        self.total = np.zeros(4, np.int64)
+        self._free = [3, 2, 1, 0]
+
+    def row(self, sig: Sig) -> int:
+        """The signature's row, a new all-zero one when it has none."""
+        r = self.rows.get(sig)
+        if r is None:
+            if not self._free:
+                have = len(self.total)
+                self.cnt = np.concatenate([self.cnt, np.zeros_like(self.cnt)])
+                self.total = np.concatenate(
+                    [self.total, np.zeros_like(self.total)])
+                self._free = list(range(2 * have - 1, have - 1, -1))
+            r = self.rows[sig] = self._free.pop()
+        return r
+
+    def drop(self, sig: Sig) -> None:
+        r = self.rows.pop(sig)
+        self.cnt[r] = 0
+        self.total[r] = 0
+        self._free.append(r)
+
+
+class ResidentAffinity:
+    """The resident side of the affinity tables, kept between launches.
+
+    What depends on the cluster and its bound (or assumed) pods only —
+    ``node_dom`` per topology key, and per signature the [S, N] planes
+    behind ``match_cnt`` / ``match_total``, ``decl_reach`` (as counts, so
+    that a pod can be taken out again) and ``sym_cnt`` — is maintained
+    where the cache maintains its aggregates: ``add_pod`` / ``remove_pod``
+    per attached pod, ``invalidate`` when the node rows or their labels
+    change (the next launch then builds them again from ``attached()``,
+    the cache's ``(pod, node index)`` of every pod on a known node).
+    ``compile_affinity(..., resident=self)`` builds a launch's tables
+    from these planes and the batch's own incidence rows; it equals the
+    from-nothing build to the element.
+
+    ``decl`` and ``sym`` signatures exist while a resident pod declares
+    them.  ``match`` signatures are registered by the first batch that
+    carries them (one vectorized pass over the resident pods) and kept
+    until the planes are built again, so the next batch of the same
+    controller finds its row.
+
+    Counted in ``scheduler_affinity_table_rebuilds_total`` (a build from
+    nothing, or a new match signature's pass) and
+    ``scheduler_affinity_table_row_updates_total`` (one resident pod added
+    or taken out).  Not thread-safe: every call on the cache's instance
+    is made under the cache lock (a ``twin`` belongs to its caller).
+    """
+
+    def __init__(self, attached: Callable[[], Iterable[tuple[api.Pod, int]]],
+                 counted: bool = True):
+        self._attached = attached
+        self._counted = counted       # False: the verifier's throwaway copy
+        self.valid = False
+        self._reset((), 0, 1)
+
+    def planes(self) -> dict[tuple[str, Sig], tuple[np.ndarray, int]]:
+        """``{(family, signature): ([N] counts, total)}``, copied."""
+        return {(family, sig): (p.cnt[r].copy(), int(p.total[r]))
+                for family, p in (("match", self.match), ("decl", self.decl),
+                                  ("sym", self.sym))
+                for sig, r in p.rows.items()}
+
+    def twin(self) -> "ResidentAffinity":
+        """An empty, uncounted instance for the same node rows, weight
+        and match signatures; ``fill``ed with the attached pods it is
+        what the kept planes must equal (the verifier's ground truth)."""
+        fresh = ResidentAffinity(self._attached, counted=False)
+        fresh._reset(self._nodes, self.n, self.hard_weight)
+        fresh.valid = True
+        for sig in self.match.rows:
+            fresh.match.row(sig)
+        return fresh
+
+    def fill(self, attached: Iterable[tuple[api.Pod, int]]) -> None:
+        """Add every ``(pod, node row)``, uncounted as row updates."""
+        for pod, nidx in attached:
+            self._add(pod, nidx, 1)
+
+    def _reset(self, nodes: Sequence[api.Node], n: int,
+               hard_pod_affinity_weight: int) -> None:
+        self._nodes = nodes
+        self.n = n
+        self.hard_weight = hard_pod_affinity_weight
+        self._dom: dict[str, np.ndarray] = {}
+        self._own_domain: set[str] = set()
+        self._declared_memo: dict = {}
+        self.match, self.decl, self.sym = _Planes(n), _Planes(n), _Planes(n)
+        self._match_memo: dict = {}
+
+    def invalidate(self) -> None:
+        """The node rows or their labels changed: the next launch builds
+        from nothing."""
+        self.valid = False
+
+    def ensure(self, nodes: Sequence[api.Node], n: int,
+               hard_pod_affinity_weight: int) -> None:
+        """Valid planes for these node rows and this weight: kept ones,
+        or a build from nothing over ``attached()``.  Match signatures
+        re-register as batches ask for them."""
+        if self.valid and self.n == n and \
+                self.hard_weight == hard_pod_affinity_weight:
+            return
+        self._reset(nodes, n, hard_pod_affinity_weight)
+        self.valid = True
+        if self._counted:
+            metrics.AFFINITY_TABLE_REBUILDS.inc()
+        self.fill(self._attached())
+
+    # -- topology ---------------------------------------------------------
+
+    def dom_row(self, key: str) -> np.ndarray:
+        """[N] int32 domain ids of one topology key, -1 where the node
+        lacks the label; ids in order of first appearance, as
+        ``_DomainTable.build`` numbers them."""
+        d = self._dom.get(key)
+        if d is None:
+            d = np.full(self.n, -1, np.int32)
+            vals: dict[str, int] = {}
+            for i, node in enumerate(self._nodes):
+                v = node.labels.get(key)
+                if v:
+                    d[i] = vals.setdefault(v, len(vals))
+            self._dom[key] = d
+            if len(vals) == int((d >= 0).sum()):
+                self._own_domain.add(key)     # the hostname's shape
+        return d
+
+    def _bump(self, row: np.ndarray, key: str, nidx: int, sign: int) -> None:
+        """``row[j] += sign`` for every node ``j`` sharing ``nidx``'s
+        topology under ``key`` ("" = any default failure domain):
+        ``_DomainTable.same_topo_row``, added in place."""
+        if not key:
+            reach = np.zeros(self.n, bool)
+            for k in api.DEFAULT_FAILURE_DOMAINS:
+                d = self.dom_row(k)
+                reach |= (d == d[nidx]) & (d >= 0)
+            row[reach] += sign
+            return
+        d = self.dom_row(key)
+        dom = d[nidx]
+        if dom < 0:
+            return
+        if key in self._own_domain:       # every node a domain of its own
+            row[nidx] += sign
+        else:
+            row[d == dom] += sign
+
+    # -- resident pods ------------------------------------------------------
+
+    def _matched(self, pod: api.Pod) -> tuple:
+        """Registered match signatures the pod's namespace and labels
+        satisfy, memoized by that template."""
+        tkey = (pod.namespace, tuple(sorted(pod.labels.items())))
+        got = self._match_memo.get(tkey)
+        if got is None:
+            if len(self._match_memo) >= 4096:
+                self._match_memo.clear()
+            got = self._match_memo[tkey] = tuple(
+                sig for sig in self.match.rows
+                if _pod_matches_sig(sig, pod.namespace, pod.labels))
+        return got
+
+    def add_pod(self, pod: api.Pod, nidx: int) -> None:
+        """One pod attached to node row ``nidx``."""
+        if self.valid and self._add(pod, nidx, 1):
+            metrics.AFFINITY_TABLE_ROW_UPDATES.inc()
+
+    def remove_pod(self, pod: api.Pod, nidx: int) -> None:
+        if self.valid and self._add(pod, nidx, -1):
+            metrics.AFFINITY_TABLE_ROW_UPDATES.inc()
+
+    def _declared(self, pod: api.Pod) -> tuple[list[Sig], list[Sig]]:
+        """``_declared_sigs``, memoized by the annotation's text and the
+        namespace: the pods of a controller carry one text."""
+        raw = pod.annotations.get(api.AFFINITY_ANNOTATION_KEY)
+        if not raw:
+            return _declared_sigs(pod, self.hard_weight)
+        got = self._declared_memo.get((raw, pod.namespace))
+        if got is None:
+            if len(self._declared_memo) >= 4096:
+                self._declared_memo.clear()
+            got = self._declared_memo[(raw, pod.namespace)] = \
+                _declared_sigs(pod, self.hard_weight)
+        return got
+
+    def _add(self, pod: api.Pod, nidx: int, sign: int) -> bool:
+        """Whether any plane changed."""
+        if not 0 <= nidx < self.n:
+            return False
+        touched = False
+        if self.match.rows:
+            for sig in self._matched(pod):
+                r = self.match.rows[sig]
+                self._bump(self.match.cnt[r], sig.key, nidx, sign)
+                self.match.total[r] += sign
+                touched = True
+        if pod.affinity() is not None:
+            decl, sym = self._declared(pod)
+            for planes, sigs in ((self.decl, decl), (self.sym, sym)):
+                for sig in sigs:
+                    r = planes.row(sig)
+                    self._bump(planes.cnt[r], sig.key, nidx, sign)
+                    planes.total[r] += sign
+                    if planes.total[r] == 0:
+                        planes.drop(sig)
+                    touched = True
+        return touched
+
+    # -- what a launch reads ------------------------------------------------
+
+    def declared(self) -> tuple[list[Sig], list[Sig]]:
+        """``(decl, sym)`` signatures some resident pod declares, in
+        ``_sig_order``."""
+        return (sorted(self.decl.rows, key=_sig_order),
+                sorted(self.sym.rows, key=_sig_order))
+
+    def match_row(self, sig: Sig, ep: fc.ExistingPodTensors,
+                  space: fc.FeatureSpace) -> tuple[np.ndarray, int]:
+        """``([N] counts, total)`` of a match signature; a signature not
+        seen before is registered by one pass over the resident pods."""
+        r = self.match.rows.get(sig)
+        if r is None:
+            r = self.match.row(sig)
+            self._match_memo.clear()
+            if self._counted:
+                metrics.AFFINITY_TABLE_REBUILDS.inc()
+            nidxs = ep.node_idx[_sig_match_existing(sig, ep, space)]
+            self.match.total[r] = len(nidxs)
+            if sig.key:
+                # per-domain counts, gathered back to the nodes
+                d = self.dom_row(sig.key)
+                doms = d[nidxs]
+                per_dom = np.bincount(doms[doms >= 0],
+                                      minlength=max(int(d.max(initial=-1)) + 1, 1))
+                self.match.cnt[r] = np.where(d >= 0, per_dom[d], 0)
+            else:
+                for ni in nidxs.tolist():
+                    self._bump(self.match.cnt[r], "", ni, 1)
+        return self.match.cnt[r], int(self.match.total[r])
+
+
 def compile_affinity(pods: Sequence[api.Pod],
                      affinity_pods: Sequence[tuple[api.Pod, int]],
                      ep: Optional[fc.ExistingPodTensors],
@@ -259,7 +545,9 @@ def compile_affinity(pods: Sequence[api.Pod],
                      space: fc.FeatureSpace,
                      hard_pod_affinity_weight: int = 1,
                      reps: Optional[Sequence[api.Pod]] = None,
-                     tpl_idx: Optional[np.ndarray] = None) -> AffinityTensors:
+                     tpl_idx: Optional[np.ndarray] = None,
+                     resident: Optional[ResidentAffinity] = None
+                     ) -> AffinityTensors:
     """Build the batch's affinity tables.
 
     ``affinity_pods``: (existing pod, node index) for every assigned pod with
@@ -270,6 +558,12 @@ def compile_affinity(pods: Sequence[api.Pod],
     ``reps``/``tpl_idx``: template dedup from compile_batch — per-pod
     incidence rows are built once per spec-identical template and gathered
     back to the full pod axis.
+    ``resident``: the cache's kept planes (``ResidentAffinity``).  With it the resident side — the
+    signatures resident pods declare, ``node_dom``, ``match_cnt`` /
+    ``match_total``, ``decl_reach``, ``sym_cnt`` — is read off the planes
+    and ``affinity_pods`` is not looked at; without it that side is built
+    here from nothing, pod by pod.  The two give the same tables to the
+    element.
     """
     if reps is not None and tpl_idx is not None:
         cand = reps
@@ -279,6 +573,8 @@ def compile_affinity(pods: Sequence[api.Pod],
     p = len(cand)
     n = n_nodes
     dt = _DomainTable(nodes or [], n)
+    if resident is not None:
+        resident.ensure(nodes or [], n, hard_pod_affinity_weight)
 
     m_tab, d_tab, y_tab = _SigTable(), _SigTable(), _SigTable()
 
@@ -288,69 +584,40 @@ def compile_affinity(pods: Sequence[api.Pod],
     any_affinity = False
     for pod in cand:
         req_a, req_aa, pref_a, pref_aa = _pod_terms(pod)
-        entries: list[tuple[int, str]] = []
-        prefs: list[tuple[int, int]] = []
-        for t in req_a:
-            sig = Sig(_resolve_ns(t, pod), _sel_sig(t.label_selector),
-                      t.topology_key)
-            entries.append((m_tab.idx(sig), "aff"))
-        for t in req_aa:
-            sig = Sig(_resolve_ns(t, pod), _sel_sig(t.label_selector),
-                      t.topology_key)
-            entries.append((m_tab.idx(sig), "anti"))
-        for wt in pref_a:
-            if wt.weight == 0:
-                continue
-            t = wt.pod_affinity_term
-            sig = Sig(_resolve_ns(t, pod), _sel_sig(t.label_selector),
-                      t.topology_key)
-            prefs.append((m_tab.idx(sig), wt.weight))
-        for wt in pref_aa:
-            if wt.weight == 0:
-                continue
-            t = wt.pod_affinity_term
-            sig = Sig(_resolve_ns(t, pod), _sel_sig(t.label_selector),
-                      t.topology_key)
-            prefs.append((m_tab.idx(sig), -wt.weight))
+        entries = [(m_tab.idx(_term_sig(t, pod)), "aff") for t in req_a]
+        entries += [(m_tab.idx(_term_sig(t, pod)), "anti") for t in req_aa]
+        prefs = [(m_tab.idx(_term_sig(wt.pod_affinity_term, pod)), wt.weight)
+                 for wt in pref_a if wt.weight != 0]
+        prefs += [(m_tab.idx(_term_sig(wt.pod_affinity_term, pod)),
+                   -wt.weight) for wt in pref_aa if wt.weight != 0]
         if entries or prefs:
             any_affinity = True
         pod_m.append(entries)
         pod_pref.append(prefs)
 
     # -- existing pods' terms -> decl + sym sigs ------------------------
-    decl_sources: dict[int, list[int]] = {}  # decl sig -> [node_idx]
-    sym_sources: dict[int, list[int]] = {}   # sym sig -> [node_idx] per instance
-    for epod, nidx in affinity_pods:
-        if nidx < 0 or nidx >= n:
-            continue
-        req_a, req_aa, pref_a, pref_aa = _pod_terms(epod)
-        for t in req_aa:
-            sig = Sig(_resolve_ns(t, epod), _sel_sig(t.label_selector),
-                      t.topology_key)
-            decl_sources.setdefault(d_tab.idx(sig), []).append(nidx)
-            any_affinity = True
-        if hard_pod_affinity_weight > 0:
-            for t in req_a:
-                sig = Sig(_resolve_ns(t, epod), _sel_sig(t.label_selector),
-                          t.topology_key, weight=hard_pod_affinity_weight)
-                sym_sources.setdefault(y_tab.idx(sig), []).append(nidx)
-                any_affinity = True
-        for wt in pref_a:
-            if wt.weight == 0:
+    # Rows in ``_sig_order``, whatever order the pods come in.
+    decl_sources: dict[Sig, list[int]] = {}  # decl sig -> [node_idx]
+    sym_sources: dict[Sig, list[int]] = {}   # sym sig -> [node_idx] per instance
+    if resident is not None:
+        decl_live, sym_live = resident.declared()
+    else:
+        for epod, nidx in affinity_pods:
+            if nidx < 0 or nidx >= n:
                 continue
-            t = wt.pod_affinity_term
-            sig = Sig(_resolve_ns(t, epod), _sel_sig(t.label_selector),
-                      t.topology_key, weight=wt.weight)
-            sym_sources.setdefault(y_tab.idx(sig), []).append(nidx)
-            any_affinity = True
-        for wt in pref_aa:
-            if wt.weight == 0:
-                continue
-            t = wt.pod_affinity_term
-            sig = Sig(_resolve_ns(t, epod), _sel_sig(t.label_selector),
-                      t.topology_key, weight=-wt.weight)
-            sym_sources.setdefault(y_tab.idx(sig), []).append(nidx)
-            any_affinity = True
+            decl, sym = _declared_sigs(epod, hard_pod_affinity_weight)
+            for sig in decl:
+                decl_sources.setdefault(sig, []).append(nidx)
+            for sig in sym:
+                sym_sources.setdefault(sig, []).append(nidx)
+        decl_live = sorted(decl_sources, key=_sig_order)
+        sym_live = sorted(sym_sources, key=_sig_order)
+    for sig in decl_live:
+        d_tab.idx(sig)
+    for sig in sym_live:
+        y_tab.idx(sig)
+    if decl_live or sym_live:
+        any_affinity = True
 
     # Batch pods that DECLARE terms (for in-batch sequential visibility):
     # placing pod j extends decl reach / sym counts / match counts.
@@ -358,34 +625,9 @@ def compile_affinity(pods: Sequence[api.Pod],
     pod_decl: list[list[int]] = []
     pod_sym: list[list[int]] = []
     for pod in cand:
-        req_a, req_aa, pref_a, pref_aa = _pod_terms(pod)
-        dsigs: list[int] = []
-        ysigs: list[int] = []
-        for t in req_aa:
-            sig = Sig(_resolve_ns(t, pod), _sel_sig(t.label_selector),
-                      t.topology_key)
-            dsigs.append(d_tab.idx(sig))
-        if hard_pod_affinity_weight > 0:
-            for t in req_a:
-                sig = Sig(_resolve_ns(t, pod), _sel_sig(t.label_selector),
-                          t.topology_key, weight=hard_pod_affinity_weight)
-                ysigs.append(y_tab.idx(sig))
-        for wt in pref_a:
-            if wt.weight == 0:
-                continue
-            t = wt.pod_affinity_term
-            ysigs.append(y_tab.idx(Sig(_resolve_ns(t, pod),
-                                       _sel_sig(t.label_selector),
-                                       t.topology_key, weight=wt.weight)))
-        for wt in pref_aa:
-            if wt.weight == 0:
-                continue
-            t = wt.pod_affinity_term
-            ysigs.append(y_tab.idx(Sig(_resolve_ns(t, pod),
-                                       _sel_sig(t.label_selector),
-                                       t.topology_key, weight=-wt.weight)))
-        pod_decl.append(dsigs)
-        pod_sym.append(ysigs)
+        decl, sym = _declared_sigs(pod, hard_pod_affinity_weight)
+        pod_decl.append([d_tab.idx(sig) for sig in decl])
+        pod_sym.append([y_tab.idx(sig) for sig in sym])
 
     # Assign key rows now that all sigs are known.
     def key_row(sig: Sig) -> int:
@@ -394,7 +636,10 @@ def compile_affinity(pods: Sequence[api.Pod],
     m_rows = [key_row(s) for s in m_tab.sigs]
     d_rows = [key_row(s) for s in d_tab.sigs]
     y_rows = [key_row(s) for s in y_tab.sigs]
-    node_dom = dt.build()
+    if resident is not None:
+        node_dom = np.stack([resident.dom_row(k) for k in dt.keys])
+    else:
+        node_dom = dt.build()
 
     # Sig-axis sizes are pow2-bucketed (padcap's discipline): live batches
     # mint signatures freely, and every new count would otherwise be a
@@ -404,31 +649,40 @@ def compile_affinity(pods: Sequence[api.Pod],
     sm, sd, sy = _pow2(len(m_tab.sigs)), _pow2(len(d_tab.sigs)), \
         _pow2(len(y_tab.sigs))
 
-    # -- match sig state from existing pods -----------------------------
     match_cnt = np.zeros((sm, n), np.float32)
     match_total = np.zeros(sm, np.float32)
-    if ep is not None:
-        for si, sig in enumerate(m_tab.sigs):
-            me = _sig_match_existing(sig, ep, space)
-            if not me.any():
-                continue
-            nidxs = ep.node_idx[me]
-            match_total[si] = float(len(nidxs))
-            krow = m_rows[si]
-            for ni in nidxs:
-                match_cnt[si] += dt.same_topo_row(node_dom, krow, int(ni))
-
     decl_reach = np.zeros((sd, n), bool)
-    for si, nidxs in decl_sources.items():
-        krow = d_rows[si]
-        for ni in set(nidxs):
-            decl_reach[si] |= dt.same_topo_row(node_dom, krow, ni)
-
     sym_cnt = np.zeros((sy, n), np.float32)
-    for si, nidxs in sym_sources.items():
-        krow = y_rows[si]
-        for ni in nidxs:  # one instance per declaring term occurrence
-            sym_cnt[si] += dt.same_topo_row(node_dom, krow, ni)
+    if resident is not None:
+        # -- the resident side, off the kept planes ---------------------
+        if ep is not None:
+            for si, sig in enumerate(m_tab.sigs):
+                match_cnt[si], match_total[si] = \
+                    resident.match_row(sig, ep, space)
+        for si, sig in enumerate(decl_live):
+            decl_reach[si] = resident.decl.cnt[resident.decl.rows[sig]] > 0
+        for si, sig in enumerate(sym_live):
+            sym_cnt[si] = resident.sym.cnt[resident.sym.rows[sig]]
+    else:
+        # -- the resident side, from nothing -----------------------------
+        if ep is not None:
+            for si, sig in enumerate(m_tab.sigs):
+                me = _sig_match_existing(sig, ep, space)
+                if not me.any():
+                    continue
+                nidxs = ep.node_idx[me]
+                match_total[si] = float(len(nidxs))
+                krow = m_rows[si]
+                for ni in nidxs:
+                    match_cnt[si] += dt.same_topo_row(node_dom, krow, int(ni))
+        for sig, nidxs in decl_sources.items():
+            si = d_tab.sig_to_idx[sig]
+            for ni in set(nidxs):
+                decl_reach[si] |= dt.same_topo_row(node_dom, d_rows[si], ni)
+        for sig, nidxs in sym_sources.items():
+            si = y_tab.sig_to_idx[sig]
+            for ni in nidxs:  # one instance per declaring term occurrence
+                sym_cnt[si] += dt.same_topo_row(node_dom, y_rows[si], ni)
 
     # -- per-pod incidence matrices --------------------------------------
     aff_need = np.zeros((p, sm), bool)

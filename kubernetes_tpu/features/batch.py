@@ -23,11 +23,14 @@ import numpy as np
 
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.features import compiler as fc
-from kubernetes_tpu.features.affinity import AffinityTensors, compile_affinity
+from kubernetes_tpu.features.affinity import (AffinityTensors,
+                                              ResidentAffinity,
+                                              compile_affinity)
 from kubernetes_tpu.features.padcap import (pad_rows_pow2 as _pad_rows_pow2,
                                             pow2 as _pow2)
 from kubernetes_tpu.features.volumes import (VolSvcTensors, compile_volsvc,
                                              empty_volsvc)
+from kubernetes_tpu.utils.trace import stage
 
 
 @dataclass
@@ -278,8 +281,13 @@ def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
                   controller_refs: Optional[ControllerRefs] = None,
                   affinity_pods: Sequence[tuple[api.Pod, int]] = (),
                   hard_pod_affinity_weight: int = 1,
-                  volsvc: Optional[VolSvcTensors] = None) -> PodBatch:
+                  volsvc: Optional[VolSvcTensors] = None,
+                  resident_affinity: Optional[ResidentAffinity] = None
+                  ) -> PodBatch:
     """Compile a pending-pod batch against the current node tensors.
+
+    ``resident_affinity``: the cache's kept affinity planes; with them
+    ``affinity_pods`` is not needed (compile_affinity).
 
     ``volsvc``: precompiled volume/service tables (compile_volsvc); a
     neutral all-pass table is built when omitted."""
@@ -505,9 +513,11 @@ def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
             pod._affinity = rep._affinity
             pod._affinity_parsed = True
 
-    aff = compile_affinity(pods, affinity_pods, ep, nodes, n, space,
-                           hard_pod_affinity_weight,
-                           reps=reps, tpl_idx=tpl_idx)
+    with stage("compile.affinity"):
+        aff = compile_affinity(pods, affinity_pods, ep, nodes, n, space,
+                               hard_pod_affinity_weight,
+                               reps=reps, tpl_idx=tpl_idx,
+                               resident=resident_affinity)
     if volsvc is None:
         if nodes is not None:
             volsvc = compile_volsvc(pods, nodes, nt.schedulable)

@@ -204,6 +204,8 @@ def run_soak(n_nodes: int = 2000, duration_s: float = 60.0,
                   file=sys.stderr)
 
     violations_before = metrics.CACHE_INVARIANT_VIOLATIONS.value
+    violation_kinds_before = _labeled_snapshot(
+        metrics.CACHE_INVARIANT_VIOLATIONS)
     degraded_before = metrics.DEGRADED_DRAINS.value
     from kubernetes_tpu.perf.harness import _stage_snapshot, \
         stage_breakdown
@@ -514,6 +516,8 @@ def run_soak(n_nodes: int = 2000, duration_s: float = 60.0,
         # Verifier + violation accounting across both incarnations.
         report["invariant_violations"] = \
             metrics.CACHE_INVARIANT_VIOLATIONS.value - violations_before
+        report["invariant_violations_by_kind"] = _labeled_delta(
+            metrics.CACHE_INVARIANT_VIOLATIONS, violation_kinds_before)
         report["verifier_passes"] = \
             factory.verifier.passes if factory.verifier else 0
         report["queue_depth"] = sampler.summary()

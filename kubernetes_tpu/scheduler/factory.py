@@ -20,6 +20,7 @@ wire through a QPS/Burst rate-limited client (factory.go:77-91)."""
 from __future__ import annotations
 
 import threading
+import time
 from typing import Callable, Optional, Union
 
 from kubernetes_tpu.api import types as api
@@ -168,9 +169,11 @@ class ConfigFactory:
             # chunk meanwhile, not idling behind them.
             scheduler_name=scheduler_name, async_bind=True,
             recorder=recorder,
-            condition_updater=self._update_pod_condition))
+            condition_updater=self._update_pod_condition,
+            still_pending=self._still_pending))
         self.batched = batched
         self._reflectors: list[Reflector] = []
+        self._unassigned: Optional[Reflector] = None
         self._threads: list[threading.Thread] = []
         self._stop = threading.Event()
         # Startup reconciliation report (scheduler/recovery.py), served
@@ -361,6 +364,15 @@ class ConfigFactory:
         if etype != "DELETED":
             self.listers.replica_sets.append(rs)
 
+    def _still_pending(self, pod: api.Pod) -> bool:
+        """Whether the unassigned-pod informer still holds ``pod`` (a pod
+        leaves that set when it is bound or deleted) and the cache does
+        not: a pod this daemon has assumed is in the cache at once, while
+        the informer may be a second of events behind."""
+        return (self._unassigned is None
+                or self._unassigned.knows(pod.key)) \
+            and not self.algorithm.cache.contains(pod.key)
+
     def _update_pod_condition(self, pod: api.Pod, reason: str,
                               message: str) -> None:
         """podConditionUpdater (factory.go:589-600): PodScheduled=False."""
@@ -485,6 +497,81 @@ class ConfigFactory:
 
     # -- lifecycle -------------------------------------------------------
 
+    # What the start waits for its first lists, in all and a round: ONE
+    # deadline over every reflector, however many are late.
+    SYNC_WAIT_S = 120.0
+    SYNC_ROUND_S = 10.0
+
+    def _wait_for_first_lists(self) -> None:
+        """Wait until every reflector has delivered its first list.  The
+        node and pod lists are what prewarm traces and what the first
+        launch schedules on, so for those the wait goes on, round by
+        round, each one logged, until ``SYNC_WAIT_S`` have passed since
+        the start of the wait; the others get one round.  A start that
+        has to go on unsynced says so with the counts (prewarm then
+        traces a partial cluster, whose programs the live cluster will
+        not reuse)."""
+        cache = self.algorithm.cache
+        deadline = time.monotonic() + self.SYNC_WAIT_S
+        round_ = 0
+        while True:
+            round_ += 1
+            round_end = min(time.monotonic() + self.SYNC_ROUND_S, deadline)
+            late = [r for r in self._reflectors if not r.wait_for_sync(
+                max(round_end - time.monotonic(), 0.0))]
+            if time.monotonic() >= deadline or \
+                    not any(r.kind in ("nodes", "pods") for r in late):
+                break
+            # node_count(), not nodes(): building the node tensors from a
+            # partial list puts every later node on the row-by-row path
+            log.info("waiting for the first lists of %s (round %d, %.0f s "
+                     "left): %d nodes, %d pods cached",
+                     sorted({r.kind for r in late}), round_,
+                     deadline - time.monotonic(), cache.node_count(),
+                     cache.pod_count())
+        if late:
+            log.warning("going on WITHOUT the first lists of %s: %d nodes, "
+                        "%d pods cached, %d pending",
+                        sorted({r.kind for r in late}), cache.node_count(),
+                        cache.pod_count(), len(self.daemon.queue))
+        else:
+            log.info("reflectors synced (%d nodes cached); starting loop",
+                     cache.node_count())
+
+    def _prewarm_samples(self) -> list[api.Pod]:
+        """Sample pods shaped like what the daemon will schedule, so that
+        the content flags and the table-axis sizes of the warmed programs
+        are those of the first live batch.
+
+        With tenancy on, one pod per tenant namespace: the
+        selector-spread group axis is per-namespace, so the FIRST
+        cross-tenant packed batch would otherwise ratchet that capacity
+        past what a single-namespace warmup traced.  Where the cache
+        holds pods with inter-pod affinity, or the pending queue does,
+        one pod per distinct (namespace, labels, affinity, containers)
+        template among them: ``BatchFlags.any_affinity_pred`` / ``_prio``
+        are static arguments of the scan programs, so a ladder warmed
+        with plain pods is a ladder such a cluster never runs."""
+        samples = []
+        if self.tenancy is not None:
+            samples += [api.Pod(name=f"__warm-tenant-{i}", namespace=t)
+                        for i, t in enumerate(self.tenancy.tenants)]
+        templates: dict[tuple, api.Pod] = {}
+        resident = [pod for pod, _ in self.algorithm.cache.affinity_pods()]
+        for pod in resident + self.daemon.queue.pending():
+            raw = pod.annotations.get(api.AFFINITY_ANNOTATION_KEY)
+            if not raw or pod.affinity() is None:
+                continue
+            key = (pod.namespace, tuple(sorted(pod.labels.items())), raw)
+            templates.setdefault(key, pod)
+        samples += [api.Pod(
+            name=f"__warm-affinity-{i}", namespace=pod.namespace,
+            labels=dict(pod.labels),
+            annotations={api.AFFINITY_ANNOTATION_KEY: key[2]},
+            containers=list(pod.containers))
+            for i, (key, pod) in enumerate(templates.items())]
+        return samples
+
     def run(self, started: Callable[[], object] | None = None
             ) -> "ConfigFactory":
         """f.Run (factory.go:387-416) + scheduler.Run.  ``started`` is
@@ -509,29 +596,17 @@ class ConfigFactory:
             r = Reflector(self.store, kind, handler, selector,
                           field_selector=field_selector)
             self._reflectors.append(r)
+            if field_selector == "spec.nodeName=":
+                self._unassigned = r
             self._threads.append(r.run())
-        for r in self._reflectors:
-            r.wait_for_sync()
-        log.info("reflectors synced (%d nodes cached); starting loop",
-                 len(self.algorithm.cache.nodes()))
+        self._wait_for_first_lists()
         from kubernetes_tpu.utils import knobs
         if knobs.get_bool("KT_PREWARM"):
             # Trace the bucket ladder before the queue opens (opt-in:
             # interactive rigs keep their startup latency; the perf rigs
             # and production daemons set KT_PREWARM=1 and, with the
             # persistent compile cache populated, pay near-zero here).
-            # With tenancy on, the warm batches span the tenant
-            # namespaces: the selector-spread group axis is
-            # per-namespace, so the FIRST cross-tenant packed batch
-            # would otherwise ratchet that capacity past what the
-            # single-namespace warmup traced — a compile stall on
-            # exactly the first drain the service exists to share.
-            samples = None
-            if self.tenancy is not None:
-                samples = [api.Pod(name=f"__warm-tenant-{i}",
-                                   namespace=t)
-                           for i, t in enumerate(self.tenancy.tenants)]
-            self.daemon.prewarm(sample_pods=samples)
+            self.daemon.prewarm(sample_pods=self._prewarm_samples() or None)
         if knobs.get_bool("KT_RECOVERY"):
             # Crash-safe restart: reconcile cache + queue against one
             # apiserver relist (re-adopt bound pods, requeue orphans,

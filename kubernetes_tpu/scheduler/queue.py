@@ -206,6 +206,12 @@ class FIFO:
             return pod_key in self._items or any(
                 pod_key in h for h in self._gang_hold.values())
 
+    def pending(self) -> list[api.Pod]:
+        """The queued pods (gang holds included), none popped."""
+        with self._lock:
+            return list(self._items.values()) + [
+                pod for h in self._gang_hold.values() for pod in h.values()]
+
     def held_gangs(self) -> dict[str, int]:
         """Gang name -> held member count (observability)."""
         with self._lock:

@@ -126,6 +126,10 @@ class SchedulerConfig:
     # Pod-condition updater analogue (factory.go:589-600); called with
     # (pod, reason, message) when scheduling fails.
     condition_updater: Optional[Callable[[api.Pod, str, str], None]] = None
+    # Asked of a pod whose backoff has run out (factory.go:536-549: the
+    # reference gets the pod again and requeues it only while its
+    # spec.nodeName is empty); None requeues every such pod.
+    still_pending: Optional[Callable[[api.Pod], bool]] = None
     async_bind: bool = True
     # Decision flight recorder (/debug/scheduler/decisions); None disables
     # recording entirely (and the failure-detail device pass with it).
@@ -711,6 +715,12 @@ class Scheduler:
                     pass
 
             audited("single_pod", run_single)
+            # The failure-detail pass of a drain that left a pod unplaced
+            # (masks + evaluate at EXPLAIN_CAP pods, the sample's flags).
+            if self.config.flight_recorder is not None:
+                audited("explain", lambda: alg.explain_failures(
+                    list(sample_pods[:1]) if sample_pods else
+                    [api.Pod(name="__warm-explain", namespace="__warm__")]))
             # The dirty-row scatter kernel compiles per pow2 dirty-row count;
             # untraced, the first drain after any assume paid it mid-drain.
             audited("scatter", lambda: alg.resident.prewarm_scatter())
@@ -1007,7 +1017,10 @@ class Scheduler:
                 pod.key, reason, message,
                 failed_predicates=failed_predicates)
         self.config.recorder.eventf(pod.key, "Warning", reason, message)
-        if self.config.condition_updater is not None:
+        if self.config.condition_updater is not None \
+                and result != "bind_conflict":
+            # (a 409 says the pod IS assigned: PodScheduled=False on it
+            # would be false, and a write to a bound pod besides)
             self.config.condition_updater(pod, "Unschedulable", message)
         backoff_s = self.backoff.get_backoff(pod.key)
         with self._requeue_cv:
@@ -1034,6 +1047,15 @@ class Scheduler:
                     self._requeue_cv.wait(timeout=min(delay, 0.5))
                     continue
                 heapq.heappop(self._requeue_heap)
+            still_pending = self.config.still_pending
+            if still_pending is not None and not still_pending(pod):
+                # Bound or deleted while it sat in backoff: the condition
+                # update of a failed attempt comes back on the unassigned
+                # watch and requeues the pod at once, so this entry can
+                # outlive the pod's bind; scheduling it again would end
+                # in a 409, or a 404 once the pod is gone.
+                self._first_seen.pop(pod.key, None)
+                continue
             pod.node_name = ""
             if self.owns_pod is not None and not self.owns_pod(pod):
                 # The shard moved while this pod sat in backoff: its new

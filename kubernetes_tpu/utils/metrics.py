@@ -550,7 +550,8 @@ CACHE_INVARIANT_VIOLATIONS = register(Counter(
     "scheduler_cache_invariant_violations_total",
     "Resident-state invariant violations found by the background "
     "verifier, by kind (aggregates: cache aggregate rows vs a recompute "
-    "from tracked pods; device_row: device-resident tensor rows vs host "
+    "from tracked pods; affinity_planes: the kept inter-pod affinity "
+    "planes vs a build from nothing; device_row: device-resident tensor rows vs host "
     "arrays; apiserver: cache pod placements vs apiserver truth).  Each "
     "triggers a self-heal full re-snapshot",
     labelnames=("kind",)))
@@ -779,6 +780,26 @@ CACHE_LOCK_CONTENDED = register(Counter(
     "Acquisitions of the scheduler cache's lock that had to block, by "
     "the waiting thread's role",
     labelnames=("role",)))
+# The resident side of the inter-pod affinity tables, kept between
+# launches (features/affinity.py ResidentAffinity, owned by the cache).
+AFFINITY_TABLE_REBUILDS = register(Counter(
+    "scheduler_affinity_table_rebuilds_total",
+    "Builds of the kept affinity tables from nothing (first use, node "
+    "rows or labels changed) plus passes over the resident pods that "
+    "register a match signature not seen before; 0 in a steady window"))
+AFFINITY_TABLE_ROW_UPDATES = register(Counter(
+    "scheduler_affinity_table_row_updates_total",
+    "Resident pods added to or taken out of the kept affinity tables "
+    "one at a time (attach / detach of a pod that changes a plane)"))
+AFFINITY_RESIDENT_PODS = register(Gauge(
+    "scheduler_affinity_resident_pods",
+    "Attached pods (bound or assumed) that carry an affinity "
+    "annotation, as the last launch found them"))
+AFFINITY_SIGNATURES = register(Gauge(
+    "scheduler_affinity_signatures",
+    "Signatures the kept affinity tables hold a plane for, by family "
+    "(match / decl / sym), as the last launch found them",
+    labelnames=("family",)))
 # The daemon's cyclic collector (utils/gcstats.py, installed at daemon
 # start): every Python thread stands still for a collection.
 GC_PAUSE_SECONDS = register(Counter(

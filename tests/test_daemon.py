@@ -112,6 +112,21 @@ class TestScheduleOne:
         time.sleep(1.2)
         assert len(s.queue) == 1
 
+    @pytest.mark.parametrize("pending", [True, False])
+    def test_backoff_requeues_only_a_pod_that_is_still_pending(self, pending):
+        # factory.go:536-549: after the backoff the pod is looked at again
+        # and requeued only while it is unassigned; one that was bound (or
+        # deleted) meanwhile is dropped, not scheduled a second time.
+        asked = []
+        s = _scheduler(n_nodes=1)
+        s.config.still_pending = lambda pod: asked.append(pod.key) or pending
+        s.enqueue(make_pod("big", cpu="64"))
+        assert s.schedule_one(timeout=0.1)
+        time.sleep(1.3)
+        assert asked == ["default/big"]
+        assert len(s.queue) == (1 if pending else 0)
+        assert ("default/big" in s._first_seen) == pending
+
     def test_bind_conflict_forgets_assumed_pod(self):
         class RejectingBinder(InMemoryBinder):
             def bind(self, pod, node_name):
@@ -128,6 +143,27 @@ class TestScheduleOne:
         assert algo.cache.pod_count() == 0
         evs = s.config.recorder.events("default/p1")
         assert evs and evs[-1].reason == "FailedScheduling"
+
+    @pytest.mark.parametrize("error, written", [
+        (BindConflict("already assigned to node n0"), []),
+        (RuntimeError("connection reset"), ["default/p1"])])
+    def test_a_bind_conflict_writes_no_unschedulable_condition(
+            self, error, written):
+        # A 409 says the pod IS assigned: PodScheduled=False on it would
+        # be false; any other bind error still updates the condition.
+        class FailingBinder(InMemoryBinder):
+            def bind(self, pod, node_name):
+                raise error
+
+        updates = []
+        algo = GenericScheduler()
+        algo.cache.add_node(make_node("n0"))
+        s = Scheduler(SchedulerConfig(
+            algorithm=algo, binder=FailingBinder(), async_bind=False,
+            condition_updater=lambda pod, *_: updates.append(pod.key)))
+        s.enqueue(make_pod("p1"))
+        assert s.schedule_one(timeout=0.1)
+        assert updates == written
 
     def test_multi_scheduler_annotation_dispatch(self):
         s = _scheduler()

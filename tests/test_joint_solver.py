@@ -172,6 +172,7 @@ def test_prewarm_covers_the_single_pod_path_and_scatter(tmp_path):
         # single-pod + scatter signatures the ladder used to miss.
         stats = daemon.prewarm_cache_stats
         assert "single_pod" in stats and "scatter" in stats
+        assert "explain" in stats      # the failure-detail pass (PR 30)
         assert all(b in stats for b in timings)
         # Post-prewarm, the previously-dodging paths compile NOTHING on
         # the clock: a schedule_one and a dirtying drain are all cache
@@ -183,6 +184,14 @@ def test_prewarm_covers_the_single_pod_path_and_scatter(tmp_path):
             daemon.enqueue(p)
         daemon.schedule_pending(wait_first=False)  # scatters dirty rows
         daemon.wait_for_binds()
+        # a pod no node holds: the drain's failure-detail pass
+        big = synth.make_pods(1, name_prefix="big")[0]
+        big.containers[0].requests["cpu"] = "4096"
+        daemon.enqueue(big)
+        daemon.schedule_pending(wait_first=False)
+        daemon.wait_for_binds()
+        assert daemon.config.recorder.events(big.key)[-1].reason \
+            == "FailedScheduling"
         assert COMPILE_CACHE_MISSES.value == misses0, \
             "a post-prewarm decision path still compiles on the clock"
         # Cold vs warm: a fresh-executable re-trace (restart analogue)

@@ -416,7 +416,8 @@ def test_mini_soak_smoke():
                    stream_chunk=256, heartbeat_period=0.5,
                    verify_period=0.5, settle_timeout=120,
                    parity_samples=8, quiet=True)
-    assert rec["invariant_violations"] == 0
+    assert rec["invariant_violations"] == 0, \
+        rec["invariant_violations_by_kind"]
     assert rec["reconciliation"]["double_binds"] == 0
     assert rec["reconciliation"]["stranded_pending"] == 0
     assert rec["reconciliation"]["orphaned_assumes"] == 0
