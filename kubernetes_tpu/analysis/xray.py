@@ -467,6 +467,18 @@ def resize_pod_axis(b_abs: Any, p: int) -> Any:
     return b_abs._replace(aff=aff, volsvc=vs, **upd)
 
 
+def packed_avals(b_abs: Any, **riders: Any) -> Any:
+    """The wire form the live dispatch hands the jitted entrypoints:
+    ``solver.batch_layout`` over the batch avals and the launch's
+    riders (live mask, tie counter, topology planes), as a PackedBatch
+    of three buffer avals."""
+    from kubernetes_tpu.engine import solver as sv
+    layout, _leaves, sizes = sv.batch_layout(b_abs, **riders)
+    return sv.PackedBatch(
+        tuple(_sds((size,), dtype)
+              for size, dtype in zip(sizes, sv.WIRE_DTYPES)), layout)
+
+
 def build_context() -> Context:
     """One host-only feature compile of the canonical workload (a
     minimal pod over CANON['nodes'] identical nodes) through the REAL
@@ -541,44 +553,51 @@ def program_builders(ctx: Context) -> dict[str, tuple[str, Callable,
 
     progs: dict[str, tuple[str, Callable, tuple]] = {}
 
-    def scan_first(b, c, k, lv):
-        return raw_scan(solver, b, c, k, None, flags, None, lv, None)
+    # The batch arrives in its wire form (solver.PackedBatch): the live
+    # mask rides its buffers, and so does the tie counter wherever the
+    # host has it (a launch's first chunk, the one-shot solves, the
+    # single-pod compile); a later chunk takes the previous scan's.
+    def scan_first(b, c):
+        return raw_scan(solver, b, c, None, None, flags, None)
 
-    def scan_carry(b, c, k, cr, lv):
-        return raw_scan(solver, b, c, k, None, flags, cr, lv, None)
+    def scan_carry(b, c, k, cr):
+        return raw_scan(solver, b, c, k, None, flags, cr)
 
     for bucket in canonical_ladder():
         b_abs = resize_pod_axis(ctx.batch1, bucket)
         live = _sds((bucket,), np.bool_)
+        b_first = packed_avals(b_abs, live=live, counter=cnt)
         progs[f"scan_first@{bucket}"] = (
-            "scan_first", scan_first, (b_abs, c_abs, cnt, live))
-        carry = jax.eval_shape(scan_first, b_abs, c_abs, cnt, live)[2]
+            "scan_first", scan_first, (b_first, c_abs))
+        carry = jax.eval_shape(scan_first, b_first, c_abs)[2]
         progs[f"scan_carry@{bucket}"] = (
-            "scan_carry", scan_carry, (b_abs, c_abs, cnt, carry, live))
+            "scan_carry", scan_carry,
+            (packed_avals(b_abs, live=live), c_abs, cnt, carry))
 
-    b_f = resize_pod_axis(ctx.batch1, floor)
-    live_f = _sds((floor,), np.bool_)
+    b_f = packed_avals(resize_pod_axis(ctx.batch1, floor),
+                       live=_sds((floor,), np.bool_), counter=cnt)
     em = _sds((floor, n), np.bool_)
     sb = _sds((floor, n), np.float32)
 
-    def oneshot_topo(b, c, k, lv, m, s):
-        return raw_scan(solver, b, c, k, s, flags, None, lv, m)
+    def oneshot_topo(b, c, m, s):
+        return raw_scan(solver, b, c, None, s, flags, None, None, m)
 
     progs[f"oneshot_topo@{floor}"] = (
-        "oneshot_topo", oneshot_topo, (b_f, c_abs, cnt, live_f, em, sb))
+        "oneshot_topo", oneshot_topo, (b_f, c_abs, em, sb))
 
-    def joint(b, c, k, lv):
-        return raw_joint(solver, b, c, k, None, None, lv,
+    def joint(b, c):
+        return raw_joint(solver, b, c, None, None, None, None,
                          CANON["joint_iters"], flags)
 
-    progs[f"joint@{floor}"] = ("joint", joint, (b_f, c_abs, cnt, live_f))
+    progs[f"joint@{floor}"] = ("joint", joint, (b_f, c_abs))
 
+    b_1 = packed_avals(ctx.batch1, counter=cnt)
     progs["single_evaluate@1"] = (
         "single_evaluate", lambda b, c: raw_eval(solver, b, c, flags),
-        (ctx.batch1, c_abs))
+        (b_1, c_abs))
     progs["single_masks@1"] = (
         "single_masks", lambda b, c: raw_masks(solver, b, c),
-        (ctx.batch1, c_abs))
+        (b_1, c_abs))
     progs["select_hosts@1"] = (
         "select_hosts", combine.select_hosts,
         (_sds((1, n), np.float32), _sds((1, n), np.bool_), cnt))

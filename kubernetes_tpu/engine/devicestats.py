@@ -7,12 +7,18 @@ regressions ROADMAP items 1 and 3 name would otherwise be invisible:
 
 * **Transfer accounting.**  ``record_transfer(cause, nbytes)`` feeds
   ``scheduler_device_transfer_bytes_total{cause=}`` (and an ops
-  counter).  The drain path records three causes: ``scatter`` (dirty
-  rows into the resident cluster mirror — the steady-state path),
-  ``full_upload`` (whole-cluster re-snapshot — legitimate only on
-  relist/capacity growth; dominating steady-state drains means the
-  residency protocol silently broke), and ``readback`` (device→host
-  result fetches).  ``transfer_snapshot()`` returns the per-cause byte
+  counter).  The drain path records four causes: ``batch`` (the pod
+  batch in its wire form, ``solver.put_batch`` — once per chunk),
+  ``scatter`` (dirty rows into the resident cluster mirror — the
+  steady-state path), ``full_upload`` (whole-cluster re-snapshot —
+  legitimate only on relist/capacity growth; dominating steady-state
+  drains means the residency protocol silently broke), and ``readback``
+  (device→host result fetches).  The uploads also count the host arrays
+  they hand to the runtime
+  (``scheduler_device_transfer_arrays_total{cause=}``): each is a trip
+  through the interpreter's lock on the launch thread, so arrays per
+  launch — not bytes — is what an upload costs the served path.
+  ``transfer_snapshot()`` returns the per-cause byte
   totals so benches can diff a window and stamp bytes-per-pod columns
   into their artifacts.
 
@@ -53,7 +59,7 @@ from kubernetes_tpu.utils.logging import get_logger
 
 log = get_logger("devicestats")
 
-CAUSES = ("scatter", "full_upload", "readback")
+CAUSES = ("batch", "scatter", "full_upload", "readback")
 
 _lock = threading.Lock()
 _peak_fallback = 0          # high-water mark of sampled live bytes
@@ -78,13 +84,15 @@ def nbytes(tree: object) -> int:
     return 0
 
 
-def record_transfer(cause: str, n: int) -> None:
-    """Count ``n`` bytes moved for ``cause`` (scatter/full_upload/
-    readback)."""
+def record_transfer(cause: str, n: int, arrays: int = 0) -> None:
+    """Count ``n`` bytes moved for ``cause`` (batch/scatter/full_upload/
+    readback), and for an upload the ``arrays`` it handed the runtime."""
     if n <= 0:
         return
     metrics.DEVICE_TRANSFER_BYTES.labels(cause=cause).inc(int(n))
     metrics.DEVICE_TRANSFERS.labels(cause=cause).inc()
+    if arrays:
+        metrics.DEVICE_TRANSFER_ARRAYS.labels(cause=cause).inc(arrays)
 
 
 def transfer_snapshot() -> dict[str, int]:

@@ -17,7 +17,6 @@ import time
 from dataclasses import dataclass, field
 from typing import Iterator, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -186,11 +185,15 @@ class GenericScheduler:
     # -- compilation helpers --------------------------------------------
 
     def _compile(self, pods: list[api.Pod], device: bool = True,
-                 host_only: bool = False
-                 ) -> tuple[fb.PodBatch, sv.DeviceBatch,
+                 host_only: bool = False, live: np.ndarray | None = None
+                 ) -> tuple[fb.PodBatch, "sv.PackedBatch | sv.DeviceBatch",
                             sv.DeviceCluster, list[str]]:
-        """``host_only=True`` is the fallback engine's compile: the same
-        snapshot + feature compile, but NO device participation — the
+        """The batch comes back in its wire form on the device
+        (``sv.PackedBatch``, with the tie counter and, where the caller
+        padded, the ``live`` mask riding its buffers), or with
+        ``device=False`` as the host-numpy DeviceBatch the chunked drain
+        slices.  ``host_only=True`` is the fallback engine's compile: the
+        same snapshot + feature compile, but NO device participation — the
         cluster comes back as host numpy (``_host_cluster``) and the
         dirty-row set is NOT consumed (it belongs to the device mirror,
         which must replay every mutation when the breaker closes)."""
@@ -256,7 +259,9 @@ class GenericScheduler:
                 # chunks).
                 if device:
                     with stage("transfer.batch"):
-                        db = sv.device_batch(batch)
+                        db = sv.device_batch(
+                            batch, live=live,
+                            counter=self.last_node_index)
                 else:
                     db = sv.host_batch(batch)
                 # Cluster state syncs through the device-resident mirror:
@@ -329,7 +334,7 @@ class GenericScheduler:
             return host
         with self.guard.watch("single_pod", inject=False):
             choice, new_last = sv.combine.select_hosts(
-                scores, feasible, jnp.uint32(self.last_node_index))
+                scores, feasible, self.last_node_index)
             picked = int(choice[0])
         self.last_node_index = np.uint32(new_last)
         trace.log_if_long()
@@ -509,19 +514,19 @@ class GenericScheduler:
             # restore (callers re-assume through the daemon).
             return self._schedule_batch_via_extenders(pods)
         real_p = len(pods)
-        live = live_np = None
+        live_np = None
         if pad_to > real_p:
             pods = list(pods) + [
                 api.Pod(name=f"__pad-{i}", namespace="__pad__")
                 for i in range(pad_to - real_p)]
-        with self.guard.watch("oneshot" if not joint else "joint",
-                              inject=False):
-            batch, db, dc, nt = self._compile(pods)
-        flags = self._pinned_flags(batch)
-        if pad_to > real_p:
             live_np = np.zeros(len(pods), bool)
             live_np[:real_p] = True
-            live = jnp.asarray(live_np)
+        # The tie counter and the live mask ride db's buffers: the solve
+        # calls below pass None for both.
+        with self.guard.watch("oneshot" if not joint else "joint",
+                              inject=False):
+            batch, db, dc, nt = self._compile(pods, live=live_np)
+        flags = self._pinned_flags(batch)
         extra_mask = score_bias = None
         if self._topo_terms is not None:
             from kubernetes_tpu.engine.workloads import topology
@@ -538,9 +543,8 @@ class GenericScheduler:
                     self.guard.watch("joint"), \
                     stage("solve", pods=len(pods), mode="joint"):
                 choices, new_last, _ = self.solver.solve_joint(
-                    db, dc, jnp.uint32(self.last_node_index), flags=flags,
-                    extra_mask=extra_mask, score_bias=score_bias,
-                    live=live)
+                    db, dc, None, flags=flags,
+                    extra_mask=extra_mask, score_bias=score_bias)
                 with stage("device_wait"):
                     choices.block_until_ready()
             with stage("readback", pods=len(pods)):
@@ -564,9 +568,8 @@ class GenericScheduler:
                     self.guard.watch("oneshot"), \
                     stage("solve", pods=p, mode="sequential"):
                 host_dev = self.solver.solve_sequential_packed(
-                    db, dc, jnp.uint32(self.last_node_index), flags,
-                    extra_mask=extra_mask, score_bias=score_bias,
-                    live=live)
+                    db, dc, None, flags,
+                    extra_mask=extra_mask, score_bias=score_bias)
                 # Block here so the solve stage measures device compute
                 # and readback measures only the D2H copy.
                 with stage("device_wait"):
@@ -813,8 +816,9 @@ class GenericScheduler:
             batch, hb, dc, nt = self._compile(all_pods, device=False)
         flags = self._pinned_flags(batch)
         # Spread-constraint planes, host-resident like the batch: each
-        # chunk device_puts its fixed-shape row slice (pad rows carry no
-        # constraints, so their mask rows are all-pass).
+        # chunk's fixed-shape row slice rides the chunk's packed buffers
+        # (pad rows carry no constraints, so their mask rows are
+        # all-pass).
         topo_mask_np = topo_score_np = None
         if self._topo_terms is not None:
             from kubernetes_tpu.engine.workloads import topology
@@ -837,8 +841,9 @@ class GenericScheduler:
                   f"{time.perf_counter() - t_c0:.3f}s flags={tuple(flags)} "
                   f"shapes={shapes}", file=sys.stderr)
         n = sv.cluster_nodes(dc)
-        counter = jnp.uint32(self.last_node_index)
-        carry = None
+        # The first chunk's tie counter rides its packed batch; from the
+        # second on it is the device scalar the previous scan returned.
+        counter = carry = None
         live_np = np.zeros(padded, bool)
         live_np[:p] = True
         pending: list[tuple[int, jnp.ndarray]] = []
@@ -872,18 +877,25 @@ class GenericScheduler:
         debug_t = self._stream_debug
         for start in range(0, padded, chunk_size):
             t0 = time.perf_counter() if debug_t else 0.0
-            # Host-slice (free numpy views), then one batched device_put of
-            # the fixed [chunk_size, ...] shapes: slicing ON DEVICE minted
-            # a dynamic_slice program per distinct drain length.
+            # Host-slice (free numpy views), pack the fixed
+            # [chunk_size, ...] leaves with the chunk's live mask (and the
+            # counter, and the planes) into three buffers, then ONE
+            # device_put of those: slicing ON DEVICE minted a
+            # dynamic_slice program per distinct drain length.
+            stop = start + chunk_size
+
+            def rows(plane: np.ndarray | None) -> np.ndarray | None:
+                return None if plane is None else plane[start:stop]
+
             with stage("transfer", chunk_at=start), \
                     stage("transfer.batch"):
-                db_k = jax.device_put(
-                    sv.slice_pod_axis(hb, start, start + chunk_size))
-                live = jnp.asarray(live_np[start:start + chunk_size])
-                em_k = None if topo_mask_np is None else jax.device_put(
-                    topo_mask_np[start:start + chunk_size])
-                sb_k = None if topo_score_np is None else jax.device_put(
-                    topo_score_np[start:start + chunk_size])
+                db_k = sv.put_batch(
+                    sv.slice_pod_axis(hb, start, stop),
+                    live=live_np[start:stop],
+                    counter=self.last_node_index if carry is None
+                    else None,
+                    extra_mask=rows(topo_mask_np),
+                    score_bias=rows(topo_score_np))
             # The launch is async: device time surfaces in the next
             # chunk's readback, which is what keeps the pipeline
             # overlapped — this stage measures dispatch only.
@@ -891,7 +903,7 @@ class GenericScheduler:
                     self.guard.watch("stream"), \
                     stage("solve", chunk_at=start, mode="stream"):
                 choices_k, counter, carry = self.solver._solve_scan(
-                    db_k, dc, counter, sb_k, flags, carry, live, em_k)
+                    db_k, dc, counter, None, flags, carry)
             if debug_t:
                 t1 = time.perf_counter()
             pending.append((start, choices_k))

@@ -25,6 +25,7 @@ sharded across a mesh (see kubernetes_tpu.parallel).
 from __future__ import annotations
 
 import functools
+import math
 import os
 import threading
 from typing import Any, NamedTuple, Optional
@@ -186,9 +187,12 @@ class BatchFlags(NamedTuple):
     any_saa: bool             # saa_src content (placements move peer counts)
 
 
-def batch_flags(b: "PodBatch | DeviceBatch") -> BatchFlags:
+def batch_flags(b: "PodBatch | DeviceBatch | PackedBatch") -> BatchFlags:
     """Derive BatchFlags from a PodBatch (host numpy — call before
-    device transfer; also works on a DeviceBatch at the cost of syncs)."""
+    device transfer; also works on a DeviceBatch, or its wire form, at
+    the cost of syncs)."""
+    if isinstance(b, PackedBatch):
+        b = unpack_batch(b)
     a, vs = b.aff, b.volsvc
     return BatchFlags(
         any_ports=bool(np.asarray(b.ports).any()),
@@ -382,10 +386,164 @@ def host_batch(b: PodBatch) -> DeviceBatch:
     return DeviceBatch(*parts, aff=aff, volsvc=volsvc)
 
 
-def device_batch(b: PodBatch) -> DeviceBatch:
-    # One batched device_put for the whole pytree (~70 arrays): per-array
-    # transfer calls dominate small-batch compiles otherwise.
-    return jax.device_put(host_batch(b))
+# -- the batch's wire form ---------------------------------------------------
+#
+# A launch's pod batch crosses to the device as ONE buffer per dtype, as
+# the cluster crosses as a NarrowCluster: every array handed to the
+# runtime is a trip through the interpreter's lock, which the launch
+# thread shares with the decode, reflector and bind threads, so 65 small
+# leaves cost what 65 trips cost whatever their bytes.  ``pack_batch``
+# lays the leaves out on the host, ``unpack_batch`` / ``unpack_launch``
+# slice them back at the top of every jitted entrypoint; dtypes, shapes
+# and values are the DeviceBatch's own, so no decision can move.
+
+WIRE_DTYPES = ("int32", "bool", "float32")
+# dtype -> the buffer that carries it; the tie counter (uint32) rides the
+# int32 buffer bit-cast.
+_WIRE = {"int32": 0, "uint32": 0, "bool": 1, "float32": 2}
+_N_TOP = len(DeviceBatch._fields) - 2
+_BATCH_PATHS = (DeviceBatch._fields[:_N_TOP]
+                + tuple(f"aff.{f}" for f in DeviceAffinity._fields)
+                + tuple(f"volsvc.{f}" for f in DeviceVolSvc._fields))
+# What a launch carries besides the DeviceBatch's fields, behind them in
+# the buffers: the chunk's live mask, the tie counter (a launch's first
+# chunk) and the topology planes.
+RIDERS = ("live", "counter", "extra_mask", "score_bias")
+
+
+@jax.tree_util.register_pytree_node_class
+class PackedBatch:
+    """The wire form of a DeviceBatch (+ riders): children = one flat
+    buffer per WIRE_DTYPES entry, static part = the layout, a tuple of
+    ``(path, dtype, shape, offset)`` derived from the leaves' shapes
+    alone — so it passes through ``jax.jit`` as the 65 leaves did and
+    keys the program exactly as their shapes did."""
+
+    __slots__ = ("buffers", "layout")
+
+    def __init__(self, buffers: tuple, layout: tuple):
+        self.buffers = buffers
+        self.layout = layout
+
+    def tree_flatten(self) -> tuple[tuple, tuple]:
+        return self.buffers, self.layout
+
+    @classmethod
+    def tree_unflatten(cls, layout: tuple, buffers: Any) -> "PackedBatch":
+        return cls(tuple(buffers), layout)
+
+
+def _batch_leaves(b: DeviceBatch) -> tuple:
+    return b[:_N_TOP] + tuple(b.aff) + tuple(b.volsvc)
+
+
+@functools.lru_cache(maxsize=256)
+def _layout(signature: tuple, riders: tuple) -> tuple[tuple, tuple]:
+    """``(layout, sizes)`` for leaves of these ``(dtype, shape)`` in wire
+    order: each one's ``(path, dtype, shape, offset)`` and the element
+    count of the three buffers.  A launch's shapes repeat (one per
+    bucket and content-axis capacity), so the walk is paid once each."""
+    layout, sizes = [], [0, 0, 0]
+    for path, (dtype, shape) in zip(_BATCH_PATHS + riders, signature):
+        k = _WIRE.get(dtype.name)
+        if k is None:
+            raise TypeError(f"batch leaf {path}: dtype {dtype.name} has "
+                            f"no wire buffer")
+        layout.append((path, dtype.name, shape, sizes[k]))
+        sizes[k] += math.prod(shape)
+    return tuple(layout), tuple(sizes)
+
+
+def batch_layout(b: DeviceBatch, live: Any = None, counter: Any = None,
+                 extra_mask: Any = None, score_bias: Any = None
+                 ) -> tuple[tuple, list, tuple]:
+    """``(layout, leaves, sizes)`` of a batch and its riders: the leaves
+    in wire order, their layout and the three buffers' element counts.
+    Reads shapes and dtypes only (kt-xray lays out ShapeDtypeStructs
+    with it)."""
+    riders = [(name, r) for name, r in zip(
+        RIDERS, (live, counter, extra_mask, score_bias)) if r is not None]
+    leaves = list(_batch_leaves(b)) + [r for _name, r in riders]
+    layout, sizes = _layout(
+        tuple((leaf.dtype, tuple(leaf.shape)) for leaf in leaves),
+        tuple(name for name, _r in riders))
+    return layout, leaves, sizes
+
+
+def pack_batch(b: DeviceBatch, live: np.ndarray | None = None,
+               counter: np.uint32 | None = None,
+               extra_mask: np.ndarray | None = None,
+               score_bias: np.ndarray | None = None) -> PackedBatch:
+    """The host-numpy DeviceBatch (and the launch's riders) as a
+    PackedBatch of three host buffers.  One ``bytes.join`` per buffer:
+    a C loop that keeps the interpreter's lock, where 65 NumPy copies
+    would each offer it to the other threads."""
+    layout, leaves, _sizes = batch_layout(b, live, counter, extra_mask,
+                                          score_bias)
+    parts: tuple[list, list, list] = ([], [], [])
+    for (_path, dtype, _shape, _off), leaf in zip(layout, leaves):
+        parts[_WIRE[dtype]].append(
+            leaf if leaf.flags.c_contiguous else np.ascontiguousarray(leaf))
+    return PackedBatch(
+        tuple(np.frombuffer(b"".join(part), dtype)
+              for part, dtype in zip(parts, WIRE_DTYPES)), layout)
+
+
+def _unpack(pb: PackedBatch) -> tuple[DeviceBatch, dict]:
+    """Static slices and reshapes of the buffers back into the exact
+    DeviceBatch, and the riders by name."""
+    vals = []
+    for _path, dtype, shape, off in pb.layout:
+        v = jax.lax.slice(pb.buffers[_WIRE[dtype]], (off,),
+                          (off + math.prod(shape),))
+        if dtype == "uint32":
+            v = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        vals.append(v.reshape(shape))
+    n_aff = len(DeviceAffinity._fields)
+    n_b = len(_BATCH_PATHS)
+    db = DeviceBatch(
+        *vals[:_N_TOP], aff=DeviceAffinity(*vals[_N_TOP:_N_TOP + n_aff]),
+        volsvc=DeviceVolSvc(*vals[_N_TOP + n_aff:n_b]))
+    return db, {e[0]: v for e, v in zip(pb.layout[n_b:], vals[n_b:])}
+
+
+def unpack_batch(b: "DeviceBatch | PackedBatch") -> DeviceBatch:
+    """The exact DeviceBatch back from the wire form — idempotent (a
+    DeviceBatch passes through), traced at the top of every jitted
+    entrypoint that takes a batch, like ``widen_cluster``."""
+    return b if isinstance(b, DeviceBatch) else _unpack(b)[0]
+
+
+def unpack_launch(b: "DeviceBatch | PackedBatch",
+                  counter: jnp.ndarray | None,
+                  score_bias: jnp.ndarray | None,
+                  live: jnp.ndarray | None,
+                  extra_mask: jnp.ndarray | None) -> tuple:
+    """``unpack_batch`` plus the launch's riders, in ``_solve_scan``'s
+    argument order: an argument the caller gave outright stands, a None
+    is filled from the buffers where the batch carries it."""
+    if isinstance(b, DeviceBatch):
+        return b, counter, score_bias, live, extra_mask
+    db, riders = _unpack(b)
+    given = {"counter": counter, "score_bias": score_bias, "live": live,
+             "extra_mask": extra_mask}
+    return (db,) + tuple(riders.get(k) if v is None else v
+                         for k, v in given.items())
+
+
+def put_batch(hb: DeviceBatch, **riders: Any) -> PackedBatch:
+    """Pack a host batch with its riders and hand it to the device: ONE
+    device_put of three arrays, counted under cause ``batch``."""
+    from kubernetes_tpu.engine import devicestats
+    pb = pack_batch(hb, **riders)
+    devicestats.record_transfer("batch", devicestats.nbytes(pb.buffers),
+                                arrays=len(pb.buffers))
+    return jax.device_put(pb)
+
+
+def device_batch(b: PodBatch, **riders: Any) -> PackedBatch:
+    """The PodBatch on the device, in its wire form."""
+    return put_batch(host_batch(b), **riders)
 
 
 def _host_cluster(nt: NodeTensors, agg: NodeAggregates,
@@ -613,7 +771,8 @@ class ResidentCluster:
             # walks every live array — the telemetry scrape cadence
             # covers it off the drain path.)
             devicestats.record_transfer("full_upload",
-                                        devicestats.nbytes(self.dc))
+                                        devicestats.nbytes(self.dc),
+                                        arrays=len(self.dc))
             return self.dc
         if not dirty:
             return self.dc
@@ -626,7 +785,8 @@ class ResidentCluster:
         self.stats["rows_scattered"] += len(dirty)
         # Only the gathered rows crossed the wire (idx + padded rows).
         devicestats.record_transfer(
-            "scatter", idx.nbytes + devicestats.nbytes(rows))
+            "scatter", idx.nbytes + devicestats.nbytes(rows),
+            arrays=1 + len(rows))
         return self.dc
 
     @staticmethod
@@ -859,6 +1019,7 @@ class Solver:
     @functools.partial(jax.jit, static_argnums=(0,))
     def masks(self, b: DeviceBatch, c: DeviceCluster) -> dict[str, jnp.ndarray]:
         """Per-predicate [P,N] masks (for Filter verbs / failure reporting)."""
+        b = unpack_batch(b)
         c = widen_cluster(c)
         n = c.alloc.shape[0]
         return {name: _predicate_mask(name, b, c, n, self.extra)
@@ -876,6 +1037,7 @@ class Solver:
         provably cannot trigger — an all-pass mask or all-zero plane — which
         matters because per-kernel dispatch overhead, not FLOPs, dominates
         small-batch evaluation."""
+        b = unpack_batch(b)
         c = widen_cluster(c)
         n = c.alloc.shape[0]
         skip_preds = set()
@@ -907,7 +1069,7 @@ class Solver:
     # -- sequential greedy solve ----------------------------------------
 
     def solve_sequential(self, b: DeviceBatch, c: DeviceCluster,
-                         last_node_index: jnp.ndarray,
+                         last_node_index: jnp.ndarray | None,
                          flags: BatchFlags | None = None,
                          extra_mask: jnp.ndarray | None = None,
                          score_bias: jnp.ndarray | None = None
@@ -928,7 +1090,7 @@ class Solver:
         return choices, counter, self._carry_cluster(c, final)
 
     def solve_sequential_packed(self, b: DeviceBatch, c: DeviceCluster,
-                                last_node_index: jnp.ndarray,
+                                last_node_index: jnp.ndarray | None,
                                 flags: BatchFlags,
                                 extra_mask: jnp.ndarray | None = None,
                                 score_bias: jnp.ndarray | None = None,
@@ -975,7 +1137,8 @@ class Solver:
     # non-donated: they alias the resident mirror / the sliced batch.)
     @functools.partial(jax.jit, static_argnums=(0, 5), donate_argnums=(6,))
     def _solve_scan(self, b: DeviceBatch, c: DeviceCluster,
-                    last_node_index: jnp.ndarray, score_bias: jnp.ndarray,
+                    last_node_index: jnp.ndarray | None,
+                    score_bias: jnp.ndarray | None,
                     flags: BatchFlags = ALL_ON_FLAGS,
                     carry: dict | None = None,
                     live: jnp.ndarray | None = None,
@@ -990,7 +1153,11 @@ class Solver:
         every chunk carries the same state shape.  ``extra_mask`` [P,N] is
         an additional hard feasibility plane (workload constraints —
         topology spread's DoNotSchedule terms); None compiles it away.
+        A PackedBatch brings ``last_node_index`` / ``live`` / the two
+        planes in its buffers; pass None for what rides there.
         Returns (choices [P], counter, final state dict)."""
+        b, last_node_index, score_bias, live, extra_mask = unpack_launch(
+            b, last_node_index, score_bias, live, extra_mask)
         c = widen_cluster(c)
         n = c.alloc.shape[0]
         p = b.request.shape[0]
@@ -1699,6 +1866,7 @@ class Solver:
 
         Returns (score_bias [P, N] = -price cost, repair-order key [P]).
         """
+        b = unpack_batch(b)
         c = widen_cluster(c)
         feasible, scores = self.evaluate(b, c)
         if extra_mask is not None:
@@ -1748,7 +1916,7 @@ class Solver:
     # would invalidate it for the next drain's scatter)
     @functools.partial(jax.jit, static_argnums=(0, 7, 8))
     def _solve_joint_jit(self, b: DeviceBatch, c: DeviceCluster,
-                         last_node_index: jnp.ndarray,
+                         last_node_index: jnp.ndarray | None,
                          extra_mask: jnp.ndarray | None,
                          score_bias: jnp.ndarray | None,
                          live: jnp.ndarray | None,
@@ -1763,6 +1931,8 @@ class Solver:
         cache could amortize as a unit.  One trace means one XLA program,
         persisted once, deserialized on every later start
         (tests/test_joint_solver.py pins the cold-vs-warm gap)."""
+        b, last_node_index, score_bias, live, extra_mask = unpack_launch(
+            b, last_node_index, score_bias, live, extra_mask)
         c = widen_cluster(c)
         bias, key = self._price_iterate(b, c, n_iters, extra_mask)
         if score_bias is not None:
@@ -1779,7 +1949,7 @@ class Solver:
         return jnp.take(choices_p, inv), counter, final
 
     def solve_joint(self, b: DeviceBatch, c: DeviceCluster,
-                    last_node_index: jnp.ndarray, n_iters: int = 24,
+                    last_node_index: jnp.ndarray | None, n_iters: int = 24,
                     flags: BatchFlags | None = None,
                     extra_mask: jnp.ndarray | None = None,
                     score_bias: jnp.ndarray | None = None,

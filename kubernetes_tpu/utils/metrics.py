@@ -595,6 +595,7 @@ BATCH_DEADLINE_MISSES = register(Counter(
 DEVICE_TRANSFER_BYTES = register(Counter(
     "scheduler_device_transfer_bytes_total",
     "Bytes moved between host and device by the drain path, by cause: "
+    "batch (the pod batch's packed buffers, once per chunk), "
     "scatter (dirty-row updates into the resident cluster mirror), "
     "full_upload (whole-cluster re-snapshot on relist/capacity growth), "
     "readback (device->host result fetches)",
@@ -603,6 +604,13 @@ DEVICE_TRANSFERS = register(Counter(
     "scheduler_device_transfers_total",
     "Host<->device transfer operations by cause (same label set as the "
     "bytes counter; bytes/ops is the mean transfer size)",
+    labelnames=("cause",)))
+DEVICE_TRANSFER_ARRAYS = register(Counter(
+    "scheduler_device_transfer_arrays_total",
+    "Host arrays handed to the runtime by the uploads, by cause (batch/"
+    "scatter/full_upload): each is a trip through the interpreter's "
+    "lock on the launch thread; arrays{cause=batch} per launch is 3 "
+    "with the packed wire form",
     labelnames=("cause",)))
 DEVICE_HBM_LIVE_BYTES = register(Gauge(
     "scheduler_device_hbm_live_bytes",

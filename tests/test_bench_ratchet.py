@@ -128,6 +128,13 @@ def test_transfer_bytes_per_pod_regression_fails():
         [("BENCH_r08.json", _parsed(p50=1.0, device=_device())),
          ("BENCH_r09.json", _parsed(p50=1.0, device=_device(
              scatter=80.0, readback=60.0)))]) == []
+    # A cause the older artifact never counted (the batch's packed
+    # upload, counted since PR 31) is new accounting, not a regression.
+    counted = _device()
+    counted["bytes_per_pod"]["batch"] = 3000.0
+    assert cb.check(
+        [("BENCH_r08.json", _parsed(p50=1.0, device=_device())),
+         ("BENCH_r09.json", _parsed(p50=1.0, device=counted))]) == []
 
 
 def test_artifacts_predating_device_columns_ratchet_nothing():

@@ -838,8 +838,11 @@ def check_device(artifacts: list[tuple[str, dict]],
     prev_name = artifacts[-2][0]
     prev_bpp = prev_dev.get("bytes_per_pod") or {}
     new_bpp = dev.get("bytes_per_pod") or {}
+    # Cause for cause: one the older artifact never counted (PR 31's
+    # ``batch``: the pod batch's bytes crossed before, uncounted) is new
+    # accounting, not new traffic.
     prev_total = sum(v for v in prev_bpp.values() if v)
-    new_total = sum(v for v in new_bpp.values() if v)
+    new_total = sum(v for c, v in new_bpp.items() if v and c in prev_bpp)
     if prev_total and new_total > prev_total * (1.0 + tolerance):
         problems.append(
             f"device transfer bytes-per-pod regressed: {new_name} "
