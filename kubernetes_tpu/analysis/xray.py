@@ -96,8 +96,6 @@ CANON = {
     # (NarrowCluster i16/u8 planes), so a plane silently widening back
     # to int32 storage IS manifest drift.
     "feature_bits": {"float": 32, "int": 32},
-    # Canonical resident-plane dtype policy (KT_FEATURE_DTYPE default).
-    "feature_dtype": "narrow",
 }
 
 _DTYPE_SHORT = {
@@ -497,25 +495,20 @@ def build_context() -> Context:
     eng = GenericScheduler(cache=cache)
     pods = [api.Pod(name="__xray-0", namespace="__xray__")]
     batch, hb, hc, _nt = eng._compile(pods, host_only=True)
-    # The manifested cluster avals are the NARROW wire form when the
-    # canonical dtype policy says so (CANON["feature_dtype"]) — the
-    # committed manifest is the proof the narrowing holds: a plane
-    # widening back to int32 storage changes in_avals and drifts.
-    if CANON["feature_dtype"] == "narrow":
-        with cache.lock:
-            nt, agg, _ep, _nodes = cache.snapshot()
-        policy = sv.narrow_policy(nt, agg, cache.space, mode="narrow")
-        if policy is not None:
-            hc = sv.narrow_cluster(hc, policy)
+    # The manifested cluster avals are the NARROW wire form the resident
+    # mirror uploads — the committed manifest is the proof the narrowing
+    # holds: a plane widening back to int32 storage changes in_avals and
+    # drifts.
+    with cache.lock:
+        nt, agg, _ep, _nodes = cache.snapshot()
+    hc = sv.narrow_cluster(hc, sv.narrow_policy(nt, agg, cache.space))
     # A FRESH solver (not the process-shared registry instance), with
     # the env-derived MaxPD caps pinned to their provider defaults: the
     # caps are compile-time constants baked into the jaxprs, and a
     # KUBE_MAX_PD_VOLS leak in some earlier test of the same process
-    # must not make the committed manifest look drifted.  The fused
-    # scan body is pinned the same way (KT_FUSED=0 in the running
-    # process must not move the committed surface).
+    # must not make the committed manifest look drifted.
     import jax.numpy as jnp
-    solver = sv.Solver(eng.policy, fused=True)
+    solver = sv.Solver(eng.policy)
     solver._half_dtype = jnp.float16  # canonical, backend-independent
     solver.extra = {"max_ebs": DEFAULT_MAX_EBS_VOLUMES,
                     "max_gce": DEFAULT_MAX_GCE_PD_VOLUMES}
@@ -618,13 +611,12 @@ def program_builders(ctx: Context) -> dict[str, tuple[str, Callable,
         _sds((), np.int32)))
 
     t, d = CANON["topo_terms"], CANON["topo_domains"]
-    # topo_dom arrives in the resident mirror's narrow form (int16 under
-    # the canonical dtype policy) — the topology kernel is the one
-    # narrow-plane consumer outside the widening entrypoints, so its
-    # manifested aval must match the live dispatch or the first live
-    # spread solve would mint an unmanifested shape.
-    topo_dtype = np.int16 if CANON["feature_dtype"] == "narrow" \
-        else np.int32
+    # topo_dom arrives in the resident mirror's narrow form (int16 at
+    # the canonical size) — the topology kernel is the one narrow-plane
+    # consumer outside the widening entrypoints, so its manifested aval
+    # must match the live dispatch or the first live spread solve would
+    # mint an unmanifested shape.
+    topo_dtype = ctx.cluster.topo_dom.dtype
     progs["topo_planes"] = ("topo_planes", raw_planes, (
         _sds((t,), np.int32), _sds((t,), np.float32),
         _sds((t,), np.bool_), _sds((t, d), np.float32),

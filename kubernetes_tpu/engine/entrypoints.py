@@ -63,10 +63,9 @@ ENTRYPOINTS: tuple[EntrySpec, ...] = (
         "scan_first", "stream", (f"{_SOLVER}:_solve_scan",),
         f"{_GS}:schedule_batch_stream", True,
         "First stream chunk / one-shot sequential solve: the scan with "
-        "no carried state, live-mask padded to a ladder bucket (the "
-        "fused body under KT_FUSED — packed aggregates, template "
-        "score planes, fused select; the canonical manifest records "
-        "the fused jaxpr)."),
+        "no carried state, live-mask padded to a ladder bucket "
+        "(packed aggregates, template score planes, one select per "
+        "step)."),
     EntrySpec(
         "scan_carry", "stream", (f"{_SOLVER}:_solve_scan",),
         f"{_GS}:schedule_batch_stream", True,

@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import functools
 import os
-import sys
 import time
 from dataclasses import dataclass, field
 from typing import Iterator, Optional
@@ -164,11 +163,6 @@ class GenericScheduler:
         # Spread-constraint term tables for the batch _compile last saw
         # (None = no pod carried topologySpreadConstraints).
         self._topo_terms = None
-        # Stream-path debug prints, read ONCE at engine init: the old
-        # per-drain env read ran twice per streamed drain (a ktlint D04
-        # hot-path finding — the KT_STREAM_MIN_BUCKET bug class).
-        from kubernetes_tpu.utils import knobs
-        self._stream_debug = knobs.get_bool("KT_STREAM_DEBUG")
 
     def _pinned_flags(self, batch) -> sv.BatchFlags:
         """Content flags OR-ed monotonically (padcap's discipline for the
@@ -811,7 +805,6 @@ class GenericScheduler:
         if padded > p:
             all_pods += [api.Pod(name=f"__pad-{i}", namespace="__pad__")
                          for i in range(padded - p)]
-        t_c0 = time.perf_counter()
         with self.guard.watch("stream", inject=False):
             batch, hb, dc, nt = self._compile(all_pods, device=False)
         flags = self._pinned_flags(batch)
@@ -826,20 +819,6 @@ class GenericScheduler:
                                                    dc.topo_dom)
             topo_mask_np = None if tmask is None else np.asarray(tmask)
             topo_score_np = None if tscore is None else np.asarray(tscore)
-        if self._stream_debug:
-            shapes = {f: tuple(getattr(hb, f).shape)
-                      for f in ("sel_required", "spread_node_counts",
-                                "avoid_rows")}
-            shapes.update({f: tuple(getattr(hb.aff, f).shape)
-                           for f in ("match_cnt", "decl_reach", "sym_cnt",
-                                     "node_dom")})
-            shapes.update({f: tuple(getattr(hb.volsvc, f).shape)
-                           for f in ("pd_pod_ebs", "pd_pod_gce", "vz_mask",
-                                     "sa_mask", "saa_cnt",
-                                     "nl_prio_rows")})
-            print(f"stream-debug compile({len(all_pods)} pods): "
-                  f"{time.perf_counter() - t_c0:.3f}s flags={tuple(flags)} "
-                  f"shapes={shapes}", file=sys.stderr)
         n = sv.cluster_nodes(dc)
         # The first chunk's tie counter rides its packed batch; from the
         # second on it is the device scalar the previous scan returned.
@@ -874,9 +853,7 @@ class GenericScheduler:
                           for c in rows[: stop - start]]
             return chunk_pods, placements
 
-        debug_t = self._stream_debug
         for start in range(0, padded, chunk_size):
-            t0 = time.perf_counter() if debug_t else 0.0
             # Host-slice (free numpy views), pack the fixed
             # [chunk_size, ...] leaves with the chunk's live mask (and the
             # counter, and the planes) into three buffers, then ONE
@@ -904,8 +881,6 @@ class GenericScheduler:
                     stage("solve", chunk_at=start, mode="stream"):
                 choices_k, counter, carry = self.solver._solve_scan(
                     db_k, dc, counter, None, flags, carry)
-            if debug_t:
-                t1 = time.perf_counter()
             pending.append((start, choices_k))
             if len(pending) > 1:
                 s_k, c_k = pending.pop(0)
@@ -914,10 +889,6 @@ class GenericScheduler:
                            functools.partial(emit, s_k, c_k))
                 else:
                     yield emit(s_k, c_k)
-            if debug_t:
-                print(f"stream-debug chunk@{start}: put+launch "
-                      f"{t1 - t0:.3f}s emit {time.perf_counter() - t1:.3f}s",
-                      file=sys.stderr)
         for start, choices_k in pending:
             if defer_readback:
                 yield (pods[start:min(start + chunk_size, p)],

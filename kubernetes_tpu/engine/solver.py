@@ -36,7 +36,6 @@ import numpy as np
 
 from kubernetes_tpu.utils import knobs
 from kubernetes_tpu.utils.trace import stage
-from kubernetes_tpu.engine import fused as fused_mod
 from kubernetes_tpu.api.policy import (DEFAULT_MAX_EBS_VOLUMES,
                                        DEFAULT_MAX_GCE_PD_VOLUMES, Policy,
                                        canonical_predicate_name,
@@ -70,16 +69,10 @@ DYNAMIC_PRIORITIES = ("LeastRequestedPriority", "MostRequestedPriority",
                       "ServiceAntiAffinityPriority")
 PASSTHROUGH_PRIORITIES = ()
 
-# lax.scan unroll for the sequential solve: measured on v5e at 30k x 5k,
-# unroll=4 runs the scan ~1.2x faster than unroll=1 (705 -> 605 ms) by
-# amortizing loop control and xs slicing.  Compile time scales with the
-# factor; 4 is the knee.
-SCAN_UNROLL = knobs.get_int("KT_SCAN_UNROLL")
-# Fused scan-step default (KT_FUSED; per-Solver override for tests).
-FUSED_DEFAULT = knobs.get_bool("KT_FUSED")
-# Resident-plane dtype policy: "narrow" = range-gated int16 wire/HBM
-# planes (mem columns stay int32), "wide" = the pre-r15 all-int32 form.
-FEATURE_DTYPE = knobs.get_str("KT_FEATURE_DTYPE")
+# lax.scan unroll for the sequential solve: unrolling amortizes loop
+# control and xs slicing over several steps; compile time grows with the
+# factor.
+SCAN_UNROLL = 4
 # Cap on distinct nonzero-request templates factored out of the scan.
 DYN_TEMPLATE_CAP = knobs.get_int("KT_DYN_TEMPLATES")
 
@@ -222,6 +215,28 @@ def batch_flags(b: "PodBatch | DeviceBatch | PackedBatch") -> BatchFlags:
 ALL_ON_FLAGS = BatchFlags(*([True] * 9))
 
 
+class ScanFamilies(NamedTuple):
+    """What one compiled scan checks, scores and carries per step: the
+    policy's dynamic predicates and priorities, less the families whose
+    inputs the batch's BatchFlags rule out.  Settled at trace time
+    (``Solver._scan_families``); everything left out is hoisted to a
+    batch-start plane, exact because its state cannot move mid-scan."""
+
+    resources: bool
+    ports: bool
+    volumes: bool
+    interpod: bool
+    max_ebs: bool
+    max_gce: bool
+    in_scan_preds: frozenset    # predicate names the step evaluates
+    static_prios: tuple         # (name, weight, aux) hoisted
+    dynamic_prios: tuple        # (name, weight, aux) scored per step
+    track_affinity: bool
+    track_spread: bool
+    track_spread_zones: bool
+    track_saa: bool
+
+
 class DeviceCluster(NamedTuple):
     schedulable: jnp.ndarray    # [N] bool — getNodeConditionPredicate
     alloc: jnp.ndarray          # [N,4] int32
@@ -245,11 +260,10 @@ class DeviceCluster(NamedTuple):
 
 
 class NarrowCluster(NamedTuple):
-    """The wire/residency form of DeviceCluster under the narrow dtype
-    policy (KT_FEATURE_DTYPE=narrow): the int32 resource planes are
-    re-laid as a range-gated int16 matrix plus an always-int32 memory
-    matrix (node memory in MiB routinely exceeds int16 — 32 GiB is
-    already 32768), the three pressure/taint bits pack into one uint8
+    """The wire/residency form of DeviceCluster: the int32 resource
+    planes are re-laid as a range-gated int16 matrix plus an always-int32
+    memory matrix (node memory in MiB routinely exceeds int16 — 32 GiB
+    is already 32768), the three pressure/taint bits pack into one uint8
     plane, and the id planes (topology domains, image KiB) narrow to
     int16 when their value ranges allow.  ``widen_cluster`` reconstructs
     the exact DeviceCluster at the top of every jitted entrypoint, so
@@ -289,17 +303,11 @@ _I16_GATE = 32000
 
 
 def narrow_policy(nt: "NodeTensors", agg: "NodeAggregates",
-                  space: "FeatureSpace",
-                  mode: Optional[str] = None) -> Optional[DtypePolicy]:
-    """The dtype policy for THIS host state, or None when the wide
-    policy is configured.  Range checks read the live arrays (cheap
-    numpy maxima), so adversarial states — overcommitted aggregates
-    ingested from a relist, a 64-core node — fall back to int32 for
-    that signature instead of wrapping.  ``mode`` overrides the
-    KT_FEATURE_DTYPE default (kt-xray's canonical build must not read
-    the environment)."""
-    if (mode or FEATURE_DTYPE) != "narrow":
-        return None
+                  space: "FeatureSpace") -> DtypePolicy:
+    """The dtype policy for THIS host state.  Range checks read the live
+    arrays (cheap numpy maxima), so adversarial states — overcommitted
+    aggregates ingested from a relist, a 64-core node — fall back to
+    int32 for that signature instead of wrapping."""
     cols = [nt.alloc[:, (0, 2, 3)], agg.requested[:, (0, 2, 3)],
             agg.nonzero[:, :1]]
     res_max = max(int(a.max()) if a.size else 0 for a in cols)
@@ -609,7 +617,7 @@ class ResidentCluster:
     FULL_FRACTION = 4  # dirty rows > N/4 -> full upload wins
 
     def __init__(self):
-        self.dc: DeviceCluster | NarrowCluster | None = None
+        self.dc: NarrowCluster | None = None
         self._sig = None
         self._epoch = None
         self._scatter = None
@@ -654,7 +662,7 @@ class ResidentCluster:
         # widen_cluster — the ONE authoritative narrow->wide layout
         # (hand-stacking columns here would be a third copy of the
         # res16/mem32 packing that could silently drift from the
-        # encode/decode pair).  Identity for a wide mirror.
+        # encode/decode pair).
         rows = widen_cluster(type(self.dc)(*[arr[i] for arr in self.dc]))
         out = {"schedulable": np.asarray(rows.schedulable),
                "alloc": np.asarray(rows.alloc),
@@ -741,13 +749,12 @@ class ResidentCluster:
 
     def sync(self, nt: NodeTensors, agg: NodeAggregates,
              space: FeatureSpace, dirty: set[int],
-             epoch: int) -> "DeviceCluster | NarrowCluster":
+             epoch: int) -> NarrowCluster:
         """The current cluster state on device: scatter ``dirty`` rows
         into the resident arrays, or re-upload everything when the
-        resident copy cannot be patched (see class docstring).  Under
-        the narrow dtype policy both the upload and the scattered rows
-        travel in the NarrowCluster wire form; the jitted entrypoints
-        widen on device."""
+        resident copy cannot be patched (see class docstring).  Both
+        the upload and the scattered rows travel in the NarrowCluster
+        wire form; the jitted entrypoints widen on device."""
         from kubernetes_tpu.engine import devicestats
         n = nt.alloc.shape[0]
         policy = narrow_policy(nt, agg, space)
@@ -756,9 +763,7 @@ class ResidentCluster:
                 or len(dirty) * self.FULL_FRACTION >= max(n, 1):
             with stage("transfer.full"):
                 host = _host_cluster(nt, agg, space)
-                self.dc = jax.device_put(
-                    host if policy is None
-                    else narrow_cluster(host, policy))
+                self.dc = jax.device_put(narrow_cluster(host, policy))
             self._sig = sig
             self._epoch = epoch
             self.stats["full_syncs"] += 1
@@ -792,7 +797,7 @@ class ResidentCluster:
     @staticmethod
     def _gather_rows(nt: NodeTensors, agg: NodeAggregates,
                      space: FeatureSpace, dirty: set[int],
-                     policy: Optional[DtypePolicy]) -> tuple:
+                     policy: DtypePolicy) -> tuple:
         """``(idx, rows)`` on the host: the dirty rows in the wire form,
         padded to their pow2 bucket."""
         idx = np.fromiter(dirty, np.int32, len(dirty))
@@ -820,8 +825,7 @@ class ResidentCluster:
             image_kib=_pad_cols(nt.image_kib[idx], space.images.capacity),
             topo_dom=_pad_cols(nt.topo_val[idx],
                                space.topo_keys.capacity, fill=-1))
-        if policy is not None:
-            rows = narrow_cluster(rows, policy)
+        rows = narrow_cluster(rows, policy)
         pad = 1 << (len(dirty) - 1).bit_length()
         if pad > len(dirty):
             extra = pad - len(dirty)
@@ -958,7 +962,7 @@ class Solver:
     def for_policy(cls, policy: Policy) -> "Solver":
         candidate = cls(policy)
         key = (candidate.predicate_names, candidate.priority_specs,
-               tuple(sorted(candidate.extra.items())), candidate._fused)
+               tuple(sorted(candidate.extra.items())))
         with cls._registry_lock:
             existing = cls._registry.get(key)
             if existing is not None:
@@ -966,12 +970,8 @@ class Solver:
             cls._registry[key] = candidate
             return candidate
 
-    def __init__(self, policy: Policy,
-                 fused: Optional[bool] = None):
+    def __init__(self, policy: Policy):
         self.policy = policy
-        # Fused scan-step selection, resolved once per Solver (KT_FUSED
-        # default; tests pass fused=False to pin the legacy body).
-        self._fused = FUSED_DEFAULT if fused is None else fused
         # Half-width encoded-score dtype (resolved once with the
         # backend): bf16 on TPU, f16 — wider mantissa, so a larger
         # exact-integer range — elsewhere.
@@ -1111,12 +1111,9 @@ class Solver:
 
     @staticmethod
     def _final_aggregates(final: dict) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """(requested [N,4], nonzero [N,2]) from a scan's final state —
-        the fused body carries them as one packed [N,6] matrix (a single
-        scatter-add per step), the legacy body as two planes."""
-        if "packed" in final:
-            return final["packed"][:, :4], final["packed"][:, 4:6]
-        return final["requested"], final["nonzero"]
+        """(requested [N,4], nonzero [N,2]) from a scan's final state,
+        which carries them as one packed [N,6] matrix."""
+        return final["packed"][:, :4], final["packed"][:, 4:6]
 
     @staticmethod
     def _carry_cluster(c: "DeviceCluster | NarrowCluster",
@@ -1128,6 +1125,51 @@ class Solver:
             ports_used=final.get("ports_used", c.ports_used),
             vol_any=final.get("vol_any", c.vol_any),
             vol_rw=final.get("vol_rw", c.vol_rw))
+
+    def _scan_families(self, flags: BatchFlags) -> ScanFamilies:
+        """The scan's trace-time specialization for this policy and these
+        content flags."""
+        # Dynamic predicate -> can this batch move the state it reads?
+        gates = {"PodFitsResources": True,
+                 "PodFitsHostPorts": flags.any_ports,
+                 "PodFitsPorts": flags.any_ports,
+                 "NoDiskConflict": flags.any_volumes,
+                 "MatchInterPodAffinity": flags.any_affinity_pred,
+                 "MaxEBSVolumeCount": flags.any_ebs,
+                 "MaxGCEPDVolumeCount": flags.any_gce}
+        in_scan_preds = frozenset(name for name in self.predicate_names
+                                  if gates.get(name, False))
+        static_prios, dynamic_prios = [], []
+        for spec in self.priority_specs:
+            name = spec[0]
+            in_scan = name in DYNAMIC_PRIORITIES
+            if name in ("SelectorSpreadPriority", "ServiceSpreadingPriority"):
+                in_scan = flags.any_spread
+            elif name == "InterPodAffinityPriority":
+                in_scan = flags.any_affinity_prio
+            elif name == "ServiceAntiAffinityPriority":
+                # No batch pod joins any scored service group: counts are
+                # provably constant, the batch-start plane is exact.
+                in_scan = flags.any_saa
+            (dynamic_prios if in_scan else static_prios).append(spec)
+        scored = {name for name, _w, _aux in dynamic_prios}
+        track_spread = bool(scored & {"SelectorSpreadPriority",
+                                      "ServiceSpreadingPriority"})
+        return ScanFamilies(
+            resources="PodFitsResources" in in_scan_preds,
+            ports=bool(in_scan_preds & {"PodFitsHostPorts", "PodFitsPorts"}),
+            volumes="NoDiskConflict" in in_scan_preds,
+            interpod="MatchInterPodAffinity" in in_scan_preds,
+            max_ebs="MaxEBSVolumeCount" in in_scan_preds,
+            max_gce="MaxGCEPDVolumeCount" in in_scan_preds,
+            in_scan_preds=in_scan_preds,
+            static_prios=tuple(static_prios),
+            dynamic_prios=tuple(dynamic_prios),
+            track_affinity="MatchInterPodAffinity" in in_scan_preds
+            or "InterPodAffinityPriority" in scored,
+            track_spread=track_spread,
+            track_spread_zones=track_spread and flags.any_spread_zones,
+            track_saa="ServiceAntiAffinityPriority" in scored)
 
     # kt-xray: donate(donate_argnums=(6,) — the carry: each chunk's
     # final state is consumed exactly once, by the next chunk's launch;
@@ -1155,7 +1197,21 @@ class Solver:
         topology spread's DoNotSchedule terms); None compiles it away.
         A PackedBatch brings ``last_node_index`` / ``live`` / the two
         planes in its buffers; pass None for what rides there.
-        Returns (choices [P], counter, final state dict)."""
+        Returns (choices [P], counter, final state dict).
+
+        The step is built for per-step cost:
+
+        * the hoisted mask/score planes merge into ONE encoded plane
+          (``-inf`` = statically infeasible), so each step slices one
+          row and folds dynamic feasibility with a single ``where``;
+        * ``requested``+``nonzero`` carry as one packed [N,6] matrix
+          committed by a single one-row scatter-add;
+        * spread/zone counts commit by one-column scatter-adds;
+          port/volume/PD planes by one-row updates;
+        * the nz-only dynamic priorities (least/most-requested,
+          balanced) are template-factored (``_template_col``);
+        * mask -> score -> tie-break -> select is
+          ``combine.select_host`` — three node-axis reductions."""
         b, last_node_index, score_bias, live, extra_mask = unpack_launch(
             b, last_node_index, score_bias, live, extra_mask)
         c = widen_cluster(c)
@@ -1167,32 +1223,10 @@ class Solver:
         # priority planes are the big vocab contractions.  A policy-dynamic
         # predicate whose inputs are absent from this batch (flags) is
         # hoisted too — its mask and state provably never change mid-scan.
-        use_resources = "PodFitsResources" in self.predicate_names
-        use_ports = flags.any_ports and any(
-            nm in self.predicate_names
-            for nm in ("PodFitsHostPorts", "PodFitsPorts"))
-        use_volumes = flags.any_volumes and \
-            "NoDiskConflict" in self.predicate_names
-        use_interpod = flags.any_affinity_pred and \
-            "MatchInterPodAffinity" in self.predicate_names
-        use_max_ebs = flags.any_ebs and \
-            "MaxEBSVolumeCount" in self.predicate_names
-        use_max_gce = flags.any_gce and \
-            "MaxGCEPDVolumeCount" in self.predicate_names
-        in_scan_preds = {"PodFitsResources"} if use_resources else set()
-        if use_ports:
-            in_scan_preds |= {"PodFitsHostPorts", "PodFitsPorts"}
-        if use_volumes:
-            in_scan_preds.add("NoDiskConflict")
-        if use_interpod:
-            in_scan_preds.add("MatchInterPodAffinity")
-        if use_max_ebs:
-            in_scan_preds.add("MaxEBSVolumeCount")
-        if use_max_gce:
-            in_scan_preds.add("MaxGCEPDVolumeCount")
+        fam = self._scan_families(flags)
         static_mask = jnp.broadcast_to(c.schedulable[None, :], (p, n))
         for name in self.predicate_names:
-            if name not in in_scan_preds:
+            if name not in fam.in_scan_preds:
                 static_mask &= _predicate_mask(name, b, c, n, self.extra)
         if live is not None:
             # Chunk padding: dead rows are infeasible everywhere, place
@@ -1206,352 +1240,26 @@ class Solver:
         # which XLA elides — callers avoid materializing a [P,N] zeros arg.
         static_score = score_bias if score_bias is not None \
             else jnp.zeros((p, n), jnp.float32)
-        dynamic_prios = []
-        for name, weight, aux in self.priority_specs:
-            in_scan = name in DYNAMIC_PRIORITIES
-            if name in ("SelectorSpreadPriority", "ServiceSpreadingPriority"):
-                in_scan = flags.any_spread
-            elif name == "InterPodAffinityPriority":
-                in_scan = flags.any_affinity_prio
-            elif name == "ServiceAntiAffinityPriority":
-                # No batch pod joins any scored service group: counts are
-                # provably constant, the batch-start plane is exact.
-                in_scan = flags.any_saa
-            if in_scan:
-                dynamic_prios.append((name, weight, aux))
-            else:
-                static_score += jnp.float32(weight) * \
-                    _priority_plane(name, b, c, n, {"aux": aux})
-        dynamic_prios = tuple(dynamic_prios)
-        use_interpod_prio = any(nm == "InterPodAffinityPriority"
-                                for nm, _, _ in dynamic_prios)
-        track_affinity = use_interpod or use_interpod_prio
-        track_spread = any(nm in ("SelectorSpreadPriority",
-                                  "ServiceSpreadingPriority")
-                           for nm, _, _ in dynamic_prios)
-        track_spread_zones = track_spread and flags.any_spread_zones
-        track_saa = any(nm == "ServiceAntiAffinityPriority"
-                        for nm, _, _ in dynamic_prios)
+        for name, weight, aux in fam.static_prios:
+            static_score += jnp.float32(weight) * \
+                _priority_plane(name, b, c, n, {"aux": aux})
 
-        fits_pods_alloc = c.alloc[:, RES_PODS]
-        zone_ids = b.node_zone_id  # [N]
-        f32 = jnp.float32
-
-        if self._fused:
-            return self._fused_scan(
-                b, c, last_node_index, static_mask, static_score, carry,
-                live, score_bias is not None, dict(
-                    use_resources=use_resources, use_ports=use_ports,
-                    use_volumes=use_volumes, use_interpod=use_interpod,
-                    use_max_ebs=use_max_ebs, use_max_gce=use_max_gce,
-                    track_affinity=track_affinity,
-                    track_spread=track_spread,
-                    track_spread_zones=track_spread_zones,
-                    track_saa=track_saa),
-                dynamic_prios)
-
-        def step(state, xs):
-            counter = state["counter"]
-
-            # Dynamic predicates on current aggregates (predicates.go:444-485,
-            # :721-741, :100-153) — O(N) per step.
-            feasible = xs["smask"]
-            if use_resources:
-                requested = state["requested"]
-                fits_pods = (requested[:, RES_PODS] + 1) <= fits_pods_alloc
-                free = c.alloc[:, :3] - requested[:, :3]
-                fits_res = jnp.all(xs["req"][None, :3] <= free, axis=-1)
-                feasible &= fits_pods & (xs["zero"] | fits_res)
-            if use_ports:
-                port_conflict = jnp.einsum(
-                    "c,nc->n", xs["ports"].astype(f32),
-                    state["ports_used"].astype(f32)) > 0
-                feasible &= ~port_conflict
-            if use_volumes:
-                vol_conflict = (
-                    jnp.einsum("w,nw->n", xs["vrw"].astype(f32),
-                               state["vol_any"].astype(f32)) +
-                    jnp.einsum("w,nw->n", xs["vro"].astype(f32),
-                               state["vol_rw"].astype(f32))) > 0
-                feasible &= ~vol_conflict
-            for fam in ("ebs", "gce") if (use_max_ebs or use_max_gce) else ():
-                if (fam == "ebs" and not use_max_ebs) or \
-                        (fam == "gce" and not use_max_gce):
-                    continue
-                pd_node = state[f"pd_{fam}"]
-                pod_row = xs[f"pd_pod_{fam}"].astype(f32)
-                overlap = jnp.einsum("w,nw->n", pod_row, pd_node.astype(f32))
-                new = jnp.sum(pod_row) + xs[f"pd_extra_{fam}"].astype(f32)
-                node_extra = getattr(b.volsvc, f"pd_node_extra_{fam}")
-                node_err = getattr(b.volsvc, f"pd_node_err_{fam}")
-                total = jnp.sum(pd_node.astype(f32), axis=1) + \
-                    node_extra.astype(f32) + new - overlap
-                ok = (total <= f32(self.extra[f"max_{fam}"])) & ~node_err
-                feasible &= (new == 0) | ok
-            if track_affinity:
-                reach = state["match_cnt"] > 0.0  # [Sm, N]
-            if use_interpod:
-                # MatchInterPodAffinity for one pod against current state
-                # (predicates.go:825-853 with the self-match escape hatch).
-                live = xs["aff_need"] & ~(xs["aff_self"] &
-                                          (state["match_total"] == 0.0))
-                viol = (jnp.einsum("s,sn->n", live.astype(f32),
-                                   (~reach).astype(f32)) +
-                        jnp.einsum("s,sn->n", xs["anti_need"].astype(f32),
-                                   reach.astype(f32)) +
-                        jnp.einsum("s,sn->n", xs["decl_match"].astype(f32),
-                                   state["decl_reach"].astype(f32))) > 0
-                feasible &= ~viol
-
-            # Dynamic priorities against current aggregates.
-            score = xs["sscore"]
-            for name, weight, aux in dynamic_prios:
-                w = f32(weight)
-                if name == "LeastRequestedPriority":
-                    score = score + w * prio.least_requested(
-                        xs["nz"][None], state["nonzero"], c.alloc)[0]
-                elif name == "MostRequestedPriority":
-                    score = score + w * prio.most_requested(
-                        xs["nz"][None], state["nonzero"], c.alloc)[0]
-                elif name == "BalancedResourceAllocation":
-                    score = score + w * prio.balanced_resource_allocation(
-                        xs["nz"][None], state["nonzero"], c.alloc)[0]
-                elif name in ("SelectorSpreadPriority",
-                              "ServiceSpreadingPriority"):
-                    if track_spread_zones:
-                        score = score + w * prio.selector_spread(
-                            xs["sgroup"][None], state["sp_node"],
-                            state["sp_zone"], b.spread_has_zones,
-                            zone_ids, c.schedulable)[0]
-                    else:
-                        # No zone-aware spread group in the batch: the
-                        # blended arm is provably never taken.
-                        score = score + w * prio.selector_spread_node_only(
-                            xs["sgroup"][None], state["sp_node"],
-                            c.schedulable)[0]
-                elif name == "InterPodAffinityPriority":
-                    counts = interpod.priority_counts(
-                        xs["pref_w"][None], state["match_cnt"],
-                        xs["sym_match"][None], a.sym_w, state["sym_cnt"])
-                    score = score + w * interpod.priority_score(
-                        counts, c.schedulable, prio._trunc)[0]
-                elif name == "ServiceAntiAffinityPriority":
-                    # Live per-domain peer counts (selector_spreading.go
-                    # would re-list the service's pods on every decision;
-                    # the scan carries the counts instead).
-                    score = score + w * saa_plane(
-                        state["saa_cnt"][aux][xs["saa_g"]][None],
-                        state["saa_num"][xs["saa_g"]][None, None],
-                        b.volsvc.saa_dom[aux],
-                        b.volsvc.saa_labeled[aux])[0]
-
-            # selectHost (generic_scheduler.go:124-141): round-robin among
-            # max-score feasible nodes; counter bumps only on success.
-            neg = f32(-jnp.inf)
-            masked = jnp.where(feasible, score, neg)
-            max_score = jnp.max(masked)
-            any_feasible = jnp.any(feasible)
-            ties = feasible & (masked == max_score)
-            n_ties = jnp.maximum(jnp.sum(ties), 1)
-            ix = (counter % n_ties.astype(jnp.uint32)).astype(jnp.int32)
-            rank = jnp.cumsum(ties.astype(jnp.int32)) - 1
-            choice = jnp.argmax(ties & (rank == ix)).astype(jnp.int32)
-            choice = jnp.where(any_feasible, choice, -1)
-
-            # Commit: the batched AssumePod (cache.go:107).
-            placed = choice >= 0
-            onehot = (jnp.arange(n, dtype=jnp.int32) == choice) & placed
-            oh_i = onehot.astype(jnp.int32)
-            oh_f = onehot.astype(f32)
-            new_state = dict(state)
-            new_state["requested"] = state["requested"] + \
-                oh_i[:, None] * xs["req"][None, :]
-            new_state["nonzero"] = state["nonzero"] + \
-                oh_i[:, None] * xs["nz"][None, :]
-            if use_ports:
-                new_state["ports_used"] = state["ports_used"] | \
-                    (onehot[:, None] & xs["ports"][None, :])
-            if use_volumes:
-                new_state["vol_any"] = state["vol_any"] | \
-                    (onehot[:, None] & (xs["vrw"] | xs["vro"])[None, :])
-                new_state["vol_rw"] = state["vol_rw"] | \
-                    (onehot[:, None] & xs["vrw"][None, :])
-            if track_spread:
-                new_state["sp_node"] = state["sp_node"] + \
-                    xs["incr"].astype(f32)[:, None] * oh_f[None, :]
-                if track_spread_zones:
-                    zid = jnp.where(placed, zone_ids[jnp.clip(choice, 0)], -1)
-                    zoh = (jnp.arange(state["sp_zone"].shape[1],
-                                      dtype=jnp.int32) == zid)
-                    new_state["sp_zone"] = state["sp_zone"] + \
-                        xs["incr"].astype(f32)[:, None] * \
-                        zoh.astype(f32)[None, :]
-            if use_max_ebs:
-                new_state["pd_ebs"] = state["pd_ebs"] | \
-                    (onehot[:, None] & xs["pd_pod_ebs"][None, :])
-            if use_max_gce:
-                new_state["pd_gce"] = state["pd_gce"] | \
-                    (onehot[:, None] & xs["pd_pod_gce"][None, :])
-            if track_saa:
-                # The placed pod joins every matching service's peer set:
-                # totals bump for each joined group, the domain count only
-                # when the chosen node carries the label.
-                src = xs["saa_src"].astype(f32) * placed.astype(f32)  # [Gy]
-                new_state["saa_num"] = state["saa_num"] + src
-                j = jnp.clip(choice, 0)
-                dom_j = b.volsvc.saa_dom[:, j]                  # [L]
-                lab_j = b.volsvc.saa_labeled[:, j] & placed     # [L]
-                n_dom = state["saa_cnt"].shape[2]
-                domoh = ((jnp.arange(n_dom, dtype=jnp.int32)[None, :]
-                          == dom_j[:, None]) & lab_j[:, None]).astype(f32)
-                new_state["saa_cnt"] = state["saa_cnt"] + \
-                    domoh[:, None, :] * src[None, :, None]
-            if track_affinity:
-                (new_state["match_cnt"], new_state["match_total"],
-                 new_state["decl_reach"], new_state["sym_cnt"]) = \
-                    interpod.place_update(
-                        a.node_dom, a.match_key, state["match_cnt"],
-                        state["match_total"], xs["match_src"],
-                        a.decl_key, state["decl_reach"], xs["decl_src"],
-                        a.sym_key, state["sym_cnt"], xs["sym_src"],
-                        choice, placed)
-            new_state["counter"] = counter + \
-                jnp.where(any_feasible, jnp.uint32(1), jnp.uint32(0))
-            return new_state, choice
-
-        init = {
-            "requested": c.requested, "nonzero": c.nonzero,
-            "counter": last_node_index,
-        }
-        xs = {
-            "req": b.request, "zero": b.zero_request, "nz": b.nonzero,
-            "smask": static_mask, "sscore": static_score,
-        }
-        if use_ports:
-            init["ports_used"] = c.ports_used
-            xs["ports"] = b.ports
-        if use_volumes:
-            init["vol_any"] = c.vol_any
-            init["vol_rw"] = c.vol_rw
-            xs["vro"] = b.vol_ro
-            xs["vrw"] = b.vol_rw
-        if track_spread:
-            init["sp_node"] = b.spread_node_counts
-            init["sp_zone"] = b.spread_zone_counts
-            xs["sgroup"] = b.spread_group
-            xs["incr"] = b.spread_incr
-        if track_affinity:
-            init.update(match_cnt=a.match_cnt, match_total=a.match_total,
-                        decl_reach=a.decl_reach, sym_cnt=a.sym_cnt)
-            xs.update(aff_need=a.aff_need, aff_self=a.aff_self,
-                      anti_need=a.anti_need, decl_match=a.decl_match,
-                      match_src=a.match_src, decl_src=a.decl_src,
-                      pref_w=a.pref_w, sym_match=a.sym_match,
-                      sym_src=a.sym_src)
-        if track_saa:
-            init["saa_cnt"] = b.volsvc.saa_cnt
-            init["saa_num"] = b.volsvc.saa_num
-            xs["saa_g"] = b.volsvc.saa_group
-            xs["saa_src"] = b.volsvc.saa_src
-        if use_max_ebs:
-            init["pd_ebs"] = b.volsvc.pd_node_ebs
-            xs["pd_pod_ebs"] = b.volsvc.pd_pod_ebs
-            xs["pd_extra_ebs"] = b.volsvc.pd_extra_ebs
-        if use_max_gce:
-            init["pd_gce"] = b.volsvc.pd_node_gce
-            xs["pd_pod_gce"] = b.volsvc.pd_pod_gce
-            xs["pd_extra_gce"] = b.volsvc.pd_extra_gce
-        if carry is not None:
-            # Continue a previous chunk: carried keys override batch-derived
-            # initial state (same key set — flags come from the full batch).
-            init.update({k: v for k, v in carry.items() if k in init})
-        final, choices = jax.lax.scan(step, init, xs, unroll=SCAN_UNROLL)
-        return choices, final["counter"], final
-
-    # Dynamic priorities whose pod-dependence is ONLY the nonzero-request
-    # row: their per-step [N] score plane is a pure function of
-    # (template, carried aggregates), so the scan can carry one
-    # [templates, N] plane and update a single column per placement
-    # instead of recomputing the whole chain every step.
-    _TEMPLATE_PRIOS = ("LeastRequestedPriority", "MostRequestedPriority",
-                       "BalancedResourceAllocation")
-
-    def _template_col(self, tmpl_prios: tuple, templates: jnp.ndarray,
-                      nz_j: jnp.ndarray, alloc_j: jnp.ndarray
-                      ) -> jnp.ndarray:
-        """[T] — the template-factored score column for one node, from
-        its (new) nonzero aggregates.  EXACTLY the per-step formulas of
-        the legacy scan body, evaluated at a single node."""
-        col = jnp.zeros(templates.shape[0], jnp.float32)
-        for name, weight, _aux in tmpl_prios:
-            w = jnp.float32(weight)
-            if name == "LeastRequestedPriority":
-                col += w * prio.least_requested(
-                    templates, nz_j[None], alloc_j[None])[:, 0]
-            elif name == "MostRequestedPriority":
-                col += w * prio.most_requested(
-                    templates, nz_j[None], alloc_j[None])[:, 0]
-            elif name == "BalancedResourceAllocation":
-                col += w * prio.balanced_resource_allocation(
-                    templates, nz_j[None], alloc_j[None])[:, 0]
-        return col
-
-    def _fused_scan(self, b: DeviceBatch, c: DeviceCluster,
-                    last_node_index: jnp.ndarray,
-                    static_mask: jnp.ndarray, static_score: jnp.ndarray,
-                    carry: dict | None, live: jnp.ndarray | None,
-                    has_bias: bool, fams: dict, dynamic_prios: tuple
-                    ) -> tuple[jnp.ndarray, jnp.ndarray, dict]:
-        """The fused scan body (KT_FUSED, the default) — decision-parity
-        identical to the legacy ``step`` (pinned by
-        tests/test_fused_solver.py against legacy, oracle and the host
-        engine), restructured for per-step cost:
-
-        * the hoisted mask/score planes merge into ONE encoded plane
-          (``-inf`` = statically infeasible), so each step slices one
-          row and folds dynamic feasibility with a single ``where``;
-        * ``requested``+``nonzero`` carry as one packed [N,6] matrix
-          committed by a single one-row scatter-add (the legacy body
-          re-materialized every plane every step);
-        * spread/zone counts commit by one-column scatter-adds;
-          port/volume/PD planes by one-row updates;
-        * the nz-only dynamic priorities (least/most-requested,
-          balanced) are template-factored: a carried [T,N] plane is
-          row-gathered per pod and recomputed for ONE column per
-          placement (``_template_col``);
-        * mask -> score -> tie-break -> select runs through the fused
-          select (engine/fused.py) — three node-axis reductions per
-          step.
-
-        ``live`` and ``extra_mask`` are already folded into
-        ``static_mask`` by the caller."""
-        del live  # folded into static_mask by _solve_scan
-        n = c.alloc.shape[0]
-        a = b.aff
         f32 = jnp.float32
         neg = f32(-jnp.inf)
-        zone_ids = b.node_zone_id
+        zone_ids = b.node_zone_id  # [N]
         fits_pods_alloc = c.alloc[:, RES_PODS]
         alloc3 = c.alloc[:, :3]
-        select = fused_mod.select_xla
-        use_resources = fams["use_resources"]
-        use_ports = fams["use_ports"]
-        use_volumes = fams["use_volumes"]
-        use_interpod = fams["use_interpod"]
-        use_max_ebs = fams["use_max_ebs"]
-        use_max_gce = fams["use_max_gce"]
-        track_affinity = fams["track_affinity"]
-        track_spread = fams["track_spread"]
-        track_spread_zones = fams["track_spread_zones"]
-        track_saa = fams["track_saa"]
-
-        tmpl_prios = tuple(sp for sp in dynamic_prios
+        # Template-factored priorities: a carried [T,N] plane is
+        # row-gathered per pod and recomputed for ONE column per
+        # placement; a batch over the template cap (no templates) scores
+        # them in the step instead.
+        tmpl_prios = tuple(sp for sp in fam.dynamic_prios
                            if sp[0] in self._TEMPLATE_PRIOS)
-        other_prios = tuple(sp for sp in dynamic_prios
+        other_prios = tuple(sp for sp in fam.dynamic_prios
                             if sp[0] not in self._TEMPLATE_PRIOS)
         use_templates = bool(tmpl_prios) and b.nz_templates.shape[0] > 0
         if not use_templates:
-            other_prios = dynamic_prios
+            other_prios = fam.dynamic_prios
             tmpl_prios = ()
 
         # The encoded static plane: score where statically feasible,
@@ -1569,7 +1277,7 @@ class Solver:
         enc = jnp.where(static_mask, static_score, neg)
         weight_bound = sum(abs(w) for _n, w, _a in self.priority_specs) \
             * prio.MAX_PRIORITY
-        if not has_bias:
+        if score_bias is None:
             exact = 256 if self._half_dtype is jnp.bfloat16 else 2048
             if weight_bound < exact:
                 enc = enc.astype(self._half_dtype)
@@ -1579,8 +1287,8 @@ class Solver:
             packed = state["packed"]
             masked = xs["enc"].astype(f32)
 
-            # Dynamic score families (identical formulas to the legacy
-            # body; template-factored ones come from the carried plane).
+            # Dynamic score families (template-factored ones come from
+            # the carried plane).
             if use_templates:
                 masked = masked + state["D"][xs["tmpl"]]
             for name, weight, aux in other_prios:
@@ -1611,7 +1319,7 @@ class Solver:
                         maxn_g > 0,
                         10.0 * ((maxn_g - counts_g)
                                 / jnp.maximum(maxn_g, 1e-9)), 10.0)
-                    if track_spread_zones:
+                    if fam.track_spread_zones:
                         zc_g = state["sp_zone"][g]          # [Z]
                         maxz_g = state["sp_maxz"][g]
                         zs_z = 10.0 * ((maxz_g - zc_g)
@@ -1641,49 +1349,48 @@ class Solver:
                         b.volsvc.saa_dom[aux],
                         b.volsvc.saa_labeled[aux])[0]
 
-            # Dynamic predicates folded into the encoded plane by one
-            # where (legacy: per-family boolean ANDs into `feasible`).
+            # Dynamic predicates (predicates.go:444-485, :721-741,
+            # :100-153) on the current aggregates — O(N) per step — folded
+            # into the encoded plane by one where.
             dyn_ok = None
 
             def also(cond):
                 return cond if dyn_ok is None else (dyn_ok & cond)
 
-            if use_resources:
+            if fam.resources:
                 fits_pods = (packed[:, RES_PODS] + 1) <= fits_pods_alloc
                 free = alloc3 - packed[:, :3]
                 fits_res = jnp.all(xs["req"][None, :3] <= free, axis=-1)
                 dyn_ok = also(fits_pods & (xs["zero"] | fits_res))
-            if use_ports:
+            if fam.ports:
                 port_conflict = jnp.einsum(
                     "c,nc->n", xs["ports"].astype(f32),
                     state["ports_used"].astype(f32)) > 0
                 dyn_ok = also(~port_conflict)
-            if use_volumes:
+            if fam.volumes:
                 vol_conflict = (
                     jnp.einsum("w,nw->n", xs["vrw"].astype(f32),
                                state["vol_any"].astype(f32)) +
                     jnp.einsum("w,nw->n", xs["vro"].astype(f32),
                                state["vol_rw"].astype(f32))) > 0
                 dyn_ok = also(~vol_conflict)
-            for fam in ("ebs", "gce") if (use_max_ebs or use_max_gce) \
-                    else ():
-                if (fam == "ebs" and not use_max_ebs) or \
-                        (fam == "gce" and not use_max_gce):
+            for pd, on in (("ebs", fam.max_ebs), ("gce", fam.max_gce)):
+                if not on:
                     continue
-                pd_node = state[f"pd_{fam}"]
-                pod_row = xs[f"pd_pod_{fam}"].astype(f32)
+                pd_node = state[f"pd_{pd}"]
+                pod_row = xs[f"pd_pod_{pd}"].astype(f32)
                 overlap = jnp.einsum("w,nw->n", pod_row,
                                      pd_node.astype(f32))
-                new = jnp.sum(pod_row) + xs[f"pd_extra_{fam}"].astype(f32)
-                node_extra = getattr(b.volsvc, f"pd_node_extra_{fam}")
-                node_err = getattr(b.volsvc, f"pd_node_err_{fam}")
+                new = jnp.sum(pod_row) + xs[f"pd_extra_{pd}"].astype(f32)
+                node_extra = getattr(b.volsvc, f"pd_node_extra_{pd}")
+                node_err = getattr(b.volsvc, f"pd_node_err_{pd}")
                 total = jnp.sum(pd_node.astype(f32), axis=1) + \
                     node_extra.astype(f32) + new - overlap
-                ok = (total <= f32(self.extra[f"max_{fam}"])) & ~node_err
+                ok = (total <= f32(self.extra[f"max_{pd}"])) & ~node_err
                 dyn_ok = also((new == 0) | ok)
-            if track_affinity:
+            if fam.track_affinity:
                 reach = state["match_cnt"] > 0.0  # [Sm, N]
-            if use_interpod:
+            if fam.interpod:
                 live_need = xs["aff_need"] & ~(
                     xs["aff_self"] & (state["match_total"] == 0.0))
                 viol = (jnp.einsum("s,sn->n", live_need.astype(f32),
@@ -1696,8 +1403,10 @@ class Solver:
             if dyn_ok is not None:
                 masked = jnp.where(dyn_ok, masked, neg)
 
-            # Fused selectHost (generic_scheduler.go:124-141).
-            choice, any_feasible = select(masked, counter)
+            # selectHost (generic_scheduler.go:124-141): round-robin
+            # among max-score feasible nodes; the counter bumps only on
+            # success.
+            choice, any_feasible = combine.select_host(masked, counter)
 
             # Commit (the batched AssumePod, cache.go:107) — one-row /
             # one-column scatters instead of full-plane rewrites.
@@ -1713,16 +1422,16 @@ class Solver:
                 new_state["D"] = state["D"].at[:, j].set(
                     self._template_col(tmpl_prios, b.nz_templates,
                                        new_packed[j, 4:6], c.alloc[j]))
-            if use_ports:
+            if fam.ports:
                 new_state["ports_used"] = state["ports_used"].at[j].set(
                     state["ports_used"][j] | (xs["ports"] & placed))
-            if use_volumes:
+            if fam.volumes:
                 new_state["vol_any"] = state["vol_any"].at[j].set(
                     state["vol_any"][j] |
                     ((xs["vrw"] | xs["vro"]) & placed))
                 new_state["vol_rw"] = state["vol_rw"].at[j].set(
                     state["vol_rw"][j] | (xs["vrw"] & placed))
-            if track_spread:
+            if fam.track_spread:
                 incr_f = xs["incr"].astype(f32) * pf          # [S]
                 new_col = state["sp_node"][:, j] + incr_f
                 new_state["sp_node"] = state["sp_node"].at[:, j].set(
@@ -1734,7 +1443,7 @@ class Solver:
                 new_state["sp_maxn"] = jnp.where(
                     placed, jnp.maximum(state["sp_maxn"], new_col),
                     state["sp_maxn"])
-                if track_spread_zones:
+                if fam.track_spread_zones:
                     zid = zone_ids[j]
                     zc = jnp.clip(zid, 0)
                     zval = incr_f * (zid >= 0).astype(f32)
@@ -1745,13 +1454,13 @@ class Solver:
                         placed & (zid >= 0),
                         jnp.maximum(state["sp_maxz"], new_zcol),
                         state["sp_maxz"])
-            if use_max_ebs:
+            if fam.max_ebs:
                 new_state["pd_ebs"] = state["pd_ebs"].at[j].set(
                     state["pd_ebs"][j] | (xs["pd_pod_ebs"] & placed))
-            if use_max_gce:
+            if fam.max_gce:
                 new_state["pd_gce"] = state["pd_gce"].at[j].set(
                     state["pd_gce"][j] | (xs["pd_pod_gce"] & placed))
-            if track_saa:
+            if fam.track_saa:
                 src = xs["saa_src"].astype(f32) * pf          # [Gy]
                 new_state["saa_num"] = state["saa_num"] + src
                 dom_j = b.volsvc.saa_dom[:, j]                # [L]
@@ -1761,7 +1470,7 @@ class Solver:
                           == dom_j[:, None]) & lab_j[:, None]).astype(f32)
                 new_state["saa_cnt"] = state["saa_cnt"] + \
                     domoh[:, None, :] * src[None, :, None]
-            if track_affinity:
+            if fam.track_affinity:
                 (new_state["match_cnt"], new_state["match_total"],
                  new_state["decl_reach"], new_state["sym_cnt"]) = \
                     interpod.place_update(
@@ -1797,15 +1506,15 @@ class Solver:
                         b.nz_templates, c.nonzero, c.alloc)
             init["D"] = D0
             xs["tmpl"] = b.nz_tmpl_idx
-        if use_ports:
+        if fam.ports:
             init["ports_used"] = c.ports_used
             xs["ports"] = b.ports
-        if use_volumes:
+        if fam.volumes:
             init["vol_any"] = c.vol_any
             init["vol_rw"] = c.vol_rw
             xs["vro"] = b.vol_ro
             xs["vrw"] = b.vol_rw
-        if track_spread:
+        if fam.track_spread:
             init["sp_node"] = b.spread_node_counts
             init["sp_zone"] = b.spread_zone_counts
             # Carried maxima, seeded exactly like the per-step
@@ -1818,7 +1527,7 @@ class Solver:
             init["sp_maxz"] = jnp.max(b.spread_zone_counts, axis=1)
             xs["sgroup"] = b.spread_group
             xs["incr"] = b.spread_incr
-        if track_affinity:
+        if fam.track_affinity:
             init.update(match_cnt=a.match_cnt, match_total=a.match_total,
                         decl_reach=a.decl_reach, sym_cnt=a.sym_cnt)
             xs.update(aff_need=a.aff_need, aff_self=a.aff_self,
@@ -1826,16 +1535,16 @@ class Solver:
                       match_src=a.match_src, decl_src=a.decl_src,
                       pref_w=a.pref_w, sym_match=a.sym_match,
                       sym_src=a.sym_src)
-        if track_saa:
+        if fam.track_saa:
             init["saa_cnt"] = b.volsvc.saa_cnt
             init["saa_num"] = b.volsvc.saa_num
             xs["saa_g"] = b.volsvc.saa_group
             xs["saa_src"] = b.volsvc.saa_src
-        if use_max_ebs:
+        if fam.max_ebs:
             init["pd_ebs"] = b.volsvc.pd_node_ebs
             xs["pd_pod_ebs"] = b.volsvc.pd_pod_ebs
             xs["pd_extra_ebs"] = b.volsvc.pd_extra_ebs
-        if use_max_gce:
+        if fam.max_gce:
             init["pd_gce"] = b.volsvc.pd_node_gce
             xs["pd_pod_gce"] = b.volsvc.pd_pod_gce
             xs["pd_extra_gce"] = b.volsvc.pd_extra_gce
@@ -1843,6 +1552,36 @@ class Solver:
             init.update({k: v for k, v in carry.items() if k in init})
         final, choices = jax.lax.scan(step, init, xs, unroll=SCAN_UNROLL)
         return choices, final["counter"], final
+
+    # Dynamic priorities whose pod-dependence is ONLY the nonzero-request
+    # row: their per-step [N] score plane is a pure function of
+    # (template, carried aggregates), so the scan can carry one
+    # [templates, N] plane and update a single column per placement
+    # instead of recomputing the whole chain every step.
+    _TEMPLATE_PRIOS = ("LeastRequestedPriority", "MostRequestedPriority",
+                       "BalancedResourceAllocation")
+
+    def _template_col(self, tmpl_prios: tuple, templates: jnp.ndarray,
+                      nz_j: jnp.ndarray, alloc_j: jnp.ndarray
+                      ) -> jnp.ndarray:
+        """[T] — the template-factored score column for one node, from
+        its (new) nonzero aggregates.  EXACTLY the formulas the step
+        applies to a batch without templates, evaluated at a single
+        node."""
+        col = jnp.zeros(templates.shape[0], jnp.float32)
+        for name, weight, _aux in tmpl_prios:
+            w = jnp.float32(weight)
+            if name == "LeastRequestedPriority":
+                col += w * prio.least_requested(
+                    templates, nz_j[None], alloc_j[None])[:, 0]
+            elif name == "MostRequestedPriority":
+                col += w * prio.most_requested(
+                    templates, nz_j[None], alloc_j[None])[:, 0]
+            elif name == "BalancedResourceAllocation":
+                col += w * prio.balanced_resource_allocation(
+                    templates, nz_j[None], alloc_j[None])[:, 0]
+        return col
+
 
     # -- joint batched assignment (the LP-relaxed global solve) ----------
 

@@ -524,8 +524,8 @@ def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
         else:
             volsvc = empty_volsvc(p, n)
 
-    # Nonzero-request templates for the fused scan's template-factored
-    # score planes (engine/solver.py _fused_scan): the distinct nonzero
+    # Nonzero-request templates for the scan's template-factored
+    # score planes (engine/solver.py _solve_scan): the distinct nonzero
     # rows, pow2-row-padded (padcap's "b_nztmpl" axis keeps the bucket
     # monotonic across batches).  Above the cap the table compiles away
     # (shape 0) and the scan keeps its in-step score path.

@@ -57,7 +57,7 @@ AXES: dict[str, list[tuple[str, str, int, object]]] = {
 }
 
 # Axes where an EMPTY table is a semantic sentinel (feature disabled for
-# this batch — the fused scan's over-cap fallback), not a size-0 count:
+# this batch — the scan's over-cap fallback), not a size-0 count:
 # padding it up would fabricate live rows.
 SKIP_EMPTY_AXES = frozenset({"b_nztmpl"})
 

@@ -11,6 +11,12 @@ the serial counter semantics.  The reference's tie *order* is nondeterministic
 (Go map iteration feeding an unstable sort), so parity is defined as "chosen
 node is in the reference's argmax set"; we fix node-index order to make our
 own output deterministic.
+
+``select_host`` is the same rule for ONE pod's row, as the sequential
+scan's step applies it (engine/solver.py ``_solve_scan``): the per-step
+mask -> score -> tie-break -> select chain is the floor of the scan's
+cost once the score planes are template-factored, so it is arranged as
+three node-axis reductions for XLA's fuser.
 """
 
 from __future__ import annotations
@@ -54,3 +60,28 @@ def select_hosts(scores: jnp.ndarray, feasible: jnp.ndarray,
     choice = jnp.argmax(pick, axis=1).astype(jnp.int32)
     choice = jnp.where(any_feasible, choice, -1)
     return choice, last_node_index + jnp.sum(any_feasible.astype(jnp.uint32))
+
+
+def select_host(masked: jnp.ndarray, counter: jnp.ndarray
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """selectHost for one pod: (choice int32 [-1 = infeasible],
+    any_feasible bool).
+
+    ``masked`` [N] f32 with -inf at infeasible nodes (the caller folds
+    the static mask and the dynamic predicate results into the score
+    row, so the row is the whole decision input); ``counter`` uint32
+    round-robin state.  Among the max-score nodes, the
+    ``counter % n_ties``-th in node-index order.  Three node-axis
+    passes: max, cumsum (whose last element is the tie count — no
+    separate sum pass), argmax.  The round-robin modulo runs in uint32:
+    an int32 cast would go negative past 2^31 cumulative placements and
+    the negative remainder would mark every pod unschedulable."""
+    mx = jnp.max(masked)
+    ties = (masked == mx) & jnp.isfinite(mx)
+    rank = jnp.cumsum(ties.astype(jnp.int32))  # 1-based among ties
+    n_raw = rank[-1]
+    any_feasible = n_raw > 0
+    ix = (counter % jnp.maximum(n_raw, 1).astype(jnp.uint32)) \
+        .astype(jnp.int32)
+    choice = jnp.argmax(ties & (rank == ix + 1)).astype(jnp.int32)
+    return jnp.where(any_feasible, choice, -1), any_feasible

@@ -10,13 +10,13 @@ reports to be the TPU; tier-1 runs it on the CPU backend at a tiny size.
   ``rich`` cluster (inter-pod affinity, volumes, taints, ports) replayed
   through ``oracle.py``; 100 % of the sampled decisions must agree and no
   choice may be infeasible.
-* ``stream_vs_host``: the streamed fused scan's choices for the
+* ``stream_vs_host``: the streamed scan's choices for the
   ``mixed`` backlog must equal ``HostSolver.solve_greedy`` row for row.
 * ``half_plane``: the same under a policy whose summed weight bound fits
   the half-width mantissa (the default provider minus
   NodePreferAvoidPods' weight 10,000), the only policies that store the
-  encoded static plane at half width (``Solver._fused_scan``).
-* ``select``: ``fused.select_xla`` against a NumPy selectHost at ragged
+  encoded static plane at half width (``Solver._solve_scan``).
+* ``select``: ``combine.select_host`` against a NumPy selectHost at ragged
   node counts with tie counters past 2^31.
 
 Run: ``python -m kubernetes_tpu.perf.chipcheck --nodes 5000 --pods 30000``
@@ -95,8 +95,8 @@ def select_check(n_nodes: int) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from kubernetes_tpu.engine import fused
-    select = jax.jit(fused.select_xla)
+    from kubernetes_tpu.ops import combine
+    select = jax.jit(combine.select_host)
     rng = np.random.RandomState(21)
     cases = wrong = 0
     for n in (n_nodes, n_nodes - 1, 128, 100):

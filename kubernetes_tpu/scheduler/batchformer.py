@@ -35,11 +35,6 @@ scheduling queue:
   only when complete or overdue), so a deadline firing mid-hold can
   never split a gang across two batches.
 
-``KT_COALESCE`` (seconds — the retired arrival-coalescing linger knob)
-is kept as a deprecated alias: it maps onto the deadline so old rig
-configs keep their meaning, but the linger loop it used to drive is
-gone — the former is the only place that decides "wait vs solve".
-
 Each formed batch records ``scheduler_batch_formation_latency_
 microseconds`` and bumps ``scheduler_batch_deadline_misses_total`` when
 hand-off overran the deadline (plus a 25% grace — the GIL, a gang
@@ -78,9 +73,8 @@ MISS_GRACE = 0.25
 
 def _env_deadline_s() -> float:
     """Resolve the formation deadline from the environment, once per
-    former (the daemon-lifetime discipline every other knob follows).
-    ``KT_BATCH_DEADLINE_MS`` wins; ``KT_COALESCE`` (seconds) is the
-    deprecated alias for rigs predating the former."""
+    former (the daemon-lifetime discipline every other knob follows):
+    ``KT_BATCH_DEADLINE_MS``, off when empty or unparsable."""
     raw = knobs.get("KT_BATCH_DEADLINE_MS")
     if raw:
         try:
@@ -88,16 +82,6 @@ def _env_deadline_s() -> float:
         except ValueError:
             log.warning("bad KT_BATCH_DEADLINE_MS=%r; deadline off", raw)
             return 0.0
-    legacy = knobs.get("KT_COALESCE")
-    if legacy:
-        try:
-            val = max(float(legacy), 0.0)
-        except ValueError:
-            return 0.0
-        if val:
-            log.warning("KT_COALESCE is deprecated; treating %ss as "
-                        "KT_BATCH_DEADLINE_MS=%d", legacy, int(val * 1e3))
-        return val
     return 0.0
 
 

@@ -85,14 +85,6 @@ _knob("KT_PROF_RING", "512", "int",
 # -- engine / device ----------------------------------------------------
 _knob("KT_PREWARM", "0", "bool",
       "Trace the bucket ladder before the queue opens (perf rigs, prod)")
-_knob("KT_SCAN_UNROLL", "4", "int",
-      "Unroll factor of the sequential-greedy placement scan")
-_knob("KT_FUSED", "1", "bool",
-      "Fused solve-scan step (sparse commits, template-factored scores, "
-      "fused select); 0 = the legacy full-plane scan body")
-_knob("KT_FEATURE_DTYPE", "narrow", "str",
-      "Resident cluster plane widths: 'narrow' = range-gated int16 "
-      "planes (mem columns stay int32), 'wide' = all int32")
 _knob("KT_DYN_TEMPLATES", "64", "int",
       "Max distinct nonzero-request templates factored out of the scan "
       "body; batches above it keep the in-scan score path")
@@ -103,9 +95,6 @@ _knob("KT_STREAM_CHUNK", "0", "int",
 _knob("KT_STREAM_MIN_BUCKET", None, "int",
       "Smallest pow2 drain bucket (default Scheduler.STREAM_MIN_BUCKET); "
       "read ONCE at daemon startup")
-_knob("KT_STREAM_DEBUG", "0", "bool",
-      "Per-chunk compile/launch timing prints on the stream path; read "
-      "once at engine init")
 _knob("KT_GUARD", "1", "bool",
       "Guarded device execution (engine/guard.py); 0 = raw solves")
 _knob("KT_GUARD_BREAKER", "3", "int",
@@ -129,8 +118,6 @@ _knob("KT_PIPELINE_WINDOW", "2", "int",
       "Overlapped solve/bind in-flight chunk window (0 = synchronous)")
 _knob("KT_BATCH_DEADLINE_MS", "", "float",
       "Deadline micro-batching window in ms (empty/0 = off)")
-_knob("KT_COALESCE", "", "float",
-      "DEPRECATED alias of KT_BATCH_DEADLINE_MS, in seconds")
 _knob("KT_QUEUE_HIGH_WATERMARK", "65536", "int",
       "Queue depth past which drains degrade to bounded pops (0 = off)")
 _knob("KT_POD_BACKOFF_S", "1", "float",

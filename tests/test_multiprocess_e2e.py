@@ -83,7 +83,7 @@ def cluster(tmp_path):
     procs["controller-manager"] = _spawn(
         "kubernetes_tpu.controller", [
             "--api-server", base, "--kube-api-token", "cm-token",
-            "--leader-elect",
+            "--port", "0", "--leader-elect",
             "--leader-elect-lease-duration", "2.0",
             "--leader-elect-renew-deadline", "1.5",
             "--leader-elect-retry-period", "0.3",

@@ -190,31 +190,48 @@ def test_disabled_path_is_one_branch(monkeypatch):
     assert elapsed < 1.0, f"off-path 200k calls took {elapsed:.3f}s"
 
 
-def test_sampler_self_cost_under_2_percent_of_busy_window():
-    """The GWP claim, measured not asserted: over a ~1 s window with a
-    busy thread and the sampler ticking at its real rate, the sampler's
-    own CPU (time.thread_time across ticks) stays under 2 %."""
+def test_sampler_self_cost_under_2_percent_of_busy_window(monkeypatch):
+    """The GWP claim, held by the loop's own pacing beside a busy
+    thread: ticks made to cost 5 ms of the sampler thread's CPU (at a
+    fixed 19 Hz, ~10 % of a core) still leave the sampler under 2 % of
+    the wall clock it ran for.  Costs are CPU seconds of the sampler's
+    thread and every sleep is at least the one the loop asked for, so
+    the bound holds whatever else the machine runs; the tick that the
+    stop cuts off from its sleep is the only one left out."""
+    p = profiler.Profiler()
+    tick_cpu = 0.005
+    costs: list[float] = []
+    sample = p.sample_once
+
+    def costly_tick() -> None:
+        c0 = time.thread_time()
+        sample()
+        while time.thread_time() - c0 < tick_cpu:
+            pass
+        costs.append(time.thread_time() - c0)
+
+    monkeypatch.setattr(p, "sample_once", costly_tick)
     stop = threading.Event()
     t = threading.Thread(target=_burn, args=(stop,), name="busy",
                          daemon=True)
     t.start()
-    p = profiler.Profiler()
-    window = 1.0
-    interval = 1.0 / p.hz
+    t0 = time.monotonic()
     try:
-        t_end = time.monotonic() + window
-        while time.monotonic() < t_end:
-            c0 = time.thread_time()
-            p.sample_once()
-            p._self_cpu += time.thread_time() - c0
-            time.sleep(interval)
+        p.start()
+        while len(costs) < 4 and time.monotonic() - t0 < 120.0:
+            time.sleep(0.05)
     finally:
+        p.stop()
         stop.set()
         t.join()
+    elapsed = time.monotonic() - t0
+    assert len(costs) >= 4, f"{len(costs)} ticks in {elapsed:.1f}s"
     self_cpu = p.snapshot()["sampler_self_cpu_s"]
-    assert self_cpu < 0.02 * window, \
-        f"sampler burned {self_cpu:.4f}s of a {window}s window " \
-        f"({self_cpu / window:.1%}, budget 2%)"
+    assert self_cpu >= sum(costs) - 1e-6  # snapshot rounds to 1 us
+    paced = self_cpu - costs[-1]
+    assert paced <= profiler._SELF_BUDGET * elapsed, \
+        f"sampler burned {paced:.4f}s of a {elapsed:.2f}s window " \
+        f"({paced / elapsed:.1%}, budget 2%)"
 
 
 def test_sampler_paces_itself_to_budget():
@@ -260,12 +277,14 @@ def test_wire_accounting_under_5_percent_of_decode_budget():
     m_s = WATCH_DECODE_SECONDS.labels(kind="overhead-test")
     m_n = WATCH_DECODE_EVENTS.labels(kind="overhead-test")
     perf_ns = time.perf_counter_ns
-    t0 = time.perf_counter()
+    # This thread's CPU, not the wall: the budget is what the accounting
+    # costs, and a worker descheduled mid-loop has spent nothing on it.
+    t0 = time.thread_time()
     for _ in range(10_000):
         t_chunk = perf_ns()
         m_s.inc((perf_ns() - t_chunk) / 1e9)
         m_n.inc(1)
-    elapsed = time.perf_counter() - t0
+    elapsed = time.thread_time() - t0
     assert elapsed < 0.05, \
         f"10k accounting flushes took {elapsed:.4f}s (budget 50ms = 5% " \
         f"of the pinned 1s decode budget)"

@@ -667,10 +667,10 @@ class TestWireRound5:
             kubelet = HollowKubelet(client, node).run()
             procs.append(self._spawn(
                 "kubernetes_tpu.scheduler.__main__",
-                ["--api-server", base]))
+                ["--api-server", base, "--port", "0"]))
             procs.append(self._spawn(
                 "kubernetes_tpu.controller.__main__",
-                ["--api-server", base]))
+                ["--api-server", base, "--port", "0"]))
 
             # PetSet: ordinal bring-up through schedule->run->Ready.
             client.create("petsets", {

@@ -148,12 +148,15 @@ class TestBatchFormer:
         _former(q, deadline_s=0.01).form(wait_first=False)
         assert metrics.BATCH_FORMATION_LATENCY.count == before + 1
 
-    def test_kt_coalesce_is_a_deprecated_alias(self, monkeypatch):
-        monkeypatch.setenv("KT_COALESCE", "0.7")
-        monkeypatch.delenv("KT_BATCH_DEADLINE_MS", raising=False)
-        assert batchformer._env_deadline_s() == 0.7
+    def test_deadline_parses_milliseconds_and_is_off_otherwise(
+            self, monkeypatch):
         monkeypatch.setenv("KT_BATCH_DEADLINE_MS", "250")
         assert batchformer._env_deadline_s() == 0.25
+        for off in ("", "soon", "-5"):
+            monkeypatch.setenv("KT_BATCH_DEADLINE_MS", off)
+            assert batchformer._env_deadline_s() == 0.0
+        monkeypatch.delenv("KT_BATCH_DEADLINE_MS", raising=False)
+        assert batchformer._env_deadline_s() == 0.0
 
     def test_first_seen_stamp_survives_requeue(self):
         pod = make_pod("fs")

@@ -34,24 +34,43 @@ _BOOT = (
 )
 
 
-def _spawn(module: str, args: list[str]) -> subprocess.Popen:
-    return subprocess.Popen(
-        [sys.executable, "-c", _BOOT.format(module=module, args=args)],
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-        env=dict(os.environ))
+# One limit PER STEP, sized for the slowest thing a step waits on when
+# this process and its four daemons share one loaded core (the driver
+# runs six workers): a daemon's start imports JAX and initialises the
+# backend, the first decision compiles its programs.  A step that is
+# merely slow passes; a daemon that died, or a step that is stuck, fails
+# at once under the step's name with the daemons' logs.  Every daemon
+# takes an ephemeral status port: on its default one it dies at start
+# whenever another worker's test runs the same daemon.
+STEP_S = 120.0
 
 
-def _wait(cond, timeout=60.0, period=0.25, msg=""):
-    deadline = time.time() + timeout
-    while time.time() < deadline:
+def _spawn(module: str, args: list[str], log_path) -> subprocess.Popen:
+    with open(log_path, "wb") as log:
+        return subprocess.Popen(
+            [sys.executable, "-c", _BOOT.format(module=module, args=args)],
+            stdout=subprocess.DEVNULL, stderr=log, env=dict(os.environ))
+
+
+def _wait(cond, msg, procs, logs, timeout=STEP_S, period=0.25):
+    t0 = time.monotonic()
+    dead: list[str] = []
+    while not dead and time.monotonic() - t0 < timeout:
         try:
             v = cond()
         except Exception:  # noqa: BLE001 — components still starting
             v = None
         if v:
             return v
+        dead = [name for name, p in procs.items() if p.poll() is not None]
         time.sleep(period)
-    raise AssertionError(f"timed out waiting for {msg}")
+    why = f"{', '.join(dead)} exited" if dead \
+        else f"timed out after {timeout:.0f}s"
+    tails = "".join(
+        f"\n--- {path.name} ---\n"
+        + path.read_text(errors="replace")[-2000:]
+        for path in sorted(logs.glob("*.log")))
+    raise AssertionError(f"{why} waiting for {msg}{tails}")
 
 
 @pytest.fixture(scope="module")
@@ -98,20 +117,25 @@ def _tls_args(pki, who) -> list[str]:
             "--client-key", str(pki / f"{who}.key")]
 
 
-def test_full_control_plane_tls_only(pki):
+def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
+        return s.getsockname()[1]
+
+
+def test_full_control_plane_tls_only(pki, tmp_path):
+    port, status_port = _free_port(), _free_port()
     base = f"https://127.0.0.1:{port}"
     procs = {"apiserver": _spawn("kubernetes_tpu.apiserver.__main__", [
         "--port", str(port),
         "--tls-cert-file", str(pki / "server.crt"),
         "--tls-private-key-file", str(pki / "server.key"),
         "--client-ca-file", str(pki / "ca.crt"),
-        "--authorization-mode", "RBAC"])}
+        "--authorization-mode", "RBAC"], tmp_path / "apiserver.log")}
     admin = _client(pki, base, "admin")
     try:
-        _wait(lambda: admin.list("pods")[1] >= 0, msg="secure apiserver")
+        _wait(lambda: admin.list("pods")[1] >= 0, "secure apiserver",
+              procs, tmp_path)
 
         # There is no insecure surface AT ALL: a plaintext request to
         # the same port dies in the handshake.
@@ -142,19 +166,26 @@ def test_full_control_plane_tls_only(pki):
 
         procs["scheduler"] = _spawn(
             "kubernetes_tpu.scheduler.__main__",
-            ["--api-server", base, "--port", "0"]
-            + _tls_args(pki, "scheduler"))
+            ["--api-server", base, "--port", str(status_port)]
+            + _tls_args(pki, "scheduler"), tmp_path / "scheduler.log")
         procs["cm"] = _spawn(
             "kubernetes_tpu.controller.__main__",
-            ["--api-server", base] + _tls_args(pki, "cm"))
+            ["--api-server", base, "--port", "0"] + _tls_args(pki, "cm"),
+            tmp_path / "cm.log")
         procs["kubelet"] = _spawn(
             "kubernetes_tpu.kubelet.__main__",
             ["--api-server", base, "--node-name", "wn0",
-             "--heartbeat-period", "2"] + _tls_args(pki, "kubelet"))
+             "--heartbeat-period", "2"] + _tls_args(pki, "kubelet"),
+            tmp_path / "kubelet.log")
 
+        _wait(lambda: urllib.request.urlopen(
+            f"http://127.0.0.1:{status_port}/healthz",
+            timeout=5).status == 200,
+            "the scheduler daemon's start (its status port)", procs,
+            tmp_path)
         _wait(lambda: any(n["metadata"]["name"] == "wn0"
                           for n in admin.list("nodes")[0]),
-              msg="kubelet registered over TLS")
+              "kubelet registered over TLS", procs, tmp_path)
 
         # kubectl over TLS creates the workload; the whole loop
         # (controller -> scheduler -> kubelet) runs on the secure port.
@@ -177,16 +208,22 @@ def test_full_control_plane_tls_only(pki):
             env=dict(os.environ, PYTHONPATH=REPO), cwd=REPO)
         assert "created" in out.stdout, out.stdout + out.stderr
 
-        def running():
+        def web_pods(ready) -> bool:
             pods = [p for p in admin.list("pods")[0]
                     if (p["metadata"].get("labels") or {})
                     .get("app") == "web"]
-            return len(pods) == 2 and all(
-                (p.get("status") or {}).get("phase") == "Running"
-                and (p.get("spec") or {}).get("nodeName") == "wn0"
-                for p in pods)
-        _wait(running, timeout=120,
-              msg="RC pods scheduled + Running, all over TLS")
+            return len(pods) == 2 and all(ready(p) for p in pods)
+
+        _wait(lambda: web_pods(lambda p: True),
+              "the controller-manager to create the RC's pods", procs,
+              tmp_path)
+        _wait(lambda: web_pods(
+            lambda p: (p.get("spec") or {}).get("nodeName") == "wn0"),
+            "the scheduler's first decisions (they compile)", procs,
+            tmp_path)
+        _wait(lambda: web_pods(
+            lambda p: (p.get("status") or {}).get("phase") == "Running"),
+            "the kubelet to run the pods, all over TLS", procs, tmp_path)
 
         # kubectl get over TLS reads it back.
         out = subprocess.run(
