@@ -469,12 +469,10 @@ def packed_avals(b_abs: Any, **riders: Any) -> Any:
     """The wire form the live dispatch hands the jitted entrypoints:
     ``solver.batch_layout`` over the batch avals and the launch's
     riders (live mask, tie counter, topology planes), as a PackedBatch
-    of three buffer avals."""
+    of one carrier aval."""
     from kubernetes_tpu.engine import solver as sv
-    layout, _leaves, sizes = sv.batch_layout(b_abs, **riders)
-    return sv.PackedBatch(
-        tuple(_sds((size,), dtype)
-              for size, dtype in zip(sizes, sv.WIRE_DTYPES)), layout)
+    layout, _leaves, words = sv.batch_layout(b_abs, **riders)
+    return sv.PackedBatch(_sds((words,), np.int32), layout)
 
 
 def build_context() -> Context:
@@ -547,7 +545,7 @@ def program_builders(ctx: Context) -> dict[str, tuple[str, Callable,
     progs: dict[str, tuple[str, Callable, tuple]] = {}
 
     # The batch arrives in its wire form (solver.PackedBatch): the live
-    # mask rides its buffers, and so does the tie counter wherever the
+    # mask rides its carrier, and so does the tie counter wherever the
     # host has it (a launch's first chunk, the one-shot solves, the
     # single-pod compile); a later chunk takes the previous scan's.
     def scan_first(b, c):
@@ -595,12 +593,13 @@ def program_builders(ctx: Context) -> dict[str, tuple[str, Callable,
         "select_hosts", combine.select_hosts,
         (_sds((1, n), np.float32), _sds((1, n), np.bool_), cnt))
 
+    # The dirty rows arrive as ONE packed buffer (solver.rows_layout:
+    # the index and the 11 narrow planes of the bucket).
     for rows in canonical_scatter_rows():
-        idx = _sds((rows,), np.int32)
-        row_tree = jax.tree_util.tree_map(
-            lambda s, r=rows: _sds((r,) + s.shape[1:], s.dtype), c_abs)
+        words = sv.rows_layout(sv._cluster_planes(c_abs), rows)[1]
         progs[f"scatter@{rows}"] = (
-            "scatter", raw_scatter, (c_abs, idx, row_tree))
+            "scatter", lambda c, buf, k=rows: raw_scatter(c, buf, k),
+            (c_abs, _sds((words,), np.int32)))
 
     v = CANON["victims"]
     progs["victim_solve"] = ("victim_solve", raw_victim, (

@@ -184,7 +184,7 @@ class GenericScheduler:
                             sv.DeviceCluster, list[str]]:
         """The batch comes back in its wire form on the device
         (``sv.PackedBatch``, with the tie counter and, where the caller
-        padded, the ``live`` mask riding its buffers), or with
+        padded, the ``live`` mask riding its carrier), or with
         ``device=False`` as the host-numpy DeviceBatch the chunked drain
         slices.  ``host_only=True`` is the fallback engine's compile: the
         same snapshot + feature compile, but NO device participation — the
@@ -515,7 +515,7 @@ class GenericScheduler:
                 for i in range(pad_to - real_p)]
             live_np = np.zeros(len(pods), bool)
             live_np[:real_p] = True
-        # The tie counter and the live mask ride db's buffers: the solve
+        # The tie counter and the live mask ride db's carrier: the solve
         # calls below pass None for both.
         with self.guard.watch("oneshot" if not joint else "joint",
                               inject=False):
@@ -809,7 +809,7 @@ class GenericScheduler:
             batch, hb, dc, nt = self._compile(all_pods, device=False)
         flags = self._pinned_flags(batch)
         # Spread-constraint planes, host-resident like the batch: each
-        # chunk's fixed-shape row slice rides the chunk's packed buffers
+        # chunk's fixed-shape row slice rides the chunk's packed carrier
         # (pad rows carry no constraints, so their mask rows are
         # all-pass).
         topo_mask_np = topo_score_np = None
@@ -856,8 +856,8 @@ class GenericScheduler:
         for start in range(0, padded, chunk_size):
             # Host-slice (free numpy views), pack the fixed
             # [chunk_size, ...] leaves with the chunk's live mask (and the
-            # counter, and the planes) into three buffers, then ONE
-            # device_put of those: slicing ON DEVICE minted a
+            # counter, and the planes) into one carrier, then ONE
+            # device_put of it: slicing ON DEVICE minted a
             # dynamic_slice program per distinct drain length.
             stop = start + chunk_size
 

@@ -25,6 +25,7 @@ sharded across a mesh (see kubernetes_tpu.parallel).
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 import os
 import threading
@@ -302,32 +303,61 @@ class DtypePolicy(NamedTuple):
 _I16_GATE = 32000
 
 
+def _res_cols(alloc: np.ndarray, requested: np.ndarray,
+              nonzero: np.ndarray) -> np.ndarray:
+    """The seven range-gated resource columns of ``NarrowCluster.res16``
+    as one int32 matrix: alloc cpu/gpu/pods, requested cpu/gpu/pods,
+    nonzero cpu."""
+    alloc, requested = np.asarray(alloc), np.asarray(requested)
+    # basic slices (views) of columns 0, 2, 3: one copy, the concatenate
+    return np.concatenate(
+        [alloc[:, :1], alloc[:, 2:], requested[:, :1], requested[:, 2:],
+         np.asarray(nonzero)[:, :1]], axis=1)
+
+
+def _range_policy(res: np.ndarray, image_kib: np.ndarray,
+                  space: "FeatureSpace") -> DtypePolicy:
+    """The narrowest policy THESE rows allow (``res`` = ``_res_cols`` of
+    them): the one range proof, read over the fleet at a full upload and
+    over the dirty rows at a scatter."""
+    ok = not res.size or (int(res.min()) >= 0
+                          and int(res.max()) < _I16_GATE)
+    img_max = int(image_kib.max()) if image_kib.size else 0
+    return DtypePolicy(
+        res="int16" if ok else "int32",
+        img="int16" if img_max < _I16_GATE else "int32",
+        topo="int16" if len(space.topo_vals) < _I16_GATE else "int32")
+
+
 def narrow_policy(nt: "NodeTensors", agg: "NodeAggregates",
                   space: "FeatureSpace") -> DtypePolicy:
     """The dtype policy for THIS host state.  Range checks read the live
-    arrays (cheap numpy maxima), so adversarial states — overcommitted
-    aggregates ingested from a relist, a 64-core node — fall back to
-    int32 for that signature instead of wrapping."""
-    cols = [nt.alloc[:, (0, 2, 3)], agg.requested[:, (0, 2, 3)],
-            agg.nonzero[:, :1]]
-    res_max = max(int(a.max()) if a.size else 0 for a in cols)
-    res_min = min(int(a.min()) if a.size else 0 for a in cols)
-    res = "int16" if 0 <= res_min and res_max < _I16_GATE else "int32"
-    img_max = int(nt.image_kib.max()) if nt.image_kib.size else 0
-    img = "int16" if img_max < _I16_GATE else "int32"
-    topo = "int16" if len(space.topo_vals) < _I16_GATE else "int32"
-    return DtypePolicy(res=res, img=img, topo=topo)
+    arrays, so adversarial states — overcommitted aggregates ingested
+    from a relist, a 64-core node — fall back to int32 for that
+    signature instead of wrapping.  A walk of the whole fleet: the
+    resident mirror pays it at a full upload only, and between two of
+    them re-proves the policy from the rows it is about to scatter
+    (``ResidentCluster._gather_rows``)."""
+    return _range_policy(_res_cols(nt.alloc, agg.requested, agg.nonzero),
+                         nt.image_kib, space)
 
 
-def narrow_cluster(c: "DeviceCluster", policy: DtypePolicy
-                   ) -> NarrowCluster:
+def policy_holds(kept: DtypePolicy, need: DtypePolicy) -> bool:
+    """True when planes stored under ``kept`` can take rows that need
+    ``need``: no plane is narrower than its rows ask.  (A plane kept
+    wider than they ask stays wide until the next full upload.)"""
+    return all(k == n or k == "int32" for k, n in zip(kept, need))
+
+
+def narrow_cluster(c: "DeviceCluster", policy: DtypePolicy,
+                   res: np.ndarray | None = None) -> NarrowCluster:
     """Re-lay a (host numpy) DeviceCluster into the narrow wire form.
     Shared by the full upload and the dirty-row gather, so the two
-    paths cannot encode differently."""
-    res16 = np.concatenate(
-        [np.asarray(c.alloc)[:, (0, 2, 3)],
-         np.asarray(c.requested)[:, (0, 2, 3)],
-         np.asarray(c.nonzero)[:, :1]], axis=1).astype(policy.res)
+    paths cannot encode differently.  ``res``: ``c``'s ``_res_cols``
+    where the caller has them already (the gather's range proof)."""
+    if res is None:
+        res = _res_cols(c.alloc, c.requested, c.nonzero)
+    res16 = res.astype(policy.res)
     mem32 = np.stack(
         [np.asarray(c.alloc)[:, 1], np.asarray(c.requested)[:, 1],
          np.asarray(c.nonzero)[:, 1]], axis=1).astype(np.int32)
@@ -394,88 +424,171 @@ def host_batch(b: PodBatch) -> DeviceBatch:
     return DeviceBatch(*parts, aff=aff, volsvc=volsvc)
 
 
+# -- the 32-bit carrier ------------------------------------------------------
+#
+# An upload is ONE host array whatever it holds: every array handed to
+# the runtime is a trip through the interpreter's lock, which the launch
+# thread shares with the decode, reflector and bind threads, so a dozen
+# small arrays cost what a dozen trips cost whatever their bytes.  The
+# carrier is a flat int32 buffer of one REGION per storage width — the
+# leaves of a region lie end to end in it as their bytes are, the region
+# padded to a whole word — and the program that reads the buffer
+# bit-casts each region back once and slices the leaves out by static
+# offsets.  Dtypes, shapes and values are the leaves' own, so no decision
+# can move.
+
+# The regions in carrier order, and the region that stores each leaf
+# dtype: uint32 (the tie counter) rides the int32 words bit-cast, bool
+# rides as bytes (XLA has no bit-cast to it).
+_REGIONS = ("int32", "float32", "int16", "uint8")
+_REGION_OF = {"int32": "int32", "uint32": "int32", "float32": "float32",
+              "int16": "int16", "uint8": "uint8", "bool": "uint8"}
+
+
+_I32 = np.dtype(np.int32)
+
+
+def _words(dtype: str, n: int) -> int:
+    """32-bit words that ``n`` elements of ``dtype`` take."""
+    return -(-n * np.dtype(dtype).itemsize // 4)
+
+
+@functools.lru_cache(maxsize=512)
+def wire_layout(names: tuple, signature: tuple) -> tuple[tuple, int]:
+    """``(wire, words)`` of a carrier for leaves of these names and
+    ``(dtype, shape)``: ``wire`` = ``(layout, regions)`` — per leaf
+    ``(name, dtype name, shape, offset)`` with the offset in ELEMENTS of
+    its region, per region ``(dtype name, word offset, elements)`` — and
+    the carrier's length.  A launch's shapes repeat, so the walk (and
+    NumPy's slow ``dtype.name``) is paid once each."""
+    layout, sizes = [], dict.fromkeys(_REGIONS, 0)
+    for name, (dtype, shape) in zip(names, signature):
+        dtype = dtype.name
+        region = _REGION_OF.get(dtype)
+        if region is None:
+            raise TypeError(f"leaf {name}: dtype {dtype} has no wire form")
+        layout.append((name, dtype, shape, sizes[region]))
+        sizes[region] += math.prod(shape)
+    regions, words = [], 0
+    for region in _REGIONS:
+        if sizes[region]:
+            regions.append((region, words, sizes[region]))
+            words += _words(region, sizes[region])
+    return (tuple(layout), tuple(regions)), words
+
+
+def _pack_words(wire: tuple, leaves: Any) -> np.ndarray:
+    """Host leaves of a ``wire_layout`` as its one int32 carrier.  ONE
+    ``bytes.join``: a C loop that keeps the interpreter's lock, where a
+    NumPy copy per leaf would each offer it to the other threads."""
+    layout, regions = wire
+    parts: dict[str, list] = {region: [] for region, _off, _n in regions}
+    for (_name, dtype, _shape, _off), leaf in zip(layout, leaves):
+        parts[_REGION_OF[dtype]].append(
+            leaf if leaf.flags.c_contiguous else np.ascontiguousarray(leaf))
+    for region, _off, n in regions:
+        parts[region].append(bytes(-n * np.dtype(region).itemsize % 4))
+    return np.frombuffer(b"".join(itertools.chain.from_iterable(
+        parts.values())), np.int32)
+
+
+def _unpack_words(buf: jnp.ndarray, wire: tuple) -> list:
+    """The leaves of a ``wire_layout`` back from its carrier: one static
+    slice and one bit-cast per region, one static slice per leaf."""
+    layout, regions = wire
+    flat = {}
+    for region, off, n in regions:
+        v = jax.lax.slice(buf, (off,), (off + _words(region, n),))
+        if region != "int32":
+            v = jax.lax.bitcast_convert_type(v, jnp.dtype(region))
+        flat[region] = v.reshape(-1)    # narrower: [words, per word]
+    vals = []
+    for _name, dtype, shape, off in layout:
+        v = jax.lax.slice(flat[_REGION_OF[dtype]], (off,),
+                          (off + math.prod(shape),))
+        if dtype == "bool":
+            v = v != 0
+        elif dtype == "uint32":
+            v = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        vals.append(v.reshape(shape))
+    return vals
+
+
+def _cluster_planes(c: "NarrowCluster") -> tuple:
+    """``(dtype, row shape)`` of every resident plane: the part of the
+    cluster signature a row's wire form follows."""
+    return tuple((a.dtype, tuple(a.shape[1:])) for a in c)
+
+
+def rows_layout(planes: tuple, k: int) -> tuple[tuple, int]:
+    """``wire_layout`` of a ``k``-row scatter buffer over resident planes
+    of these ``_cluster_planes``: the row index, then the planes in
+    field order.  A function of the cluster signature and the row bucket
+    alone, cached by them."""
+    return wire_layout(
+        ("idx",) + NarrowCluster._fields,
+        ((_I32, (k,)),) + tuple((dtype, (k,) + row)
+                                for dtype, row in planes))
+
+
 # -- the batch's wire form ---------------------------------------------------
 #
-# A launch's pod batch crosses to the device as ONE buffer per dtype, as
-# the cluster crosses as a NarrowCluster: every array handed to the
-# runtime is a trip through the interpreter's lock, which the launch
-# thread shares with the decode, reflector and bind threads, so 65 small
-# leaves cost what 65 trips cost whatever their bytes.  ``pack_batch``
-# lays the leaves out on the host, ``unpack_batch`` / ``unpack_launch``
-# slice them back at the top of every jitted entrypoint; dtypes, shapes
-# and values are the DeviceBatch's own, so no decision can move.
+# A launch's pod batch crosses to the device as ONE carrier, as the
+# cluster's dirty rows do: ``pack_batch`` lays the 65 leaves and the
+# launch's riders out on the host, ``unpack_batch`` / ``unpack_launch``
+# slice them back at the top of every jitted entrypoint.
 
-WIRE_DTYPES = ("int32", "bool", "float32")
-# dtype -> the buffer that carries it; the tie counter (uint32) rides the
-# int32 buffer bit-cast.
-_WIRE = {"int32": 0, "uint32": 0, "bool": 1, "float32": 2}
 _N_TOP = len(DeviceBatch._fields) - 2
 _BATCH_PATHS = (DeviceBatch._fields[:_N_TOP]
                 + tuple(f"aff.{f}" for f in DeviceAffinity._fields)
                 + tuple(f"volsvc.{f}" for f in DeviceVolSvc._fields))
 # What a launch carries besides the DeviceBatch's fields, behind them in
-# the buffers: the chunk's live mask, the tie counter (a launch's first
+# the buffer: the chunk's live mask, the tie counter (a launch's first
 # chunk) and the topology planes.
 RIDERS = ("live", "counter", "extra_mask", "score_bias")
 
 
 @jax.tree_util.register_pytree_node_class
 class PackedBatch:
-    """The wire form of a DeviceBatch (+ riders): children = one flat
-    buffer per WIRE_DTYPES entry, static part = the layout, a tuple of
-    ``(path, dtype, shape, offset)`` derived from the leaves' shapes
-    alone — so it passes through ``jax.jit`` as the 65 leaves did and
-    keys the program exactly as their shapes did."""
+    """The wire form of a DeviceBatch (+ riders): one child, the int32
+    carrier, and as static part ``layout``, its ``wire_layout`` — the
+    leaves' ``(path, dtype, shape, offset)`` and the regions they lie
+    in — derived from the leaves' shapes alone, so it passes through
+    ``jax.jit`` as the 65 leaves did and keys the program exactly as
+    their shapes did."""
 
-    __slots__ = ("buffers", "layout")
+    __slots__ = ("buffer", "layout")
 
-    def __init__(self, buffers: tuple, layout: tuple):
-        self.buffers = buffers
+    def __init__(self, buffer: Any, layout: tuple):
+        self.buffer = buffer
         self.layout = layout
 
     def tree_flatten(self) -> tuple[tuple, tuple]:
-        return self.buffers, self.layout
+        return (self.buffer,), self.layout
 
     @classmethod
-    def tree_unflatten(cls, layout: tuple, buffers: Any) -> "PackedBatch":
-        return cls(tuple(buffers), layout)
+    def tree_unflatten(cls, layout: tuple, children: Any) -> "PackedBatch":
+        return cls(children[0], layout)
 
 
 def _batch_leaves(b: DeviceBatch) -> tuple:
     return b[:_N_TOP] + tuple(b.aff) + tuple(b.volsvc)
 
 
-@functools.lru_cache(maxsize=256)
-def _layout(signature: tuple, riders: tuple) -> tuple[tuple, tuple]:
-    """``(layout, sizes)`` for leaves of these ``(dtype, shape)`` in wire
-    order: each one's ``(path, dtype, shape, offset)`` and the element
-    count of the three buffers.  A launch's shapes repeat (one per
-    bucket and content-axis capacity), so the walk is paid once each."""
-    layout, sizes = [], [0, 0, 0]
-    for path, (dtype, shape) in zip(_BATCH_PATHS + riders, signature):
-        k = _WIRE.get(dtype.name)
-        if k is None:
-            raise TypeError(f"batch leaf {path}: dtype {dtype.name} has "
-                            f"no wire buffer")
-        layout.append((path, dtype.name, shape, sizes[k]))
-        sizes[k] += math.prod(shape)
-    return tuple(layout), tuple(sizes)
-
-
 def batch_layout(b: DeviceBatch, live: Any = None, counter: Any = None,
                  extra_mask: Any = None, score_bias: Any = None
-                 ) -> tuple[tuple, list, tuple]:
-    """``(layout, leaves, sizes)`` of a batch and its riders: the leaves
-    in wire order, their layout and the three buffers' element counts.
+                 ) -> tuple[tuple, list, int]:
+    """``(wire, leaves, words)`` of a batch and its riders: the leaves
+    in wire order, their ``wire_layout`` and the carrier's length.
     Reads shapes and dtypes only (kt-xray lays out ShapeDtypeStructs
     with it)."""
     riders = [(name, r) for name, r in zip(
         RIDERS, (live, counter, extra_mask, score_bias)) if r is not None]
     leaves = list(_batch_leaves(b)) + [r for _name, r in riders]
-    layout, sizes = _layout(
-        tuple((leaf.dtype, tuple(leaf.shape)) for leaf in leaves),
-        tuple(name for name, _r in riders))
-    return layout, leaves, sizes
+    wire, words = wire_layout(
+        _BATCH_PATHS + tuple(name for name, _r in riders),
+        tuple((leaf.dtype, tuple(leaf.shape)) for leaf in leaves))
+    return wire, leaves, words
 
 
 def pack_batch(b: DeviceBatch, live: np.ndarray | None = None,
@@ -483,36 +596,22 @@ def pack_batch(b: DeviceBatch, live: np.ndarray | None = None,
                extra_mask: np.ndarray | None = None,
                score_bias: np.ndarray | None = None) -> PackedBatch:
     """The host-numpy DeviceBatch (and the launch's riders) as a
-    PackedBatch of three host buffers.  One ``bytes.join`` per buffer:
-    a C loop that keeps the interpreter's lock, where 65 NumPy copies
-    would each offer it to the other threads."""
-    layout, leaves, _sizes = batch_layout(b, live, counter, extra_mask,
-                                          score_bias)
-    parts: tuple[list, list, list] = ([], [], [])
-    for (_path, dtype, _shape, _off), leaf in zip(layout, leaves):
-        parts[_WIRE[dtype]].append(
-            leaf if leaf.flags.c_contiguous else np.ascontiguousarray(leaf))
-    return PackedBatch(
-        tuple(np.frombuffer(b"".join(part), dtype)
-              for part, dtype in zip(parts, WIRE_DTYPES)), layout)
+    PackedBatch of one host carrier."""
+    wire, leaves, _words = batch_layout(b, live, counter, extra_mask,
+                                        score_bias)
+    return PackedBatch(_pack_words(wire, leaves), wire)
 
 
 def _unpack(pb: PackedBatch) -> tuple[DeviceBatch, dict]:
-    """Static slices and reshapes of the buffers back into the exact
+    """Static slices and reshapes of the carrier back into the exact
     DeviceBatch, and the riders by name."""
-    vals = []
-    for _path, dtype, shape, off in pb.layout:
-        v = jax.lax.slice(pb.buffers[_WIRE[dtype]], (off,),
-                          (off + math.prod(shape),))
-        if dtype == "uint32":
-            v = jax.lax.bitcast_convert_type(v, jnp.uint32)
-        vals.append(v.reshape(shape))
+    vals = _unpack_words(pb.buffer, pb.layout)
     n_aff = len(DeviceAffinity._fields)
     n_b = len(_BATCH_PATHS)
     db = DeviceBatch(
         *vals[:_N_TOP], aff=DeviceAffinity(*vals[_N_TOP:_N_TOP + n_aff]),
         volsvc=DeviceVolSvc(*vals[_N_TOP + n_aff:n_b]))
-    return db, {e[0]: v for e, v in zip(pb.layout[n_b:], vals[n_b:])}
+    return db, {e[0]: v for e, v in zip(pb.layout[0][n_b:], vals[n_b:])}
 
 
 def unpack_batch(b: "DeviceBatch | PackedBatch") -> DeviceBatch:
@@ -529,7 +628,7 @@ def unpack_launch(b: "DeviceBatch | PackedBatch",
                   extra_mask: jnp.ndarray | None) -> tuple:
     """``unpack_batch`` plus the launch's riders, in ``_solve_scan``'s
     argument order: an argument the caller gave outright stands, a None
-    is filled from the buffers where the batch carries it."""
+    is filled from the carrier where the batch carries it."""
     if isinstance(b, DeviceBatch):
         return b, counter, score_bias, live, extra_mask
     db, riders = _unpack(b)
@@ -541,11 +640,10 @@ def unpack_launch(b: "DeviceBatch | PackedBatch",
 
 def put_batch(hb: DeviceBatch, **riders: Any) -> PackedBatch:
     """Pack a host batch with its riders and hand it to the device: ONE
-    device_put of three arrays, counted under cause ``batch``."""
+    device_put of ONE array, counted under cause ``batch``."""
     from kubernetes_tpu.engine import devicestats
     pb = pack_batch(hb, **riders)
-    devicestats.record_transfer("batch", devicestats.nbytes(pb.buffers),
-                                arrays=len(pb.buffers))
+    devicestats.record_transfer("batch", pb.buffer.nbytes, arrays=1)
     return jax.device_put(pb)
 
 
@@ -611,14 +709,23 @@ class ResidentCluster:
       duplicate scatter of identical values is a no-op) so the scatter
       compiles O(log N) shapes, and a drain dirtying more than 1/4 of
       the cluster falls back to the full upload (the gather would move
-      most of the bytes anyway).
+      most of the bytes anyway);
+    * the dirty rows cross as ONE packed int32 buffer (``rows_layout``:
+      the row index, then the 11 narrow planes) that ``kt_scatter_rows``
+      slices on the device;
+    * the narrow dtype policy is proven over the fleet at a full upload
+      and kept; a scatter re-proves it from the rows it is about to
+      send, and a row the kept planes are too narrow for takes the full
+      upload instead, as a signature change does.
     """
 
     FULL_FRACTION = 4  # dirty rows > N/4 -> full upload wins
 
     def __init__(self):
         self.dc: NarrowCluster | None = None
-        self._sig = None
+        self._sig = None     # its last component: the dtype policy the
+        #                      last full upload proved over the fleet
+        self._planes = None  # _cluster_planes(dc): the rows' wire layout
         self._epoch = None
         self._scatter = None
         self.stats = {"full_syncs": 0, "row_syncs": 0, "rows_scattered": 0}
@@ -684,16 +791,17 @@ class ResidentCluster:
             # the host->device transfer this mirror exists to avoid.
             # Named for the profiler: its ``XLA Modules`` line reads
             # ``jit_kt_scatter_rows`` beside ``jit__solve_scan``.
-            def kt_scatter_rows(c: "DeviceCluster | NarrowCluster",
-                                idx: jnp.ndarray,
-                                rows: "DeviceCluster | NarrowCluster"
-                                ) -> "DeviceCluster | NarrowCluster":
+            def kt_scatter_rows(c: NarrowCluster, buf: jnp.ndarray,
+                                k: int) -> NarrowCluster:
+                wire, words = rows_layout(_cluster_planes(c), k)
+                assert buf.shape == (words,), (buf.shape, words)
+                idx, *rows = _unpack_words(buf, wire)
                 return type(c)(*[arr.at[idx].set(new)
                                  for arr, new in zip(c, rows)])
 
             # kt-xray: no-donate(prior DeviceCluster may be aliased by an
             # in-flight drain; see the comment above)
-            self._scatter = jax.jit(kt_scatter_rows)
+            self._scatter = jax.jit(kt_scatter_rows, static_argnums=2)
         return self._scatter
 
     @staticmethod
@@ -717,17 +825,19 @@ class ResidentCluster:
         return out
 
     def prewarm_scatter(self, max_rows: int | None = None) -> int:
-        """Trace the dirty-row scatter kernel at EVERY reachable pow2
-        row-count bucket, so no drain after an assume ever compiles the
-        scatter mid-drain — measured as a fresh XLA compile on the clock
-        of the first post-warm-up stream drain (the warm-start audit,
-        ISSUE 8).  The reachable set is bounded by ``sync``'s own rule
-        (dirty * FULL_FRACTION >= N takes the full upload instead), so
-        this is log2(N/4) shapes — ~12 at 5k nodes, ~15 at 100k; an
-        explicit ``max_rows`` caps it for tests.  Requires a resident
-        copy (``sync`` must have run, which any ladder prewarm
-        guarantees); the traces scatter row 0's own values onto row 0 —
-        a no-op on the data.  Returns the number of shapes traced."""
+        """Trace the dirty-row scatter kernel — ``kt_scatter_rows`` over
+        the resident planes and ONE packed buffer of ``rows_layout`` —
+        at EVERY reachable pow2 row-count bucket, so no drain after an
+        assume ever compiles the scatter mid-drain — measured as a fresh
+        XLA compile on the clock of the first post-warm-up stream drain
+        (the warm-start audit, ISSUE 8).  The reachable set is bounded by
+        ``sync``'s own rule (dirty * FULL_FRACTION >= N takes the full
+        upload instead), so this is log2(N/4) shapes — ~12 at 5k nodes,
+        ~15 at 100k; an explicit ``max_rows`` caps it for tests.
+        Requires a resident copy (``sync`` must have run, which any
+        ladder prewarm guarantees); the traces scatter row 0's own
+        values onto row 0 — a no-op on the data.  Returns the number of
+        shapes traced."""
         if self.dc is None:
             return 0
         n = int(self.dc.schedulable.shape[0])
@@ -735,15 +845,13 @@ class ResidentCluster:
         # dirty sets take the full upload, so their shapes are
         # unreachable (ResidentCluster.scatter_buckets is that rule).
         scatter = self._scatter_fn()
+        row0 = [np.asarray(arr[:1]) for arr in self.dc]
         traced = 0
         for k in self.scatter_buckets(n, max_rows):
-            idx = np.zeros(k, np.int32)
-            rows = type(self.dc)(*[
-                np.repeat(np.asarray(arr[:1]), k, axis=0)
-                for arr in self.dc])
-            idx_d, rows_d = jax.device_put((idx, rows))
-            scatter(self.dc, idx_d,
-                    rows_d).schedulable.block_until_ready()
+            buf = _pack_words(
+                rows_layout(self._planes, k)[0], [np.zeros(k, np.int32)] + [
+                    np.repeat(row, k, axis=0) for row in row0])
+            scatter(self.dc, buf, k).schedulable.block_until_ready()
             traced += 1
         return traced
 
@@ -757,14 +865,21 @@ class ResidentCluster:
         wire form; the jitted entrypoints widen on device."""
         from kubernetes_tpu.engine import devicestats
         n = nt.alloc.shape[0]
-        policy = narrow_policy(nt, agg, space)
-        sig = self.signature(nt, space, policy)
-        if self.dc is None or self._sig != sig or self._epoch != epoch \
-                or len(dirty) * self.FULL_FRACTION >= max(n, 1):
+        buf = None
+        if self.in_sync(nt, space, epoch) \
+                and len(dirty) * self.FULL_FRACTION < max(n, 1):
+            if not dirty:
+                return self.dc
+            k = 1 << (len(dirty) - 1).bit_length()
+            with stage("transfer.rows"):
+                buf = self._gather_rows(nt, agg, space, dirty, k)
+        if buf is None:
             with stage("transfer.full"):
+                policy = narrow_policy(nt, agg, space)
                 host = _host_cluster(nt, agg, space)
                 self.dc = jax.device_put(narrow_cluster(host, policy))
-            self._sig = sig
+            self._sig = self.signature(nt, space, policy)
+            self._planes = _cluster_planes(self.dc)
             self._epoch = epoch
             self.stats["full_syncs"] += 1
             # Device accounting: the whole-cluster re-snapshot is the
@@ -779,28 +894,30 @@ class ResidentCluster:
                                         devicestats.nbytes(self.dc),
                                         arrays=len(self.dc))
             return self.dc
-        if not dirty:
-            return self.dc
-        with stage("transfer.rows"):
-            idx, rows = self._gather_rows(nt, agg, space, dirty, policy)
         with stage("transfer.scatter"):
-            idx_d, rows_d = jax.device_put((idx, rows))
-            self.dc = self._scatter_fn()(self.dc, idx_d, rows_d)
+            # The host buffer goes to the program as it is: the dispatch
+            # uploads its one array.
+            self.dc = self._scatter_fn()(self.dc, buf, k)
         self.stats["row_syncs"] += 1
         self.stats["rows_scattered"] += len(dirty)
         # Only the gathered rows crossed the wire (idx + padded rows).
-        devicestats.record_transfer(
-            "scatter", idx.nbytes + devicestats.nbytes(rows),
-            arrays=1 + len(rows))
+        devicestats.record_transfer("scatter", buf.nbytes, arrays=1)
         return self.dc
 
-    @staticmethod
-    def _gather_rows(nt: NodeTensors, agg: NodeAggregates,
+    def _gather_rows(self, nt: NodeTensors, agg: NodeAggregates,
                      space: FeatureSpace, dirty: set[int],
-                     policy: DtypePolicy) -> tuple:
-        """``(idx, rows)`` on the host: the dirty rows in the wire form,
-        padded to their pow2 bucket."""
-        idx = np.fromiter(dirty, np.int32, len(dirty))
+                     k: int) -> np.ndarray | None:
+        """The dirty rows on the host as ONE packed buffer of
+        ``rows_layout``: the index padded to its bucket ``k`` (with its
+        first row: a duplicate scatter of identical values is a no-op)
+        and the rows gathered once with it, in the resident planes' wire
+        form.  None when a row has outgrown the kept policy: nothing may
+        reach a plane too narrow for it, so the caller uploads the fleet
+        instead."""
+        policy = self._sig[-1]
+        idx = np.fromiter(
+            itertools.chain(dirty, itertools.repeat(next(iter(dirty)))),
+            np.int32, k)
         # Gather the dirty rows directly (fancy indexing copies), padding
         # and deriving only the k gathered rows — assembling the full
         # padded host cluster here would re-pay the O(N x features) host
@@ -825,15 +942,12 @@ class ResidentCluster:
             image_kib=_pad_cols(nt.image_kib[idx], space.images.capacity),
             topo_dom=_pad_cols(nt.topo_val[idx],
                                space.topo_keys.capacity, fill=-1))
-        rows = narrow_cluster(rows, policy)
-        pad = 1 << (len(dirty) - 1).bit_length()
-        if pad > len(dirty):
-            extra = pad - len(dirty)
-            idx = np.concatenate([idx, np.repeat(idx[:1], extra)])
-            rows = type(rows)(*[
-                np.concatenate([arr, np.repeat(arr[:1], extra, axis=0)])
-                for arr in rows])
-        return idx, rows
+        res = _res_cols(rows.alloc, rows.requested, rows.nonzero)
+        if not policy_holds(policy,
+                            _range_policy(res, rows.image_kib, space)):
+            return None
+        return _pack_words(rows_layout(self._planes, k)[0],
+                           (idx,) + narrow_cluster(rows, policy, res))
 
 
 def _predicate_mask(name: str, b: DeviceBatch, c: DeviceCluster,
@@ -1196,7 +1310,7 @@ class Solver:
         an additional hard feasibility plane (workload constraints —
         topology spread's DoNotSchedule terms); None compiles it away.
         A PackedBatch brings ``last_node_index`` / ``live`` / the two
-        planes in its buffers; pass None for what rides there.
+        planes in its carrier; pass None for what rides there.
         Returns (choices [P], counter, final state dict).
 
         The step is built for per-step cost:

@@ -609,8 +609,8 @@ DEVICE_TRANSFER_ARRAYS = register(Counter(
     "scheduler_device_transfer_arrays_total",
     "Host arrays handed to the runtime by the uploads, by cause (batch/"
     "scatter/full_upload): each is a trip through the interpreter's "
-    "lock on the launch thread; arrays{cause=batch} per launch is 3 "
-    "with the packed wire form",
+    "lock on the launch thread; arrays{cause=batch} and "
+    "arrays{cause=scatter} per launch are 1 with the packed wire forms",
     labelnames=("cause",)))
 DEVICE_HBM_LIVE_BYTES = register(Gauge(
     "scheduler_device_hbm_live_bytes",

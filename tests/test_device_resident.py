@@ -14,12 +14,14 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.engine import solver as sv
 from kubernetes_tpu.engine.generic_scheduler import GenericScheduler
 from kubernetes_tpu.scheduler.binder import InMemoryBinder
 from kubernetes_tpu.scheduler.scheduler import Scheduler, SchedulerConfig
+from kubernetes_tpu.utils import metrics
 
 from tests.helpers import make_node, make_pod
 
@@ -190,6 +192,183 @@ class TestResidentCluster:
             algo.cache.update_node(make_node(name, milli_cpu=8000))
         algo.schedule_batch([make_pod("sd1", cpu="100m")])
         assert algo.resident.stats["full_syncs"] == before + 1
+
+
+# -- the dirty rows' wire form (ISSUE 33) ------------------------------------
+
+PACKED_NODES = 72     # scatter_buckets(72) == [1, 2, 4, 8, 16, 32]
+
+
+def _packed_rig(res: str) -> GenericScheduler:
+    """A fleet whose every column family holds content — taints, images,
+    zone / hostname topology, and through resident pods host ports and
+    volumes — synced once.  ``res`` = "int32": one node past
+    ``_I16_GATE`` keeps the resource plane wide from the first upload."""
+    algo = GenericScheduler()
+    for i in range(PACKED_NODES):
+        algo.cache.add_node(make_node(
+            f"pk{i}", milli_cpu=64000 if res == "int32" and i == 5 else 4000,
+            labels={api.HOSTNAME_LABEL: f"pk{i}",
+                    api.ZONE_LABEL: f"z{i % 3}"},
+            taints=[{"key": "dedicated", "value": f"t{i % 2}",
+                     "effect": "PreferNoSchedule" if i % 4 else
+                     "NoSchedule"}] if i % 3 == 0 else None,
+            images=[([f"img{i % 5}:v1"], (2 + i % 8) * 1024 * 1024)]))
+    for i in range(0, PACKED_NODES, 7):
+        _land(algo, f"seed{i}", i)
+    _assert_resident_matches_fresh(algo)
+    assert algo.resident.dc.res16.dtype == np.dtype(res)
+    return algo
+
+
+def _land(algo: GenericScheduler, name: str, row: int) -> None:
+    """A bound pod with a host port and an RBD volume lands on ``row``."""
+    algo.cache.add_pod(make_pod(
+        name, cpu="100m", memory="64Mi", node_name=f"pk{row}",
+        host_ports=[31000 + row % 3],
+        volumes=[api.Volume(name="d", rbd_key=f"mon#pool#img{row % 4}",
+                            rbd_read_only=bool(row % 2))]))
+
+
+def _scatter_arrays() -> float:
+    child = metrics.DEVICE_TRANSFER_ARRAYS.children().get(("scatter",))
+    return float(child.value) if child is not None else 0.0
+
+
+class TestPackedRows:
+    def test_buckets_of_the_rig(self):
+        assert sv.ResidentCluster.scatter_buckets(PACKED_NODES) == \
+            [1, 2, 4, 8, 16, 32]
+
+    @pytest.mark.parametrize("res", ["int16", "int32"])
+    @pytest.mark.parametrize("dirty", [1, 2, 3, 4, 7, 8, 11, 16, 17])
+    def test_packed_scatter_equals_the_full_assembly(self, dirty, res):
+        """Every bucket of ``scatter_buckets`` that ``sync`` can reach
+        (17 rows of 72 pad to 32; 18 would take the full upload), full
+        and padded with duplicate rows, under both ``res`` policies."""
+        algo = _packed_rig(res)
+        rows = [(3 * j + 1) % PACKED_NODES for j in range(dirty)]
+        assert len(set(rows)) == dirty
+        for j, row in enumerate(rows):
+            if j % 2:
+                _land(algo, f"d{j}", row)
+            else:       # a node event: cordon, a new taint, a new zone
+                algo.cache.update_node(make_node(
+                    f"pk{row}", milli_cpu=3000 + j,
+                    labels={api.HOSTNAME_LABEL: f"pk{row}",
+                            api.ZONE_LABEL: "z0"},
+                    taints=[{"key": "dedicated", "value": "t0",
+                             "effect": "NoSchedule"}],
+                    images=[(["img0:v1"], 3 * 1024 * 1024)],
+                    unschedulable=bool(j % 4)))
+        before = dict(algo.resident.stats)
+        arrays = _scatter_arrays()
+        _assert_resident_matches_fresh(algo)
+        assert algo.resident.stats["full_syncs"] == before["full_syncs"]
+        assert algo.resident.stats["row_syncs"] == before["row_syncs"] + 1
+        assert algo.resident.stats["rows_scattered"] == \
+            before["rows_scattered"] + dirty
+        # the mechanism engaged: ONE array crossed for the rows
+        assert _scatter_arrays() - arrays == 1
+        dc = algo.resident.dc
+        assert dc.res16.dtype == np.dtype(res)
+        for plane in (dc.ports_used, dc.vol_any, dc.taints_nosched,
+                      dc.taints_prefer, dc.image_kib):
+            assert np.asarray(plane).any()
+        assert (np.asarray(dc.topo_dom) >= 0).any()
+
+    def test_layout_follows_signature_and_bucket_alone(self):
+        algo = _packed_rig("int16")
+        planes = sv._cluster_planes(algo.resident.dc)
+        (layout, regions), words = sv.rows_layout(planes, 8)
+        assert [e[0] for e in layout] == \
+            ["idx"] + list(sv.NarrowCluster._fields)
+        assert layout[0][1:] == ("int32", (8,), 0)
+        # one region per storage width, each on a whole word behind the
+        # one before it; a region's leaves lie end to end in it
+        assert [r[0] for r in regions] == ["int32", "int16", "uint8"]
+        at = 0
+        for dtype, off, n in regions:
+            assert off == at
+            at += sv._words(dtype, n)
+            mine = [e for e in layout if sv._REGION_OF[e[1]] == dtype]
+            assert [e[3] for e in mine] == list(np.cumsum(
+                [0] + [int(np.prod(e[2])) for e in mine[:-1]]))
+            assert n == sum(int(np.prod(e[2])) for e in mine)
+        assert at == words
+        assert sv.rows_layout(planes, 8)[0] is sv.rows_layout(planes, 8)[0]
+        wide = sv._cluster_planes(_packed_rig("int32").resident.dc)
+        assert sv.rows_layout(wide, 8)[1] > words
+
+    @pytest.mark.parametrize("how", ["alloc", "requested", "image"])
+    def test_a_row_over_the_gate_takes_the_full_upload_first(self, how):
+        """Between two launches a row crosses ``_I16_GATE``: the kept
+        proof no longer holds for it, so the fleet is uploaded under the
+        wider policy BEFORE any scatter — nothing reaches a plane too
+        narrow for it."""
+        algo = _packed_rig("int16")
+        assert algo.resident.dc.image_kib.dtype == np.int16
+        if how == "alloc":
+            algo.cache.update_node(make_node(
+                "pk9", milli_cpu=sv._I16_GATE + 1,
+                labels={api.HOSTNAME_LABEL: "pk9", api.ZONE_LABEL: "z0"}))
+        elif how == "requested":
+            algo.cache.add_pod(make_pod("hog", cpu="33", node_name="pk9"))
+        else:
+            algo.cache.update_node(make_node(
+                "pk9", labels={api.HOSTNAME_LABEL: "pk9",
+                               api.ZONE_LABEL: "z0"},
+                images=[(["img0:v1"], (sv._I16_GATE + 5) * 1024)]))
+        before = dict(algo.resident.stats)
+        arrays = _scatter_arrays()
+        _assert_resident_matches_fresh(algo)
+        assert algo.resident.stats["full_syncs"] == before["full_syncs"] + 1
+        assert algo.resident.stats["row_syncs"] == before["row_syncs"]
+        assert _scatter_arrays() == arrays
+        wide = algo.resident.dc.image_kib if how == "image" else \
+            algo.resident.dc.res16
+        assert wide.dtype == np.int32
+        # back under the gate: the row is scattered, and the plane stays
+        # wide until the next full upload (bytes, never correctness)
+        if how == "requested":
+            algo.cache.remove_pod(make_pod("hog", cpu="33",
+                                           node_name="pk9"))
+        else:
+            algo.cache.update_node(make_node(
+                "pk9", labels={api.HOSTNAME_LABEL: "pk9",
+                               api.ZONE_LABEL: "z0"},
+                images=[(["img0:v1"], 3 * 1024 * 1024)]))
+        _assert_resident_matches_fresh(algo)
+        assert algo.resident.stats["full_syncs"] == before["full_syncs"] + 1
+        assert algo.resident.stats["row_syncs"] == before["row_syncs"] + 1
+        dc = algo.resident.dc
+        assert (dc.image_kib if how == "image" else dc.res16).dtype == \
+            np.int32
+
+    def test_a_negative_row_widens_too(self):
+        """The gate has two sides: a negative aggregate (an overcommit
+        ingested backwards) is outside int16's proven range as well."""
+        need = sv._range_policy(np.array([[-1, 0, 0, 0, 0, 0, 0]]),
+                                np.zeros((1, 1), np.int32),
+                                GenericScheduler().cache.space)
+        assert need.res == "int32"
+        kept = sv.DtypePolicy("int16", "int16", "int16")
+        assert not sv.policy_holds(kept, need)
+        assert sv.policy_holds(need, kept)       # wider than asked: fine
+        assert sv.policy_holds(kept, kept)
+
+    def test_prewarm_traces_the_packed_program_at_every_bucket(self):
+        algo = _packed_rig("int16")
+        scatter = algo.resident._scatter_fn()
+        assert algo.resident.prewarm_scatter() == 6
+        size = scatter._cache_size()
+        assert size >= 6
+        before = _scatter_arrays()
+        for row in (2, 3, 5):
+            _land(algo, f"late{row}", row)
+        _assert_resident_matches_fresh(algo)      # bucket 4: warm
+        assert scatter._cache_size() == size
+        assert _scatter_arrays() - before == 1
 
 
 class TestPrewarmLadder:

@@ -1,6 +1,7 @@
-"""The pod batch's wire form (engine/solver.py ``PackedBatch``): three
-packed buffers cross to the device where ~67 small arrays did, with the
-chunk's live mask, the tie counter and the topology planes inside.  The
+"""The pod batch's wire form (engine/solver.py ``PackedBatch``): ONE
+packed carrier crosses to the device where ~67 small arrays did (three
+per-dtype buffers between PR 31 and PR 33), with the chunk's live mask,
+the tie counter and the topology planes inside.  The
 same values in the same dtypes have to reach the same program, so every
 case here is an equality, never a tolerance."""
 
@@ -140,9 +141,8 @@ def test_pack_unpack_gives_back_every_leaf(family):
                    "everything": all(flags)}
         assert want_on[family], f"{family}: the batch lacks its content"
     packed = sv.pack_batch(hb)
-    assert len(packed.buffers) == 3
-    assert [str(buf.dtype) for buf in packed.buffers] == \
-        list(sv.WIRE_DTYPES)
+    assert packed.buffer.dtype == np.int32 and packed.buffer.ndim == 1
+    assert len(jax.tree_util.tree_leaves(packed)) == 1
     _assert_same_batch(sv.unpack_batch(packed), hb)
     # and through a jit, where the entrypoints unpack it
     _assert_same_batch(jax.jit(sv.unpack_batch)(jax.device_put(packed)), hb)
@@ -177,7 +177,7 @@ def test_pod_axis_slices_with_pad_rows(start, stop, real):
     np.testing.assert_array_equal(np.asarray(lv), live[start:stop])
     assert sb is None and em is None
     if start == 0:
-        # over 2**31: the counter's bits ride the int32 buffer as they are
+        # over 2**31: the counter's bits ride the int32 carrier as they are
         assert np.asarray(k).dtype == np.uint32
         assert int(k) == 4_000_000_123
     else:
@@ -192,7 +192,7 @@ def test_planes_ride_the_buffers_and_given_arguments_stand():
     bias = rng.rand(4, N_NODES).astype(np.float32)
     packed = sv.pack_batch(hb, live=np.ones(4, bool), counter=np.uint32(7),
                            extra_mask=mask, score_bias=bias)
-    assert len(packed.buffers) == 3
+    assert len(jax.tree_util.tree_leaves(packed)) == 1
     _db, k, sb, lv, em = sv.unpack_launch(packed, None, None, None, None)
     np.testing.assert_array_equal(np.asarray(em), mask)
     np.testing.assert_array_equal(np.asarray(sb), bias)
@@ -221,7 +221,8 @@ def test_layout_follows_shapes_alone_and_a_strange_dtype_is_refused():
     assert sv.pack_batch(hb_a).layout == sv.pack_batch(hb_b).layout
     assert hash(sv.pack_batch(hb_a).layout) == \
         hash(sv.pack_batch(hb_b).layout)
-    paths = [e[0] for e in sv.pack_batch(hb_a, live=np.ones(4, bool)).layout]
+    paths = [e[0] for e in
+             sv.pack_batch(hb_a, live=np.ones(4, bool)).layout[0]]
     assert paths[:len(sv._BATCH_PATHS)] == list(sv._BATCH_PATHS)
     assert paths[len(sv._BATCH_PATHS):] == ["live"]
     with pytest.raises(TypeError, match="request"):
@@ -292,23 +293,68 @@ def _bytes(cause: str = "batch") -> float:
     return _count(metrics.DEVICE_TRANSFER_BYTES, cause)
 
 
-def test_one_launch_hands_the_runtime_three_arrays():
+def test_one_launch_hands_the_runtime_one_array():
     eng = _rig()
     before, bytes_before = _arrays(), _bytes()
     assert None not in _drain(eng, _pods("plain", 6), 8)
-    assert _arrays() - before == 3         # one chunk: <= 4 by the issue
+    assert _arrays() - before == 1         # one chunk, one carrier
     assert _bytes() > bytes_before
     before = _arrays()
     _drain(eng, _pods("plain", 20), 8)     # three chunks, three uploads
-    assert _arrays() - before == 9
+    assert _arrays() - before == 3
     before = _arrays()
     eng.schedule_batch(_pods("plain", 6))
-    assert _arrays() - before == 3
+    assert _arrays() - before == 1
     before = _arrays()
     eng.schedule(make_pod("single", cpu="100m"))
-    assert _arrays() - before == 3
+    assert _arrays() - before == 1
     # the cluster's uploads count their arrays too
     assert _arrays("full_upload") > 0
+
+
+@pytest.mark.parametrize("family", ["plain", "host_mix"])
+def test_placements_agree_across_a_launch_that_scatters(family):
+    """The rows' way through their one buffer: a first wave lands, so the
+    next launch's sync scatters its rows; the streamed drain, the
+    one-shot solve and serial ``schedule()`` (every call after the first
+    a launch that scatters the row before it) then place a second wave
+    alike, and every launch that scattered handed the runtime ONE array
+    for it."""
+    from kubernetes_tpu.engine.generic_scheduler import FitError
+    placed, counters = [], []
+    for mode in ("stream", "oneshot", "serial"):
+        eng = _rig()
+        eng.last_node_index = np.uint32(3_000_000_001)
+        pods = _pods(family, 15)     # 5 rows of 24 dirty: under N/4
+        for pod, dest in zip(pods[:5], eng.schedule_batch(pods[:5])):
+            assert dest is not None
+            pod.node_name = dest
+            eng.cache.add_pod(pod)
+        syncs, arrays = eng.resident.stats["row_syncs"], _arrays("scatter")
+        if mode == "stream":
+            got = _drain(eng, pods[5:], 8)
+        elif mode == "oneshot":
+            got = eng.schedule_batch(pods[5:])
+        else:
+            got = []
+            for pod in pods[5:]:
+                try:
+                    got.append(eng.schedule(pod))
+                except FitError:
+                    got.append(None)
+                    continue
+                pod.node_name = got[-1]
+                eng.cache.add_pod(pod)
+        scattered = eng.resident.stats["row_syncs"] - syncs
+        assert scattered == (sum(g is not None for g in got)
+                             if mode == "serial" else 1)
+        assert _arrays("scatter") - arrays == scattered
+        assert eng.resident.stats["full_syncs"] == 1
+        placed.append(got)
+        counters.append(int(eng.last_node_index))
+    assert placed[0] == placed[1] == placed[2]
+    assert counters[0] == counters[1] == counters[2]
+    assert sum(p is not None for p in placed[0]) >= 5
 
 
 def test_equal_shapes_and_different_content_hit_one_program():

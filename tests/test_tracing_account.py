@@ -289,12 +289,9 @@ def test_lowered_module_names_are_the_ones_the_trace_readers_match():
         ctx.solver, batch, ctx.cluster, counter, None, ctx.flags, None,
         live, None)
     assert "module @jit__solve_scan" in scan.as_text()
-    idx = jax.ShapeDtypeStruct((1,), np.int32)
-    rows = jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct((1,) + s.shape[1:], s.dtype),
-        ctx.cluster)
+    words = sv.rows_layout(sv._cluster_planes(ctx.cluster), 1)[1]
     scatter = sv.ResidentCluster()._scatter_fn().lower(
-        ctx.cluster, idx, rows)
+        ctx.cluster, jax.ShapeDtypeStruct((words,), np.int32), 1)
     assert "module @jit_kt_scatter_rows" in scatter.as_text()
 
 
