@@ -176,6 +176,11 @@ class GenericScheduler:
         self._flags_seen = flags
         return flags
 
+    def _count_scored(self, flags: sv.BatchFlags, pods: int) -> None:
+        """``pods`` live pods go into a scan compiled for ``flags``."""
+        if self.solver.scores_affinity(flags):
+            metrics.AFFINITY_PRIORITY_PODS.inc(pods)
+
     # -- compilation helpers --------------------------------------------
 
     def _compile(self, pods: list[api.Pod], device: bool = True,
@@ -521,6 +526,8 @@ class GenericScheduler:
                               inject=False):
             batch, db, dc, nt = self._compile(pods, live=live_np)
         flags = self._pinned_flags(batch)
+        if not joint:
+            self._count_scored(flags, real_p)
         extra_mask = score_bias = None
         if self._topo_terms is not None:
             from kubernetes_tpu.engine.workloads import topology
@@ -808,6 +815,7 @@ class GenericScheduler:
         with self.guard.watch("stream", inject=False):
             batch, hb, dc, nt = self._compile(all_pods, device=False)
         flags = self._pinned_flags(batch)
+        self._count_scored(flags, p)
         # Spread-constraint planes, host-resident like the batch: each
         # chunk's fixed-shape row slice rides the chunk's packed carrier
         # (pad rows carry no constraints, so their mask rows are

@@ -1112,6 +1112,8 @@ class Solver:
             else:
                 specs.append((name, s.weight, 0))
         self.priority_specs = tuple(specs)
+        self._has_affinity_prio = any(
+            name == "InterPodAffinityPriority" for name, _w, _aux in specs)
         self.passthrough = tuple(n for n in self.predicate_names
                                  if n in PASSTHROUGH_PREDICATES)
         # MaxPD caps: policy value, else KUBE_MAX_PD_VOLS env, else provider
@@ -1239,6 +1241,12 @@ class Solver:
             ports_used=final.get("ports_used", c.ports_used),
             vol_any=final.get("vol_any", c.vol_any),
             vol_rw=final.get("vol_rw", c.vol_rw))
+
+    def scores_affinity(self, flags: BatchFlags) -> bool:
+        """Whether a scan compiled for ``flags`` carries
+        InterPodAffinityPriority as a dynamic priority
+        (``_scan_families``' rule for it)."""
+        return flags.any_affinity_prio and self._has_affinity_prio
 
     def _scan_families(self, flags: BatchFlags) -> ScanFamilies:
         """The scan's trace-time specialization for this policy and these

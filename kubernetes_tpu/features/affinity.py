@@ -43,6 +43,11 @@ from kubernetes_tpu.api import types as api
 from kubernetes_tpu.features import compiler as fc
 from kubernetes_tpu.features.padcap import pad1 as _pad1, pow2 as _pow2
 from kubernetes_tpu.utils import metrics
+from kubernetes_tpu.utils.trace import stage
+
+_LAUNCH_SIGNATURES = {
+    family: metrics.AFFINITY_LAUNCH_SIGNATURES.labels(family=family)
+    for family in ("match", "decl", "sym")}
 
 # Resolved namespace marker: () after resolution means "all namespaces".
 _ALL_NS = ()
@@ -285,33 +290,40 @@ def _declared_sigs(pod: api.Pod, hard_pod_affinity_weight: int
 
 
 class _Planes:
-    """One signature family's resident planes: ``cnt[row]`` is an [N]
-    int32 count per node (pods whose topology reaches the node) and
-    ``total[row]`` the pods counted, for the signature ``rows`` maps to
-    ``row``.  Rows are reused; the arrays double when they run out."""
+    """One signature family's resident planes.  For the signature
+    ``rows`` maps to ``row``, ``cnt[row]`` is an int32 count per DOMAIN
+    of the signature's topology key (the pods that sit in the domain:
+    every node of a domain reads the same, so an update writes one
+    element and a launch gathers the row back to the nodes,
+    ``ResidentAffinity.node_row``; one more count behind them stays zero
+    for the nodes without the label) — or, for the empty key, per NODE
+    (the pods whose default failure domains reach the node) — and
+    ``total[row]`` the pods counted.  Row numbers are reused; ``total``
+    doubles when they run out."""
 
-    def __init__(self, n: int):
+    def __init__(self):
         self.rows: dict[Sig, int] = {}
-        self.cnt = np.zeros((4, n), np.int32)
+        self.cnt: dict[int, np.ndarray] = {}
         self.total = np.zeros(4, np.int64)
         self._free = [3, 2, 1, 0]
 
-    def row(self, sig: Sig) -> int:
-        """The signature's row, a new all-zero one when it has none."""
+    def row(self, sig: Sig, width: int) -> int:
+        """The signature's row, a new all-zero one of ``width`` counts
+        when it has none."""
         r = self.rows.get(sig)
         if r is None:
             if not self._free:
                 have = len(self.total)
-                self.cnt = np.concatenate([self.cnt, np.zeros_like(self.cnt)])
                 self.total = np.concatenate(
                     [self.total, np.zeros_like(self.total)])
                 self._free = list(range(2 * have - 1, have - 1, -1))
             r = self.rows[sig] = self._free.pop()
+            self.cnt[r] = np.zeros(width, np.int32)
         return r
 
     def drop(self, sig: Sig) -> None:
         r = self.rows.pop(sig)
-        self.cnt[r] = 0
+        del self.cnt[r]
         self.total[r] = 0
         self._free.append(r)
 
@@ -353,7 +365,8 @@ class ResidentAffinity:
 
     def planes(self) -> dict[tuple[str, Sig], tuple[np.ndarray, int]]:
         """``{(family, signature): ([N] counts, total)}``, copied."""
-        return {(family, sig): (p.cnt[r].copy(), int(p.total[r]))
+        return {(family, sig): (np.array(self.node_row(p, sig)),
+                                int(p.total[r]))
                 for family, p in (("match", self.match), ("decl", self.decl),
                                   ("sym", self.sym))
                 for sig, r in p.rows.items()}
@@ -366,7 +379,7 @@ class ResidentAffinity:
         fresh._reset(self._nodes, self.n, self.hard_weight)
         fresh.valid = True
         for sig in self.match.rows:
-            fresh.match.row(sig)
+            fresh._row(fresh.match, sig)
         return fresh
 
     def fill(self, attached: Iterable[tuple[api.Pod, int]]) -> None:
@@ -380,9 +393,8 @@ class ResidentAffinity:
         self.n = n
         self.hard_weight = hard_pod_affinity_weight
         self._dom: dict[str, np.ndarray] = {}
-        self._own_domain: set[str] = set()
         self._declared_memo: dict = {}
-        self.match, self.decl, self.sym = _Planes(n), _Planes(n), _Planes(n)
+        self.match, self.decl, self.sym = _Planes(), _Planes(), _Planes()
         self._match_memo: dict = {}
 
     def invalidate(self) -> None:
@@ -419,35 +431,69 @@ class ResidentAffinity:
                 if v:
                     d[i] = vals.setdefault(v, len(vals))
             self._dom[key] = d
-            if len(vals) == int((d >= 0).sum()):
-                self._own_domain.add(key)     # the hostname's shape
         return d
 
-    def _bump(self, row: np.ndarray, key: str, nidx: int, sign: int) -> None:
-        """``row[j] += sign`` for every node ``j`` sharing ``nidx``'s
-        topology under ``key`` ("" = any default failure domain):
-        ``_DomainTable.same_topo_row``, added in place."""
+    def _row(self, planes: _Planes, sig: Sig) -> int:
+        """The signature's row in ``planes``, made when it has none: a
+        count per domain of its key and one more that stays zero — what
+        a node without the label (domain -1) reads, all there is for a
+        key no node carries — or a count per node for the empty key."""
+        r = planes.rows.get(sig)
+        if r is None:
+            if sig.key:
+                width = int(self.dom_row(sig.key).max(initial=-1)) + 2
+            else:
+                width = self.n
+            r = planes.row(sig, width)
+        return r
+
+    def _bump(self, counts: np.ndarray, key: str, nidx: int,
+              sign: int) -> int:
+        """A row's ``counts`` ``+= sign`` for a pod on node ``nidx``
+        under ``key``: the count of the node's domain, or for the empty
+        key every node that shares one of ``nidx``'s default failure
+        domains (``_DomainTable.same_topo_row``, added in place).
+        Returns the elements written: 1 for a named key (0 where the
+        node lacks the label), the nodes reached for the empty one."""
         if not key:
             reach = np.zeros(self.n, bool)
             for k in api.DEFAULT_FAILURE_DOMAINS:
                 d = self.dom_row(k)
                 reach |= (d == d[nidx]) & (d >= 0)
-            row[reach] += sign
-            return
-        d = self.dom_row(key)
-        dom = d[nidx]
+            counts[reach] += sign
+            return int(np.count_nonzero(reach))
+        dom = self.dom_row(key)[nidx]
         if dom < 0:
-            return
-        if key in self._own_domain:       # every node a domain of its own
-            row[nidx] += sign
-        else:
-            row[d == dom] += sign
+            return 0
+        counts[dom] += sign
+        return 1
+
+    def node_row(self, planes: _Planes, sig: Sig) -> np.ndarray:
+        """[N] int32 counts of a signature's plane: the per-domain counts
+        gathered back to the nodes (a node that lacks the key reads the
+        row's last count, always zero), the row itself for the empty
+        key."""
+        counts = planes.cnt[planes.rows[sig]]
+        if not sig.key:
+            return counts
+        return counts[self.dom_row(sig.key)]
 
     # -- resident pods ------------------------------------------------------
 
     def _matched(self, pod: api.Pod) -> tuple:
         """Registered match signatures the pod's namespace and labels
-        satisfy, memoized by that template."""
+        satisfy, memoized by that template.
+
+        How much this keeps is the deployment's, not the code's: the
+        memo holds one entry per (namespace, labels) template seen since
+        the last match registration (cleared at 4,096) and the planes
+        one row per signature some batch carried (match) or some
+        resident pod declares (decl / sym); nothing bounds the
+        signatures themselves (ROADMAP.md Reach A3).  The first size to
+        set such a bound against is upstream's MixedSchedulingBasePod
+        (``benchmarks/configs/mixedaffinity-5000n.json``): 5 label
+        templates in the memo and 4 match, 1 decl and 3 sym signatures
+        (``tests/test_mixed_affinity.py`` holds those numbers)."""
         tkey = (pod.namespace, tuple(sorted(pod.labels.items())))
         got = self._match_memo.get(tkey)
         if got is None:
@@ -460,12 +506,20 @@ class ResidentAffinity:
 
     def add_pod(self, pod: api.Pod, nidx: int) -> None:
         """One pod attached to node row ``nidx``."""
-        if self.valid and self._add(pod, nidx, 1):
-            metrics.AFFINITY_TABLE_ROW_UPDATES.inc()
+        if self.valid:
+            self._count(self._add(pod, nidx, 1))
 
     def remove_pod(self, pod: api.Pod, nidx: int) -> None:
-        if self.valid and self._add(pod, nidx, -1):
+        if self.valid:
+            self._count(self._add(pod, nidx, -1))
+
+    @staticmethod
+    def _count(cells: int) -> None:
+        """One counted update of the planes that wrote ``cells``
+        elements (0: the pod touched no plane)."""
+        if cells:
             metrics.AFFINITY_TABLE_ROW_UPDATES.inc()
+            metrics.AFFINITY_PLANE_CELLS.inc(cells)
 
     def _declared(self, pod: api.Pod) -> tuple[list[Sig], list[Sig]]:
         """``_declared_sigs``, memoized by the annotation's text and the
@@ -481,28 +535,30 @@ class ResidentAffinity:
                 _declared_sigs(pod, self.hard_weight)
         return got
 
-    def _add(self, pod: api.Pod, nidx: int, sign: int) -> bool:
-        """Whether any plane changed."""
+    def _add(self, pod: api.Pod, nidx: int, sign: int) -> int:
+        """Elements of the planes written, 0 = the pod touched none.  On
+        a node that lacks a signature's key the pod moves the
+        signature's ``total`` alone: that is the one element."""
         if not 0 <= nidx < self.n:
-            return False
-        touched = False
+            return 0
+        cells = 0
         if self.match.rows:
             for sig in self._matched(pod):
                 r = self.match.rows[sig]
-                self._bump(self.match.cnt[r], sig.key, nidx, sign)
+                cells += self._bump(self.match.cnt[r], sig.key, nidx,
+                                    sign) or 1
                 self.match.total[r] += sign
-                touched = True
         if pod.affinity() is not None:
             decl, sym = self._declared(pod)
             for planes, sigs in ((self.decl, decl), (self.sym, sym)):
                 for sig in sigs:
-                    r = planes.row(sig)
-                    self._bump(planes.cnt[r], sig.key, nidx, sign)
+                    r = self._row(planes, sig)
+                    cells += self._bump(planes.cnt[r], sig.key, nidx,
+                                        sign) or 1
                     planes.total[r] += sign
                     if planes.total[r] == 0:
                         planes.drop(sig)
-                    touched = True
-        return touched
+        return cells
 
     # -- what a launch reads ------------------------------------------------
 
@@ -518,23 +574,21 @@ class ResidentAffinity:
         seen before is registered by one pass over the resident pods."""
         r = self.match.rows.get(sig)
         if r is None:
-            r = self.match.row(sig)
+            r = self._row(self.match, sig)
             self._match_memo.clear()
             if self._counted:
                 metrics.AFFINITY_TABLE_REBUILDS.inc()
             nidxs = ep.node_idx[_sig_match_existing(sig, ep, space)]
             self.match.total[r] = len(nidxs)
+            counts = self.match.cnt[r]
             if sig.key:
-                # per-domain counts, gathered back to the nodes
-                d = self.dom_row(sig.key)
-                doms = d[nidxs]
-                per_dom = np.bincount(doms[doms >= 0],
-                                      minlength=max(int(d.max(initial=-1)) + 1, 1))
-                self.match.cnt[r] = np.where(d >= 0, per_dom[d], 0)
+                doms = self.dom_row(sig.key)[nidxs]
+                counts[:] = np.bincount(doms[doms >= 0],
+                                        minlength=len(counts))
             else:
                 for ni in nidxs.tolist():
-                    self._bump(self.match.cnt[r], "", ni, 1)
-        return self.match.cnt[r], int(self.match.total[r])
+                    self._bump(counts, "", ni, 1)
+        return self.node_row(self.match, sig), int(self.match.total[r])
 
 
 def compile_affinity(pods: Sequence[api.Pod],
@@ -614,20 +668,15 @@ def compile_affinity(pods: Sequence[api.Pod],
         sym_live = sorted(sym_sources, key=_sig_order)
     for sig in decl_live:
         d_tab.idx(sig)
-    for sig in sym_live:
-        y_tab.idx(sig)
     if decl_live or sym_live:
         any_affinity = True
 
     # Batch pods that DECLARE terms (for in-batch sequential visibility):
     # placing pod j extends decl reach / sym counts / match counts.
     # Register their sigs too so the scan state has rows for them.
-    pod_decl: list[list[int]] = []
-    pod_sym: list[list[int]] = []
-    for pod in cand:
-        decl, sym = _declared_sigs(pod, hard_pod_affinity_weight)
-        pod_decl.append([d_tab.idx(sig) for sig in decl])
-        pod_sym.append([y_tab.idx(sig) for sig in sym])
+    declared = [_declared_sigs(pod, hard_pod_affinity_weight)
+                for pod in cand]
+    pod_decl = [[d_tab.idx(sig) for sig in decl] for decl, _sym in declared]
 
     # Assign key rows now that all sigs are known.
     def key_row(sig: Sig) -> int:
@@ -635,7 +684,15 @@ def compile_affinity(pods: Sequence[api.Pod],
 
     m_rows = [key_row(s) for s in m_tab.sigs]
     d_rows = [key_row(s) for s in d_tab.sigs]
-    y_rows = [key_row(s) for s in y_tab.sigs]
+    # The SCORE side of the tables (InterPodAffinityPriority alone reads
+    # it) is stage ``compile.affinity.prio``, in two parts: the sym
+    # signatures here, their rows and the pods' score incidence below.
+    with stage("compile.affinity.prio"):
+        for sig in sym_live:
+            y_tab.idx(sig)
+        pod_sym = [[y_tab.idx(sig) for sig in sym]
+                   for _decl, sym in declared]
+        y_rows = [key_row(s) for s in y_tab.sigs]
     if resident is not None:
         node_dom = np.stack([resident.dom_row(k) for k in dt.keys])
     else:
@@ -660,9 +717,7 @@ def compile_affinity(pods: Sequence[api.Pod],
                 match_cnt[si], match_total[si] = \
                     resident.match_row(sig, ep, space)
         for si, sig in enumerate(decl_live):
-            decl_reach[si] = resident.decl.cnt[resident.decl.rows[sig]] > 0
-        for si, sig in enumerate(sym_live):
-            sym_cnt[si] = resident.sym.cnt[resident.sym.rows[sig]]
+            decl_reach[si] = resident.node_row(resident.decl, sig) > 0
     else:
         # -- the resident side, from nothing -----------------------------
         if ep is not None:
@@ -679,10 +734,6 @@ def compile_affinity(pods: Sequence[api.Pod],
             si = d_tab.sig_to_idx[sig]
             for ni in set(nidxs):
                 decl_reach[si] |= dt.same_topo_row(node_dom, d_rows[si], ni)
-        for sig, nidxs in sym_sources.items():
-            si = y_tab.sig_to_idx[sig]
-            for ni in nidxs:  # one instance per declaring term occurrence
-                sym_cnt[si] += dt.same_topo_row(node_dom, y_rows[si], ni)
 
     # -- per-pod incidence matrices --------------------------------------
     aff_need = np.zeros((p, sm), bool)
@@ -698,37 +749,58 @@ def compile_affinity(pods: Sequence[api.Pod],
     # Candidate-vs-sig matching memoized by (namespace, labels) template:
     # pods stamped from one controller share labels, so each template is
     # matched against each sig family once.
-    tmpl_cache: dict = {}
+    def matches(pod: api.Pod, sigs: list, cache: dict) -> np.ndarray:
+        tkey = (pod.namespace, tuple(sorted(pod.labels.items())))
+        row = cache.get(tkey)
+        if row is None:
+            row = cache[tkey] = np.array(
+                [_pod_matches_sig(s, pod.namespace, pod.labels)
+                 for s in sigs] or [False], bool)
+        return row
+
+    m_cache: dict = {}
+    d_cache: dict = {}
     for i, pod in enumerate(cand):
         for si, kind in pod_m[i]:
             if kind == "aff":
                 aff_need[i, si] = True
             else:
                 anti_need[i, si] = True
-        for si, w in pod_pref[i]:
-            pref_w[i, si] += w
         for si in pod_decl[i]:
             decl_src[i, si] = True
-        for si in pod_sym[i]:
-            sym_src[i, si] = True
-        tkey = (pod.namespace, tuple(sorted(pod.labels.items())))
-        rows = tmpl_cache.get(tkey)
-        if rows is None:
-            rows = (
-                np.array([_pod_matches_sig(s, pod.namespace, pod.labels)
-                          for s in m_tab.sigs] or [False], bool),
-                np.array([_pod_matches_sig(s, pod.namespace, pod.labels)
-                          for s in d_tab.sigs] or [False], bool),
-                np.array([_pod_matches_sig(s, pod.namespace, pod.labels)
-                          for s in y_tab.sigs] or [False], bool))
-            tmpl_cache[tkey] = rows
-        match_src[i, :len(rows[0])] = rows[0][:sm]
-        decl_match[i, :len(rows[1])] = rows[1][:sd]
-        sym_match[i, :len(rows[2])] = rows[2][:sy]
+        row = matches(pod, m_tab.sigs, m_cache)
+        match_src[i, :len(row)] = row[:sm]
+        row = matches(pod, d_tab.sigs, d_cache)
+        decl_match[i, :len(row)] = row[:sd]
         # Self-match escape hatch (predicates.go:1038-1048).
         for si, kind in pod_m[i]:
             if kind == "aff" and match_src[i, si]:
                 aff_self[i, si] = True
+
+    # -- the score side: sym rows, preferred weights, sym incidence ------
+    with stage("compile.affinity.prio"):
+        if resident is not None:
+            for si, sig in enumerate(sym_live):
+                sym_cnt[si] = resident.node_row(resident.sym, sig)
+        else:
+            for sig, nidxs in sym_sources.items():
+                si = y_tab.sig_to_idx[sig]
+                for ni in nidxs:  # one instance per declaring occurrence
+                    sym_cnt[si] += dt.same_topo_row(node_dom, y_rows[si],
+                                                    ni)
+        y_cache: dict = {}
+        for i, pod in enumerate(cand):
+            for si, w in pod_pref[i]:
+                pref_w[i, si] += w
+            for si in pod_sym[i]:
+                sym_src[i, si] = True
+            row = matches(pod, y_tab.sigs, y_cache)
+            sym_match[i, :len(row)] = row[:sy]
+        sym_w = _pad1([s.weight for s in y_tab.sigs], sy, 0, np.float32)
+    if resident is not None and resident._counted:
+        for family, tab in (("match", m_tab), ("decl", d_tab),
+                            ("sym", y_tab)):
+            _LAUNCH_SIGNATURES[family].inc(len(tab.sigs))
 
     if tpl_idx is not None:
         # Expand template rows back to the full pod axis.
@@ -748,6 +820,6 @@ def compile_affinity(pods: Sequence[api.Pod],
         decl_key=_pad1(d_rows, sd, -1, np.int32),
         decl_reach=decl_reach, decl_match=decl_match, decl_src=decl_src,
         sym_key=_pad1(y_rows, sy, -1, np.int32),
-        sym_w=_pad1([s.weight for s in y_tab.sigs], sy, 0, np.float32),
+        sym_w=sym_w,
         sym_cnt=sym_cnt, sym_match=sym_match, sym_src=sym_src,
         has_any=any_affinity)

@@ -808,6 +808,23 @@ AFFINITY_SIGNATURES = register(Gauge(
     "Signatures the kept affinity tables hold a plane for, by family "
     "(match / decl / sym), as the last launch found them",
     labelnames=("family",)))
+AFFINITY_PRIORITY_PODS = register(Counter(
+    "scheduler_affinity_priority_pods_total",
+    "Pods of the launches (streamed or one-shot) whose scan carried "
+    "InterPodAffinityPriority as a dynamic priority: every pod of a "
+    "launch once BatchFlags.any_affinity_prio is pinned, none before"))
+AFFINITY_LAUNCH_SIGNATURES = register(Counter(
+    "scheduler_affinity_launch_signatures_total",
+    "Rows of the affinity tables a launch's compile handed the scan, by "
+    "family (match / decl / sym), padding apart: the signatures of the "
+    "batch's own terms and of the terms the resident pods declare",
+    labelnames=("family",)))
+AFFINITY_PLANE_CELLS = register(Counter(
+    "scheduler_affinity_plane_cells_total",
+    "Elements of the kept affinity planes written by the one-at-a-time "
+    "updates, under the cache lock: 1 for a term on a named topology key "
+    "(the count of the node's domain, hostname or zone), the nodes "
+    "reached for a term with an empty key"))
 # The daemon's cyclic collector (utils/gcstats.py, installed at daemon
 # start): every Python thread stands still for a collection.
 GC_PAUSE_SECONDS = register(Counter(

@@ -22,7 +22,7 @@ import pytest
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.cache.scheduler_cache import SchedulerCache
 from kubernetes_tpu.engine import solver as sv
-from kubernetes_tpu.engine.generic_scheduler import GenericScheduler
+from kubernetes_tpu.engine.generic_scheduler import FitError, GenericScheduler
 from kubernetes_tpu.features import affinity as fa
 from kubernetes_tpu.features import batch as fb
 from kubernetes_tpu.utils import metrics
@@ -175,6 +175,95 @@ def test_kept_tables_equal_from_nothing_after_any_sequence(seed):
     # the kept planes were built from nothing only when the node rows or
     # their labels changed (or for the other weight), never per launch
     assert metrics.AFFINITY_TABLE_REBUILDS.value > rebuilds0
+
+
+def _absent_key_templates(key: str) -> list[dict]:
+    """Required and preferred terms of both kinds on ``key``."""
+    sel = {"app": "db"}
+    return [
+        dict(labels={"app": "db"}, affinity={"podAffinity": {
+            "requiredDuringSchedulingIgnoredDuringExecution": [
+                _term(sel, key)]}}),
+        dict(labels={"app": "web"}, affinity={"podAntiAffinity": {
+            "requiredDuringSchedulingIgnoredDuringExecution": [
+                _term(sel, key)]}}),
+        dict(labels={"app": "db"}, affinity={
+            "podAffinity": {
+                "preferredDuringSchedulingIgnoredDuringExecution": [
+                    _weighted(_term(sel, key), 4)]},
+            "podAntiAffinity": {
+                "preferredDuringSchedulingIgnoredDuringExecution": [
+                    _weighted(_term({"app": "web"}, key), 2)]}}),
+    ]
+
+
+@pytest.mark.parametrize("key", ["example.com/rack", ZONE])
+@pytest.mark.parametrize("first", ["batch", "resident"])
+def test_a_key_no_node_carries_keeps_zero_rows(key, first):
+    """A term on a topology key the fleet lacks (a zone term on a
+    zone-less fleet) has no domain: its kept rows stay zero and equal the
+    build from nothing, whether a batch registers the signature against
+    resident pods (``match_row``'s pass) or a resident pod declares it,
+    through add / assume / delete."""
+    templates = _absent_key_templates(key)
+    cache = SchedulerCache()
+    for i in range(5):
+        cache.add_node(_node(i, None))
+    batch = [make_pod(name=f"b{k}", **t) for k, t in enumerate(templates)]
+    if first == "batch":
+        for i in range(3):                 # matched by the batch's terms
+            cache.add_pod(make_pod(name=f"db{i}", node_name=f"n{i}",
+                                   labels={"app": "db"}))
+        _assert_tables_equal(cache, batch)
+    resident = []
+    for k, t in enumerate(templates * 2):
+        pod = make_pod(name=f"r{k}", **t)
+        if k % 2:
+            cache.assume_pod(pod, f"n{k % 5}")
+        else:
+            pod.node_name = f"n{k % 5}"
+            cache.add_pod(pod)
+        resident.append(pod)
+        _assert_tables_equal(cache, batch)
+    aff = cache.affinity_tables()
+    for planes in (aff.match, aff.decl, aff.sym):
+        assert planes.rows
+        for sig, r in planes.rows.items():
+            assert sig.key == key and not planes.cnt[r].any()
+            assert not aff.node_row(planes, sig).any()
+    assert cache.affinity_planes_drift() == []
+    for pod in resident:
+        cache.remove_pod(pod)
+        _assert_tables_equal(cache, batch)
+    assert not aff.decl.rows and not aff.sym.rows
+
+
+def test_engine_answers_terms_on_a_key_no_node_carries():
+    """On the launch path: a required affinity term on a key the fleet
+    lacks finds no node in its target's topology (unschedulable), a
+    required anti-affinity or a preferred term on it keeps nothing out."""
+    key = "example.com/rack"
+    need, avoid, prefer = _absent_key_templates(key)
+    for route in ("batch", "serial"):
+        s = GenericScheduler()
+        for i in range(4):
+            s.cache.add_node(_node(i, None))
+        s.cache.add_pod(make_pod(name="db0", node_name="n0",
+                                 labels={"app": "db"}))
+        pods = [make_pod(name="need", cpu="100m", memory="64Mi", **need),
+                make_pod(name="avoid", cpu="100m", memory="64Mi", **avoid),
+                make_pod(name="prefer", cpu="100m", memory="64Mi", **prefer)]
+        if route == "batch":
+            got = s.schedule_batch(pods)
+        else:
+            got = []
+            for pod in pods:
+                try:
+                    got.append(s.schedule(pod))
+                except FitError:
+                    got.append(None)
+        assert got[0] is None, route
+        assert got[1] is not None and got[2] is not None, route
 
 
 def test_a_launch_follows_the_batch_not_the_resident_population():
@@ -441,7 +530,7 @@ def test_verifier_counts_and_heals_a_drifted_plane():
     assert verifier.verify_once() == []
     assert metrics.AFFINITY_TABLE_REBUILDS.value == rebuilds   # not counted
     aff = cache.affinity_tables()
-    aff.decl.cnt[next(iter(aff.decl.rows.values())), 4] += 1   # drift
+    aff.decl.cnt[next(iter(aff.decl.rows.values()))][4] += 1   # drift
     found = verifier.verify_once()
     assert [v.kind for v in found] == ["affinity_planes"]
     _assert_tables_equal(cache, [make_pod(name="b2", **TEMPLATES[0])])
