@@ -144,6 +144,16 @@ class SchedulerCache:
         # the same 1:1 engine/cache pairing _compile already assumes.
         self.tensor_epoch = 0
         self._dirty_rows: set[int] = set()
+        # ``node_epoch`` moves whenever NODE-side content can have
+        # changed — a node added, removed, or updated with something
+        # different, the rebuild of the node tensors (every path that
+        # marks the nodes dirty ends there, ``ensure_topo_key`` too) —
+        # and never on a pod event.  The feature build keys what it keeps
+        # between launches on it (features/plan.py).  ``_node_list`` is
+        # the ``api.Node`` objects in row order, made once per epoch and
+        # handed out by ``snapshot()`` / ``nodes()``: read-only to callers.
+        self.node_epoch = 0
+        self._node_list: Optional[list[api.Node]] = None
         # Churn observability: full rebuilds vs incremental row updates.
         self.stats = {"rebuilds": 0, "rebuild_s": 0.0,
                       "incremental_node_updates": 0}
@@ -165,6 +175,7 @@ class SchedulerCache:
             fc.update_node_row(self._nt, idx, node, self.space)
             if old.labels != node.labels:
                 self._aff.invalidate()
+            self._node_changed(old, node, idx)
             self._dirty_rows.add(idx)
             self.stats["incremental_node_updates"] += 1
             self.generation += 1
@@ -176,6 +187,7 @@ class SchedulerCache:
             fc.append_aggregate_row(self._agg)
             self._aff.invalidate()
             self._node_order.append(node.name)
+            self._node_changed()
             self.tensor_epoch += 1
             self.stats["incremental_node_updates"] += 1
             self.generation += 1
@@ -201,6 +213,7 @@ class SchedulerCache:
             fc.update_node_row(self._nt, idx, node, self.space)
             if old is None or old.labels != node.labels:
                 self._aff.invalidate()
+            self._node_changed(old, node, idx)
             self._dirty_rows.add(idx)
             self.stats["incremental_node_updates"] += 1
             self.generation += 1
@@ -216,6 +229,22 @@ class SchedulerCache:
     def _mark_nodes_dirty(self) -> None:
         self._dirty_nodes = True
         self.generation += 1
+
+    def _node_changed(self, old: Optional[api.Node] = None,
+                      node: Optional[api.Node] = None,
+                      idx: int = -1) -> None:
+        """Move ``node_epoch`` for the node tensors rebuilt, or for row
+        ``idx`` appended or written in place — unless the update changed
+        nothing: ``api.Node`` holds only what the features read (no
+        heartbeat time, no resource version), so an equal object is a
+        status heartbeat, and takes its twin's place in the kept list.
+        The SAME object handed in again was mutated by its owner and
+        cannot be told from its old self: that moves the epoch."""
+        if old is None or old is node or old != node:
+            self.node_epoch += 1
+            self._node_list = None
+        elif self._node_list is not None:
+            self._node_list[idx] = node
 
     # ---- pod state machine --------------------------------------------
 
@@ -422,8 +451,14 @@ class SchedulerCache:
 
     @_locked
     def nodes(self) -> list[api.Node]:
+        """The nodes in row order: one list per ``node_epoch``, shared by
+        every caller until a node event — not to be mutated."""
         self._ensure_tensors()
-        return [self._nodes[n] for n in self._node_order]
+        nodes = self._node_list
+        if nodes is None:
+            nodes = self._node_list = [self._nodes[n]
+                                       for n in self._node_order]
+        return nodes
 
     @_locked
     def node_pods(self, node_name: str) -> list[api.Pod]:
@@ -551,6 +586,7 @@ class SchedulerCache:
                 self._ep, pods, idxs, self.space)
         self._aff.invalidate()
         self._dirty_nodes = False
+        self._node_changed()
         # Relist/rebuild: row identity moved — the device mirror must
         # re-upload; any pending per-row deltas are subsumed.
         self.tensor_epoch += 1
@@ -731,5 +767,4 @@ class SchedulerCache:
         self._ensure_tensors()
         # Existing-pod label matrix may lag vocab growth from newly seen pods.
         self._ep.labels = fc._grow_cols(self._ep.labels, self.space.pod_labels.capacity)
-        return self._nt, self._agg, self._ep, \
-            [self._nodes[n] for n in self._node_order]
+        return self._nt, self._agg, self._ep, self.nodes()

@@ -36,6 +36,7 @@ from kubernetes_tpu.utils import metrics
 from kubernetes_tpu.features import batch as fb
 from kubernetes_tpu.features import compiler as fc
 from kubernetes_tpu.features import padcap
+from kubernetes_tpu.features.plan import FeaturePlan
 from kubernetes_tpu.features.volumes import compile_volsvc
 from kubernetes_tpu.utils.logging import get_logger
 from kubernetes_tpu.utils.trace import Trace, record_stage, stage
@@ -163,6 +164,19 @@ class GenericScheduler:
         # Spread-constraint term tables for the batch _compile last saw
         # (None = no pod carried topologySpreadConstraints).
         self._topo_terms = None
+        # What the feature build keeps between launches: the node-side
+        # and template-side tables of compile_batch / compile_volsvc,
+        # valid for one ``cache.node_epoch`` (features/plan.py).  Read
+        # and written in _compile's locked section only.
+        self._plan = FeaturePlan()
+        # The policy's arguments to compile_volsvc (the policy of an
+        # engine never changes).
+        self._volsvc_args = dict(
+            service_affinity_labels=service_affinity_labels(self.policy),
+            service_anti_affinity_labels=service_anti_affinity_labels(
+                self.policy),
+            node_label_args=node_label_args(self.policy),
+            node_label_prio_args=node_label_prio_args(self.policy))
 
     def _pinned_flags(self, batch) -> sv.BatchFlags:
         """Content flags OR-ed monotonically (padcap's discipline for the
@@ -182,6 +196,33 @@ class GenericScheduler:
             metrics.AFFINITY_PRIORITY_PODS.inc(pods)
 
     # -- compilation helpers --------------------------------------------
+
+    def _features(self, pods: list[api.Pod], nt: fc.NodeTensors,
+                  ep: fc.ExistingPodTensors, nodes: list[api.Node],
+                  plan: Optional[FeaturePlan],
+                  caps: dict[str, int]) -> fb.PodBatch:
+        """The host feature build of one launch against a snapshot, under
+        the cache lock: the volume / service tables, the pod batch, the
+        monotonic caps.  With the engine's ``plan`` what only a node
+        event or a new template changes is looked up; ``plan=None``
+        builds all of it from nothing — the same batch to the element
+        (tests/test_feature_plan.py)."""
+        volsvc = compile_volsvc(
+            pods, nodes, nt.schedulable,
+            volume_pods=self.cache.volume_pods(),
+            listers=self.listers,
+            service_peers=self.cache.service_peer_nodes,
+            first_peer=self.cache.first_peer_node,
+            plan=plan, **self._volsvc_args)
+        batch = fb.compile_batch(
+            pods, nt, self.cache.space, ep=ep, nodes=nodes,
+            spread_selectors=self.listers.spread_selectors,
+            controller_refs=self.listers.controller_refs,
+            resident_affinity=self.cache.affinity_tables(),
+            hard_pod_affinity_weight=(
+                self.policy.hard_pod_affinity_symmetric_weight),
+            volsvc=volsvc, plan=plan)
+        return padcap.apply_caps(batch, caps)
 
     def _compile(self, pods: list[api.Pod], device: bool = True,
                  host_only: bool = False, live: np.ndarray | None = None
@@ -222,27 +263,13 @@ class GenericScheduler:
                 # since).
                 self._snapshot_generation = self.cache.generation
             with stage("compile", pods=len(pods)):
-                volsvc = compile_volsvc(
-                    pods, nodes, nt.schedulable,
-                    volume_pods=self.cache.volume_pods(),
-                    listers=self.listers,
-                    service_affinity_labels=service_affinity_labels(
-                        self.policy),
-                    service_anti_affinity_labels=(
-                        service_anti_affinity_labels(self.policy)),
-                    node_label_args=node_label_args(self.policy),
-                    node_label_prio_args=node_label_prio_args(self.policy),
-                    service_peers=self.cache.service_peer_nodes,
-                    first_peer=self.cache.first_peer_node)
-                batch = fb.compile_batch(
-                    pods, nt, self.cache.space, ep=ep, nodes=nodes,
-                    spread_selectors=self.listers.spread_selectors,
-                    controller_refs=self.listers.controller_refs,
-                    resident_affinity=self.cache.affinity_tables(),
-                    hard_pod_affinity_weight=(
-                        self.policy.hard_pod_affinity_symmetric_weight),
-                    volsvc=volsvc)
-                batch = padcap.apply_caps(batch, self._axis_caps)
+                plan = self._plan
+                plan.begin(self.cache.node_epoch)
+                batch = self._features(pods, nt, ep, nodes, plan,
+                                       self._axis_caps)
+                result, cause = plan.outcome()
+                metrics.FEATURE_PLAN.labels(result=result,
+                                            cause=cause).inc()
                 # Spread-constraint term tables (counts snapshotted under
                 # the same lock as everything else this solve reads).
                 self._topo_terms = topology.compile_terms(
@@ -515,9 +542,7 @@ class GenericScheduler:
         real_p = len(pods)
         live_np = None
         if pad_to > real_p:
-            pods = list(pods) + [
-                api.Pod(name=f"__pad-{i}", namespace="__pad__")
-                for i in range(pad_to - real_p)]
+            pods = fb.pad_pods(pods, pad_to)
             live_np = np.zeros(len(pods), bool)
             live_np[:real_p] = True
         # The tie counter and the live mask ride db's carrier: the solve
@@ -607,6 +632,11 @@ class GenericScheduler:
         names = nt.names
         return [names[c] if c >= 0 else None for c in rows]
 
+    def plan_report(self) -> dict:
+        """What the feature build keeps, for ``/debug/vars``."""
+        with self.cache.lock:
+            return self._plan.report()
+
     def take_agg_handoff(self) -> Optional[tuple]:
         """One-shot: the (generation, requested, nonzero) handoff from the
         last schedule_batch, if any (see assume_pods)."""
@@ -641,9 +671,7 @@ class GenericScheduler:
             return {pod.key: {"message": "no nodes in cluster",
                               "failed_predicates": {}}
                     for pod in pods}
-        padded = list(pods) + [
-            api.Pod(name=f"__explain-pad-{i}", namespace="__pad__")
-            for i in range(self.EXPLAIN_CAP - len(pods))]
+        padded = fb.pad_pods(pods, self.EXPLAIN_CAP)
         batch, db, dc, nt = self._compile(padded)
         masks = {name: np.asarray(m) for name, m in
                  self.solver.masks(db, dc).items()}
@@ -701,9 +729,7 @@ class GenericScheduler:
         pods = pods[:self.PREEMPT_CAP]
         if not pods or not self.cache.nodes():
             return []
-        padded = list(pods) + [
-            api.Pod(name=f"__preempt-pad-{i}", namespace="__pad__")
-            for i in range(self.PREEMPT_CAP - len(pods))]
+        padded = fb.pad_pods(pods, self.PREEMPT_CAP)
         batch, db, dc, nt = self._compile(padded)
         # Non-resource predicate rows: victims free resources, nothing
         # else — a node that only becomes selector/taint-feasible after
@@ -808,10 +834,7 @@ class GenericScheduler:
             return
         n_chunks = (p + chunk_size - 1) // chunk_size
         padded = n_chunks * chunk_size
-        all_pods = list(pods)
-        if padded > p:
-            all_pods += [api.Pod(name=f"__pad-{i}", namespace="__pad__")
-                         for i in range(padded - p)]
+        all_pods = fb.pad_pods(pods, padded)
         with self.guard.watch("stream", inject=False):
             batch, hb, dc, nt = self._compile(all_pods, device=False)
         flags = self._pinned_flags(batch)

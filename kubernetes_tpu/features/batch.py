@@ -26,8 +26,8 @@ from kubernetes_tpu.features import compiler as fc
 from kubernetes_tpu.features.affinity import (AffinityTensors,
                                               ResidentAffinity,
                                               compile_affinity)
-from kubernetes_tpu.features.padcap import (pad_rows_pow2 as _pad_rows_pow2,
-                                            pow2 as _pow2)
+from kubernetes_tpu.features.padcap import pow2 as _pow2
+from kubernetes_tpu.features.plan import FeaturePlan, Fleet, Meta, keep
 from kubernetes_tpu.features.volumes import (VolSvcTensors, compile_volsvc,
                                              empty_volsvc)
 from kubernetes_tpu.utils.trace import stage
@@ -273,6 +273,107 @@ def _default_nz_row() -> np.ndarray:
     return _DEFAULT_NZ_ROW
 
 
+# The inert fill of a padded launch is ONE pod: ``pad_pods`` repeats this
+# object at the tail, so a launch neither constructs nor keys its ~200
+# pad rows, and ``compile_batch`` walks the live prefix only.  Nothing
+# reads a pad's name after the compile.
+PAD_POD = api.Pod(name="__pad__", namespace="__pad__")
+
+
+def pad_pods(pods: Sequence[api.Pod], to: int) -> list[api.Pod]:
+    """``pods`` followed by the inert fill up to ``to`` rows."""
+    return list(pods) + [PAD_POD] * (to - len(pods))
+
+
+def _fleet_tables(nt: fc.NodeTensors, space: fc.FeatureSpace) -> Fleet:
+    """The node-side tables every template row is compiled against."""
+    node_zone_id = _node_zone_ids(nt, space)
+    num_zones = int(node_zone_id.max()) + 1 if (node_zone_id >= 0).any() else 0
+    # haveZones iff some READY node carries zone info (the reference's
+    # countsByZone only sees the ready node list, selector_spreading.go:121).
+    any_zones = bool(((node_zone_id >= 0) & nt.schedulable).any())
+    # Parse the taint vocabulary once; every pod's tolerations are matched
+    # against it host-side, turning device-side toleration checks into a
+    # single untolerated-taints contraction.
+    vocab_taints = []
+    for tok in space.taints.tokens():
+        kv, _, effect = tok.rpartition(":")
+        key, _, value = kv.partition("=")
+        vocab_taints.append(api.Taint(key=key, value=value, effect=effect))
+    return Fleet(keep(node_zone_id), num_zones, any_zones, vocab_taints)
+
+
+def _node_avoids(nodes: Sequence[api.Node]) -> Sequence[set]:
+    """Node avoid-annotation entries: node -> set of (kind, uid)
+    controller signatures (GetAvoidPodsFromNodeAnnotations); ``()`` when
+    no node carries the annotation."""
+    import json as _json
+    out: list[set] = []
+    found = False
+    for node in nodes:
+        entries: set = set()
+        raw = node.annotations.get(api.PREFER_AVOID_PODS_ANNOTATION_KEY, "") \
+            if node.annotations else ""
+        if raw:
+            found = True
+            try:
+                d = _json.loads(raw)
+                for e in d.get("preferAvoidPods") or ():
+                    pc = (e.get("podSignature") or {}).get("podController") or {}
+                    entries.add((pc.get("kind", ""), pc.get("uid", "")))
+            except (ValueError, AttributeError):
+                pass
+        out.append(entries)
+    return out if found else ()
+
+
+def _compile_template(plan: FeaturePlan, key: tuple, pod: api.Pod,
+                      nt: fc.NodeTensors, space: fc.FeatureSpace,
+                      nodes: Optional[Sequence[api.Node]],
+                      fleet: Fleet) -> int:
+    """One template's pod-side rows into a new slot of the plan, and its
+    selector signature's node rows when the plan lacks them.  Everything
+    that parses (and so can raise) comes before the slot is taken."""
+    request = fc.pod_resource_row(pod)
+    nonzero = fc.pod_nonzero_row(pod)
+    tols = pod.tolerations()
+    pref_tols = [t for t in tols if not t.effect
+                 or t.effect == api.TAINT_EFFECT_PREFER_NO_SCHEDULE]
+    # Selector group (nodeSelector + node affinity).
+    aff = pod.affinity()
+    na = aff.node_affinity if aff else None
+    sig = (tuple(sorted(pod.node_selector.items())), na)
+    if sig not in plan.sel:
+        plan.sel[sig] = (required_node_mask(pod, nt, space, nodes),
+                         preferred_count_row(pod, nt, space, nodes))
+    slot = plan.add(key, Meta(
+        sel_sig=sig, lkey=(key[0], key[3]), namespace=pod.namespace,
+        labels=pod.labels, deleted=pod.deletion_timestamp is not None,
+        nz=(int(nonzero[0]), int(nonzero[1])),
+        res_row=request, nz_row=nonzero, affinity=aff))
+    tab = plan.tables
+    tab.request[slot] = request
+    tab.nonzero[slot] = nonzero
+    tab.zero_req[slot] = not (request[0] or request[1] or request[2])
+    tab.best_effort[slot] = pod.is_best_effort()
+    if pod.node_name:
+        tab.host_idx[slot] = nt.name_to_idx.get(pod.node_name, -2)
+    for port in pod.used_host_ports():
+        tab.ports[slot, space.ports.id(str(port))] = True
+    for v in pod.volumes:
+        for token, ro in fc.FeatureSpace.volume_tokens(v):
+            (tab.vol_ro if ro else tab.vol_rw)[
+                slot, space.volumes.id(token)] = True
+    tab.has_tols[slot] = len(tols) > 0
+    for ti, taint in enumerate(fleet.vocab_taints):
+        tab.tol_ns[slot, ti] = taint.tolerated_by(tols)
+        tab.tol_pref[slot, ti] = taint.tolerated_by(pref_tols)
+    for c in pod.containers:
+        if c.image:
+            tab.images[slot, space.images.id(c.image)] += 1
+    return slot
+
+
 def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
                   space: fc.FeatureSpace,
                   ep: Optional[fc.ExistingPodTensors] = None,
@@ -282,7 +383,8 @@ def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
                   affinity_pods: Sequence[tuple[api.Pod, int]] = (),
                   hard_pod_affinity_weight: int = 1,
                   volsvc: Optional[VolSvcTensors] = None,
-                  resident_affinity: Optional[ResidentAffinity] = None
+                  resident_affinity: Optional[ResidentAffinity] = None,
+                  plan: Optional[FeaturePlan] = None
                   ) -> PodBatch:
     """Compile a pending-pod batch against the current node tensors.
 
@@ -290,27 +392,54 @@ def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
     ``affinity_pods`` is not needed (compile_affinity).
 
     ``volsvc``: precompiled volume/service tables (compile_volsvc); a
-    neutral all-pass table is built when omitted."""
+    neutral all-pass table is built when omitted.
+
+    ``plan``: the tables kept between launches (features/plan.py), valid
+    for these node tensors (``FeaturePlan.begin``); what is there is
+    looked up, what is not is built and kept.  Without one the call
+    builds everything from nothing in a plan of its own: one path, and
+    the two give the same batch to the element."""
+    if plan is None:
+        plan = FeaturePlan()
     p = len(pods)
     n = nt.n
 
     # Group the batch into spec-identical templates; all per-pod rows are
     # compiled once per template and gathered back to [P, ...] at the end.
+    # The inert fill at the tail is one object: counted, not walked.
+    n_live = p
+    while n_live and pods[n_live - 1] is PAD_POD:
+        n_live -= 1
     tpl_of: dict[tuple, int] = {}
     reps: list[api.Pod] = []
-    tpl_idx = np.empty(p, np.int64)
-    for i, pod in enumerate(pods):
+    keys: list[tuple] = []
+    tpl_list: list[int] = []
+    for pod in (pods if n_live == p else pods[:n_live]):
         k = pod_template_key(pod)
         ti = tpl_of.get(k)
         if ti is None:
-            ti = len(reps)
-            tpl_of[k] = ti
+            ti = tpl_of[k] = len(reps)
             reps.append(pod)
-        tpl_idx[i] = ti
+            keys.append(k)
+        tpl_list.append(ti)
+    if n_live < p:
+        k = pod_template_key(PAD_POD)
+        ti = tpl_of.get(k)
+        if ti is None:
+            ti = tpl_of[k] = len(reps)
+            reps.append(PAD_POD)
+            keys.append(k)
+        tpl_idx = np.array(tpl_list + [ti] * (p - n_live), np.int64)
+    else:
+        tpl_idx = np.array(tpl_list, np.int64)
     t = len(reps)
 
-    # Intern everything first so capacities are final.
-    for pod in reps:
+    # Intern the new templates' tokens first so capacities are final (a
+    # kept template's are interned already).
+    known = plan.slots
+    for k, pod in zip(keys, reps):
+        if k in known:
+            continue
         for port in pod.used_host_ports():
             space.ports.id(str(port))
         for v in pod.volumes:
@@ -319,123 +448,68 @@ def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
         for c in pod.containers:
             if c.image:
                 space.images.id(c.image)
+    plan.check_vocab(space)
 
-    request = np.zeros((t, 4), np.int32)
-    nonzero = np.zeros((t, 2), np.int32)
-    zero_req = np.zeros(t, bool)
-    best_effort = np.zeros(t, bool)
-    host_idx = np.full(t, -1, np.int32)
-    ports = np.zeros((t, space.ports.capacity), bool)
-    vol_ro = np.zeros((t, space.volumes.capacity), bool)
-    vol_rw = np.zeros((t, space.volumes.capacity), bool)
-    tol_ns = np.zeros((t, space.taints.capacity), bool)
-    tol_pref = np.zeros((t, space.taints.capacity), bool)
-    has_tols = np.zeros(t, bool)
-    images = np.zeros((t, space.images.capacity), np.int32)
-    avoid_group = np.zeros(t, np.int32)
-    avoid_rows_map: dict = {(): 0}
-    avoid_rows: list[np.ndarray] = [np.zeros(n, bool)]
+    fleet = plan.fleet
+    if fleet is None:
+        fleet = plan.fleet = _fleet_tables(nt, space)
+    node_zone_id, num_zones = fleet.node_zone_id, fleet.num_zones
 
-    # Parse the taint vocabulary once; every pod's tolerations are matched
-    # against it host-side, turning device-side toleration checks into a
-    # single untolerated-taints contraction.
-    vocab_taints = []
-    for tok in space.taints.tokens():
-        kv, _, effect = tok.rpartition(":")
-        key, _, value = kv.partition("=")
-        vocab_taints.append(api.Taint(key=key, value=value, effect=effect))
+    slots = []
+    for k, pod in zip(keys, reps):
+        slot = plan.slots.get(k)
+        if slot is None:
+            slot = _compile_template(plan, k, pod, nt, space, nodes, fleet)
+        slots.append(slot)
+    meta = plan.meta
 
-    # Node avoid-annotation entries, parsed once: node -> set of
-    # (kind, uid) controller signatures (GetAvoidPodsFromNodeAnnotations).
-    node_avoids: list[set] = []
-    if controller_refs is not None and nodes is not None:
-        import json as _json
-        for node in nodes:
-            entries = set()
-            raw = node.annotations.get(api.PREFER_AVOID_PODS_ANNOTATION_KEY, "")
-            if raw:
-                try:
-                    d = _json.loads(raw)
-                    for e in d.get("preferAvoidPods") or ():
-                        pc = (e.get("podSignature") or {}).get("podController") or {}
-                        entries.add((pc.get("kind", ""), pc.get("uid", "")))
-                except (ValueError, AttributeError):
-                    pass
-            node_avoids.append(entries)
+    # Stamp the parsed/compiled per-pod caches from each pod's template
+    # so the assume path (cache.assume_pods -> aggregate updates) never
+    # re-parses quantities or affinity JSON for controller-stamped pods.
+    metas = [meta[s] for s in slots]
+    for pod, ti in zip(pods, tpl_list):
+        m = metas[ti]
+        pod._res_row = m.res_row
+        pod._nz_row = m.nz_row
+        pod._affinity = m.affinity
+        pod._affinity_parsed = True
 
-    sel_sig_to_group: dict = {}
-    sel_rows: list[np.ndarray] = []
-    pref_rows: list[np.ndarray] = []
-    sel_group = np.zeros(t, np.int32)
-    # Lister lookups memoized by (namespace, labels): controller-stamped
-    # pods share both, and the listers answer from labels alone.
-    _sel_memo: dict = {}
-    _ref_memo: dict = {}
-
-    node_zone_id = _node_zone_ids(nt, space)
-    num_zones = int(node_zone_id.max()) + 1 if (node_zone_id >= 0).any() else 0
-    # haveZones iff some READY node carries zone info (the reference's
-    # countsByZone only sees the ready node list, selector_spreading.go:121).
-    any_zones = bool(((node_zone_id >= 0) & nt.schedulable).any())
-
+    # -- the batch's groups: numbered in order of first appearance ---------
+    want_avoid = controller_refs is not None and nodes is not None
+    want_spread = spread_selectors is not None and ep is not None
+    avoid_group = [0] * t
+    avoid_of: dict = {(): 0}
+    avoid_keys: list = [()]
+    sel_group = [0] * t
+    sel_of: dict = {}
+    sel_keys: list = []
+    spread_group = [0] * t
     spread_sig_to_group: dict = {}
     spread_groups_meta: list[tuple[str, list]] = []  # (namespace, selectors)
-    spread_node_rows: list[np.ndarray] = []
-    spread_zone_rows: list[np.ndarray] = []
-    spread_has_zone: list[bool] = []
-    spread_group = np.zeros(t, np.int32)
-
-    for i, pod in enumerate(reps):
-        request[i] = fc.pod_resource_row(pod)
-        nonzero[i] = fc.pod_nonzero_row(pod)
-        zero_req[i] = not (request[i, 0] or request[i, 1] or request[i, 2])
-        best_effort[i] = pod.is_best_effort()
-        if pod.node_name:
-            host_idx[i] = nt.name_to_idx.get(pod.node_name, -2)
-        for port in pod.used_host_ports():
-            ports[i, space.ports.id(str(port))] = True
-        for v in pod.volumes:
-            for token, ro in fc.FeatureSpace.volume_tokens(v):
-                (vol_ro if ro else vol_rw)[i, space.volumes.id(token)] = True
-        tols = pod.tolerations()
-        has_tols[i] = len(tols) > 0
-        pref_tols = [t for t in tols if not t.effect
-                     or t.effect == api.TAINT_EFFECT_PREFER_NO_SCHEDULE]
-        for ti, taint in enumerate(vocab_taints):
-            tol_ns[i, ti] = taint.tolerated_by(tols)
-            tol_pref[i, ti] = taint.tolerated_by(pref_tols)
-        for c in pod.containers:
-            if c.image:
-                images[i, space.images.id(c.image)] += 1
-
+    # Lister lookups memoized by (namespace, labels): controller-stamped
+    # pods share both, and the listers answer from labels alone.  The
+    # listers are lists mutated in place, so they are asked every launch
+    # and what is kept is keyed by their answer.
+    _sel_memo: dict = {}
+    _ref_memo: dict = {}
+    for i, m in enumerate(metas):
         # NodePreferAvoidPods: mark nodes whose annotation lists one of the
         # pod's controllers (priorities.go:326-398), deduped by controller
         # signature so the [P, N] plane is a gather of few [N] rows.
-        if controller_refs is not None and nodes is not None:
-            lkey = (pod.namespace, tuple(sorted(pod.labels.items())))
-            refs = _ref_memo.get(lkey)
+        if want_avoid:
+            refs = _ref_memo.get(m.lkey)
             if refs is None:
-                refs = _ref_memo[lkey] = tuple(controller_refs(pod))
-            g = avoid_rows_map.get(refs)
+                refs = _ref_memo[m.lkey] = tuple(controller_refs(reps[i]))
+            g = avoid_of.get(refs)
             if g is None:
-                row = np.zeros(n, bool)
-                for ni, avoids in enumerate(node_avoids):
-                    if any(r in avoids for r in refs):
-                        row[ni] = True
-                g = avoid_rows_map[refs] = len(avoid_rows)
-                avoid_rows.append(row)
+                g = avoid_of[refs] = len(avoid_keys)
+                avoid_keys.append(refs)
             avoid_group[i] = g
 
-        # Selector group (nodeSelector + node affinity).
-        aff = pod.affinity()
-        na = aff.node_affinity if aff else None
-        sig = (tuple(sorted(pod.node_selector.items())), na)
-        g = sel_sig_to_group.get(sig)
+        g = sel_of.get(m.sel_sig)
         if g is None:
-            g = len(sel_rows)
-            sel_sig_to_group[sig] = g
-            sel_rows.append(required_node_mask(pod, nt, space, nodes))
-            pref_rows.append(preferred_count_row(pod, nt, space, nodes))
+            g = sel_of[m.sel_sig] = len(sel_keys)
+            sel_keys.append(m.sel_sig)
         sel_group[i] = g
 
         # Spread group (services/RCs/RSs selecting this pod), if listers given.
@@ -443,24 +517,15 @@ def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
         # a group: their distinct namespace would otherwise change S only
         # on drains that happen to need padding — a new compiled shape for
         # identical real content.
-        if spread_selectors is not None and ep is not None \
-                and pod.namespace != "__pad__":
-            lkey = (pod.namespace, tuple(sorted(pod.labels.items())))
-            sels = _sel_memo.get(lkey)
+        if want_spread and m.namespace != "__pad__":
+            sels = _sel_memo.get(m.lkey)
             if sels is None:
-                sels = _sel_memo[lkey] = spread_selectors(pod)
-            ssig = (pod.namespace, tuple(sorted(repr(s) for s in sels)))
+                sels = _sel_memo[m.lkey] = spread_selectors(reps[i])
+            ssig = (m.namespace, tuple(sorted(repr(s) for s in sels)))
             sg = spread_sig_to_group.get(ssig)
             if sg is None:
-                sg = len(spread_node_rows)
-                spread_sig_to_group[ssig] = sg
-                spread_groups_meta.append((pod.namespace, sels))
-                ncounts, zcounts = _spread_counts(
-                    pod.namespace, sels, ep, space, n, node_zone_id, num_zones,
-                    nt.schedulable)
-                spread_node_rows.append(ncounts)
-                spread_zone_rows.append(zcounts)
-                spread_has_zone.append(any_zones and len(sels) > 0)
+                sg = spread_sig_to_group[ssig] = len(spread_groups_meta)
+                spread_groups_meta.append((m.namespace, sels))
             spread_group[i] = sg
 
     # Content-sized group axes are padded to powers of two (padcap's
@@ -469,49 +534,58 @@ def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
     # otherwise be a fresh compiled shape).  Padding rows are never
     # referenced by any pod index: sel pad rows are all-ones ("no
     # constraint"), the rest zeros.
-    G = _pow2(len(sel_rows))
-    sel_required = np.ones((G, n), bool)
-    if sel_rows:
-        sel_required[:len(sel_rows)] = np.stack(sel_rows)
-    sel_pref = np.zeros((G, n), np.int32)
-    if pref_rows:
-        sel_pref[:len(pref_rows)] = np.stack(pref_rows)
-    S = _pow2(len(spread_node_rows))
-    Z = max(num_zones, 1)
-    sp_n = np.zeros((S, n), np.float32)
-    if spread_node_rows:
-        sp_n[:len(spread_node_rows)] = np.stack(spread_node_rows)
-    sp_z = np.zeros((S, Z), np.float32)
-    if spread_zone_rows:
-        sp_z[:len(spread_zone_rows)] = np.stack(spread_zone_rows)
-    sp_hz = np.zeros(S, bool)
-    if spread_has_zone:
-        sp_hz[:len(spread_has_zone)] = spread_has_zone
+    def sel_stack() -> tuple:
+        G = _pow2(len(sel_keys))
+        required = np.ones((G, n), bool)
+        pref = np.zeros((G, n), np.int32)
+        for g, sig in enumerate(sel_keys):
+            required[g], pref[g] = plan.sel[sig]
+        return keep(required), keep(pref)
 
-    # In-batch increments: once pod i is placed it becomes an "existing pod"
-    # for every later pod in the batch (the reference sees it via the assumed-
-    # pod cache, cache.go:107).
+    def avoid_stack() -> np.ndarray:
+        if want_avoid and fleet.node_avoids is None:
+            fleet.node_avoids = _node_avoids(nodes)
+        rows = np.zeros((_pow2(len(avoid_keys)), n), bool)
+        for g, refs in enumerate(avoid_keys):
+            if refs and fleet.node_avoids:
+                rows[g] = [any(r in avoids for r in refs)
+                           for avoids in fleet.node_avoids]
+        return keep(rows)
+
+    sel_required, sel_pref = plan.stack("sel", tuple(sel_keys), sel_stack)
+    avoid_rows = plan.stack("avoid", tuple(avoid_keys), avoid_stack)
+
+    S = _pow2(len(spread_groups_meta))
+    Z = max(num_zones, 1)
     spread_incr = np.zeros((t, S), bool)
-    if spread_groups_meta:
-        for i, pod in enumerate(reps):
-            if pod.deletion_timestamp is not None:
+    if any(sels for _ns, sels in spread_groups_meta):
+        sp_n = np.zeros((S, n), np.float32)
+        sp_z = np.zeros((S, Z), np.float32)
+        sp_hz = np.zeros(S, bool)
+        for s, (ns, sels) in enumerate(spread_groups_meta):
+            sp_n[s], sp_z[s] = _spread_counts(
+                ns, sels, ep, space, n, node_zone_id, num_zones,
+                nt.schedulable)
+            sp_hz[s] = fleet.any_zones and len(sels) > 0
+        # In-batch increments: once pod i is placed it becomes an "existing
+        # pod" for every later pod in the batch (the reference sees it via
+        # the assumed-pod cache, cache.go:107).
+        for i, m in enumerate(metas):
+            if m.deleted:
                 continue
             for s, (ns, sels) in enumerate(spread_groups_meta):
-                if ns == pod.namespace and any(
-                        _selector_matches_pod_labels(sel, pod.labels)
+                if ns == m.namespace and any(
+                        _selector_matches_pod_labels(sel, m.labels)
                         for sel in sels):
                     spread_incr[i, s] = True
-
-    # Stamp the parsed/compiled per-pod caches from each pod's template rep
-    # so the assume path (cache.assume_pods -> aggregate updates) never
-    # re-parses quantities or affinity JSON for controller-stamped pods.
-    for pod, ti in zip(pods, tpl_idx.tolist()):
-        rep = reps[ti]
-        if rep is not pod:
-            pod._res_row = rep._res_row
-            pod._nz_row = rep._nz_row
-            pod._affinity = rep._affinity
-            pod._affinity_parsed = True
+    else:
+        # No group selects anything: every count is zero whatever the
+        # resident pods are.
+        sp_n, sp_z, sp_hz = plan.stack(
+            "nospread", (S,),
+            lambda: (keep(np.zeros((S, n), np.float32)),
+                     keep(np.zeros((S, Z), np.float32)),
+                     keep(np.zeros(S, bool))))
 
     with stage("compile.affinity"):
         aff = compile_affinity(pods, affinity_pods, ep, nodes, n, space,
@@ -520,16 +594,15 @@ def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
                                resident=resident_affinity)
     if volsvc is None:
         if nodes is not None:
-            volsvc = compile_volsvc(pods, nodes, nt.schedulable)
+            volsvc = compile_volsvc(pods, nodes, nt.schedulable, plan=plan)
         else:
             volsvc = empty_volsvc(p, n)
 
     # Nonzero-request templates for the scan's template-factored
     # score planes (engine/solver.py _solve_scan): the distinct nonzero
-    # rows, pow2-row-padded (padcap's "b_nztmpl" axis keeps the bucket
-    # monotonic across batches).  Above the cap the table compiles away
-    # (shape 0) and the scan keeps its in-step score path.
-    from kubernetes_tpu.engine.solver import DYN_TEMPLATE_CAP
+    # rows in row order, pow2-row-padded (padcap's "b_nztmpl" axis keeps
+    # the bucket monotonic across batches).  Above the cap the table
+    # compiles away (shape 0) and the scan keeps its in-step score path.
     # The default nonzero row (a request-less pod's non_zero_request) is
     # ALWAYS in the table: chunk/gang pad pods carry exactly it, and a
     # live padded batch must not grow the template table past what the
@@ -537,34 +610,46 @@ def compile_batch(pods: Sequence[api.Pod], nt: fc.NodeTensors,
     # minted an unwarmed scan shape on the wire clock.  Derived through
     # the SAME row encoder the pad pods go through (not re-derived
     # constants), so the two can never diverge.
-    nz_uniq, nz_inv = np.unique(
-        np.concatenate([nonzero, _default_nz_row()[None]]), axis=0,
-        return_inverse=True)
-    if 0 < len(nz_uniq) <= DYN_TEMPLATE_CAP:
+    default_nz = _default_nz_row()
+    nz_rows = {m.nz for m in metas}
+    nz_rows.add((int(default_nz[0]), int(default_nz[1])))
+    nz_key = tuple(sorted(nz_rows))
+
+    def nz_stack() -> tuple:
+        from kubernetes_tpu.engine.solver import DYN_TEMPLATE_CAP
+        if len(nz_key) > DYN_TEMPLATE_CAP:
+            return keep(np.zeros((0, 2), np.int32)), None
         # Row floor of 8 bounds tiny-batch wobble to one shape.
-        rows = max(_pow2(len(nz_uniq)), 8)
-        nz_templates = np.zeros((rows, 2), np.int32)
-        nz_templates[:len(nz_uniq)] = nz_uniq
-        nz_tmpl_idx = nz_inv[:-1].astype(np.int32)[tpl_idx]
+        table = np.zeros((max(_pow2(len(nz_key)), 8), 2), np.int32)
+        table[:len(nz_key)] = nz_key
+        return keep(table), {nz: i for i, nz in enumerate(nz_key)}
+
+    nz_templates, nz_of = plan.stack("nz", nz_key, nz_stack)
+    if nz_of is not None:
+        nz_tmpl_idx = np.array([nz_of[m.nz] for m in metas],
+                               np.int32)[tpl_idx]
     else:
-        nz_templates = np.zeros((0, 2), np.int32)
         nz_tmpl_idx = np.zeros(p, np.int32)
 
+    tab = plan.tables
+    slot_idx = np.array(slots, np.int64)[tpl_idx]
     return PodBatch(
-        pods=list(pods), request=request[tpl_idx],
-        zero_request=zero_req[tpl_idx], nonzero=nonzero[tpl_idx],
-        best_effort=best_effort[tpl_idx], host_idx=host_idx[tpl_idx],
-        ports=ports[tpl_idx],
-        vol_ro=vol_ro[tpl_idx], vol_rw=vol_rw[tpl_idx],
-        tol_nosched=tol_ns[tpl_idx], tol_prefer=tol_pref[tpl_idx],
-        has_tolerations=has_tols[tpl_idx],
-        images=images[tpl_idx], sel_group=sel_group[tpl_idx],
+        pods=list(pods), request=tab.request[slot_idx],
+        zero_request=tab.zero_req[slot_idx], nonzero=tab.nonzero[slot_idx],
+        best_effort=tab.best_effort[slot_idx],
+        host_idx=tab.host_idx[slot_idx], ports=tab.ports[slot_idx],
+        vol_ro=tab.vol_ro[slot_idx], vol_rw=tab.vol_rw[slot_idx],
+        tol_nosched=tab.tol_ns[slot_idx], tol_prefer=tab.tol_pref[slot_idx],
+        has_tolerations=tab.has_tols[slot_idx],
+        images=tab.images[slot_idx],
+        sel_group=np.array(sel_group, np.int32)[tpl_idx],
         sel_required=sel_required, sel_pref_counts=sel_pref,
-        spread_group=spread_group[tpl_idx],
+        spread_group=np.array(spread_group, np.int32)[tpl_idx],
         spread_node_counts=sp_n, spread_zone_counts=sp_z,
         spread_has_zones=sp_hz, spread_incr=spread_incr[tpl_idx],
-        node_zone_id=node_zone_id, avoid_group=avoid_group[tpl_idx],
-        avoid_rows=_pad_rows_pow2(np.stack(avoid_rows)),
+        node_zone_id=node_zone_id,
+        avoid_group=np.array(avoid_group, np.int32)[tpl_idx],
+        avoid_rows=avoid_rows,
         nz_tmpl_idx=nz_tmpl_idx, nz_templates=nz_templates,
         aff=aff, volsvc=volsvc)
 

@@ -40,6 +40,8 @@ INFEASIBLE_EXTRA = 1 << 20
 # indexes them.
 from kubernetes_tpu.features.padcap import (pow2 as _pow2,  # noqa: E402
                                             stack_pad as _stack_pad)
+from kubernetes_tpu.features.plan import (VOLSVC_CAP,  # noqa: E402
+                                          FeaturePlan, keep)
 
 
 class VolumeListers(Protocol):
@@ -386,16 +388,36 @@ def compile_volsvc(pods: Sequence[api.Pod],
                    service_anti_affinity_labels: tuple[str, ...] = (),
                    node_label_args: Optional[tuple[tuple[str, ...], bool]] = None,
                    node_label_prio_args: Sequence[tuple[str, bool]] = (),
-                   service_peers=None, first_peer=None) -> VolSvcTensors:
+                   service_peers=None, first_peer=None,
+                   plan: Optional[FeaturePlan] = None) -> VolSvcTensors:
     """Build all volume/service tables for a batch.
 
     ``service_peers(ns, selector)`` -> list of node names hosting matching
     assigned pods; ``first_peer(ns, selector)`` -> first such node name or
     None.  Both come from the scheduler cache.
+
+    ``plan``: the tables kept between launches for these ``nodes``
+    (features/plan.py).  Only the NEUTRAL form is kept — no volume in the
+    batch or on the fleet, no service label in the policy: then every
+    table is a function of the nodes, the pod axis and the node-label
+    arguments, and the kept one is handed out.  Any other batch runs the
+    code below and tells the plan so.
     """
     n = len(nodes)
     p = len(pods)
     any_vols = any(pod.volumes for pod in pods)
+    kept_key = None
+    if plan is not None:
+        if any_vols or volume_pods or service_affinity_labels \
+                or service_anti_affinity_labels:
+            plan.miss("not_neutral")
+        else:
+            kept_key = (p, node_label_args and (tuple(node_label_args[0]),
+                                                node_label_args[1]),
+                        tuple(node_label_prio_args))
+            kept = plan.volsvc.get(kept_key)
+            if kept is not None:
+                return kept
     if any_vols or volume_pods:
         pe, ne, xe, nxe, nee = _compile_pd_family(
             pods, volume_pods, n, "ebs", listers)
@@ -449,7 +471,7 @@ def compile_volsvc(pods: Sequence[api.Pod],
         has = np.array([lb in nd.labels for nd in nodes], bool)
         nl_prio_rows[li] = has if pres else ~has
 
-    return VolSvcTensors(
+    out = VolSvcTensors(
         pd_pod_ebs=pe, pd_node_ebs=ne, pd_extra_ebs=xe,
         pd_node_extra_ebs=nxe, pd_node_err_ebs=nee,
         pd_pod_gce=pg, pd_node_gce=ng, pd_extra_gce=xg,
@@ -459,3 +481,10 @@ def compile_volsvc(pods: Sequence[api.Pod],
         saa_group=saa_group, saa_src=saa_src, saa_dom=saa_dom,
         saa_labeled=saa_labeled, saa_cnt=saa_cnt, saa_num=saa_num,
         nl_pred_row=nl_pred_row, nl_prio_rows=nl_prio_rows)
+    if kept_key is not None:
+        if len(plan.volsvc) >= VOLSVC_CAP:
+            plan.volsvc.clear()
+        plan.volsvc[kept_key] = out
+        for a in out:
+            keep(a)
+    return out

@@ -340,6 +340,17 @@ def _status_mux(factory: ConfigFactory, configz: dict, port: int,
                     "cachedNodes": len(cache.nodes()),
                     "cacheStats": cache.stats,
                     "generation": cache.generation,
+                    # The three counters of the cache (ARCHITECTURE.md)
+                    # and what the feature build kept under the node
+                    # epoch: launches that reused it, misses by cause.
+                    "tensorEpoch": cache.tensor_epoch,
+                    "nodeEpoch": cache.node_epoch,
+                    # (null behind a shared solver service, whose
+                    # engine holds the plan)
+                    "featurePlan": (
+                        factory.algorithm.plan_report()
+                        if hasattr(factory.algorithm, "plan_report")
+                        else None),
                 }).encode(), "application/json")
             elif path == "/tenancy":
                 if getattr(factory, "tenancy", None) is None:

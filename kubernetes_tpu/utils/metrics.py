@@ -612,6 +612,18 @@ DEVICE_TRANSFER_ARRAYS = register(Counter(
     "lock on the launch thread; arrays{cause=batch} and "
     "arrays{cause=scatter} per launch are 1 with the packed wire forms",
     labelnames=("cause",)))
+FEATURE_PLAN = register(Counter(
+    "scheduler_feature_plan_total",
+    "Launches by whether the feature build reused the node-side and "
+    "template-side tables it keeps between launches (features/plan.py): "
+    "result=hit, or result=miss with the first cause that applied — "
+    "node_epoch (a node added, removed or changed, or the first launch), "
+    "vocab (a port / volume / image vocabulary grew past its capacity), "
+    "template_new (a pod template not seen since), not_neutral (volumes "
+    "in the batch or on the fleet, or service labels in the policy: the "
+    "volume / service tables are built per launch).  A steady window "
+    "reads hits only",
+    labelnames=("result", "cause")))
 DEVICE_HBM_LIVE_BYTES = register(Gauge(
     "scheduler_device_hbm_live_bytes",
     "Device memory held by live arrays (device.memory_stats when the "
