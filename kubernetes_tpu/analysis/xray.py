@@ -80,7 +80,7 @@ DEFAULT_MANIFEST = os.path.join(REPO, "tools", "shape_manifest.json")
 #: never move the committed surface.
 CANON = {
     "schema": 1,
-    "nodes": 8,                  # canonical cluster rows
+    "nodes": 8,                  # canonical fleet (canonical_rows() rows)
     "floor": 256,                # Scheduler.STREAM_MIN_BUCKET default
     "pad_limit": 4096,           # Scheduler._PAD_LIMIT
     "stream_threshold_off": True,  # KT_STREAM_CHUNK default 0
@@ -410,9 +410,17 @@ def canonical_ladder() -> list[int]:
     return bucket_ladder(CANON["floor"], 1 << 62, CANON["pad_limit"], 0)
 
 
+def canonical_rows() -> int:
+    """Rows of the canonical cluster: the node axis' capacity for
+    ``CANON['nodes']`` nodes (what the cache allocates and the mirror
+    uploads)."""
+    from kubernetes_tpu.features.compiler import capacity
+    return capacity(CANON["nodes"])
+
+
 def canonical_scatter_rows() -> list[int]:
     from kubernetes_tpu.engine.solver import ResidentCluster
-    return ResidentCluster.scatter_buckets(CANON["nodes"])
+    return ResidentCluster.scatter_buckets(canonical_rows())
 
 
 def canonical_plan() -> list[str]:
@@ -438,7 +446,7 @@ class Context:
     bake in."""
     solver: Any
     batch1: Any          # DeviceBatch avals at P=1
-    cluster: Any         # DeviceCluster avals at N=CANON nodes
+    cluster: Any         # DeviceCluster avals at canonical_rows() rows
     flags: Any
     scratch: dict = field(default_factory=dict)
 
@@ -530,7 +538,7 @@ def program_builders(ctx: Context) -> dict[str, tuple[str, Callable,
     from kubernetes_tpu.engine.workloads import preemption, topology
     from kubernetes_tpu.ops import combine
     solver, flags = ctx.solver, ctx.flags
-    n = CANON["nodes"]
+    n = canonical_rows()
     floor = CANON["floor"]
     cnt = _sds((), np.uint32)
     c_abs = ctx.cluster

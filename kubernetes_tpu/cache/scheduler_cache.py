@@ -12,6 +12,18 @@ aggregates live as the dense arrays the device kernels consume
 (``NodeAggregates``/``ExistingPodTensors``) and are updated incrementally —
 the tensor analogue of NodeInfo.addPod/removePod plus the generation-counter
 snapshotting of UpdateNodeNameToInfoMap (cache.go:77-91).
+
+The node axis of those arrays has a CAPACITY (``features.compiler.capacity``:
+the node count rounded up to whole 128-row tiles with a row to spare), and a
+row is live (a node's) or free (it reads as a node no pod fits).  A node
+that joins takes the lowest free row, a node that leaves frees its row, an
+update rewrites its row: each is ONE dirty row for the device mirror's
+scatter and a move of ``node_epoch`` — no rebuild, no ``tensor_epoch`` bump,
+no new XLA shape.  Only a join that finds no free row grows the arrays, by
+whole tiles (``tensor_epoch`` moves: one full upload, one new shape); the
+rebuild from the tracked objects is left to the first snapshot, a relist,
+the verifier's self-heal and a new topology key.  ARCHITECTURE.md
+("Performance model") has the table of what each event moves.
 """
 
 from __future__ import annotations
@@ -83,6 +95,48 @@ def _locked(fn):
     return wrapper
 
 
+class _node_event:
+    """One node event of the cache, entered with its lock held.
+    ``took(path)`` says which road the event goes: ``row`` (one row
+    written in place), ``grow`` (no row was free: the axis grows by
+    tiles), ``rebuild`` (the tensors are unbuilt or already marked for a
+    rebuild, which the next snapshot pays and
+    ``scheduler_cache_rebuild_seconds_total`` counts); from there to the
+    end the event is the host event ``kt.node_event`` of a live profiler
+    session (attributes ``event``, ``path``; the wait for the lock ahead
+    of it is ``kt.cache_lock_wait``).  On exit it counts
+    ``scheduler_cache_node_events_total{event, path}`` and adds the time
+    the event HELD the lock to
+    ``scheduler_cache_node_event_seconds_total{event}`` (what it waited
+    for the lock is the lock's own account:
+    ``scheduler_cache_lock_wait_seconds_total``)."""
+
+    __slots__ = ("event", "path", "_t0", "_span")
+
+    def __init__(self, event: str):
+        self.event = event
+        self.path = self._span = None
+
+    def __enter__(self) -> "_node_event":
+        self._t0 = time.perf_counter()
+        return self
+
+    def took(self, path: str) -> None:
+        self.path = path
+        self._span = trace.annotation("node_event", event=self.event,
+                                      path=path)
+        self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        if self._span is None:      # nothing to do (an unknown node left)
+            return
+        self._span.__exit__(*exc)
+        metrics.CACHE_NODE_EVENTS.labels(event=self.event,
+                                         path=self.path).inc()
+        metrics.CACHE_NODE_EVENT_SECONDS.labels(event=self.event).inc(
+            time.perf_counter() - self._t0)
+
+
 DEFAULT_ASSUMED_POD_TTL = 30.0  # factory.go:102
 CLEANUP_PERIOD = 1.0            # cache.go:31
 
@@ -113,7 +167,6 @@ class SchedulerCache:
         self.lock = _CacheLock(locktrace.make_rlock(
             "cache.SchedulerCache", hold_ms=0))
         self._nodes: dict[str, api.Node] = {}
-        self._node_order: list[str] = []
         self._pod_states: dict[str, _PodState] = {}
         self._node_pods: dict[str, dict[str, api.Pod]] = {}
         # PodsWithAffinity analogue (node_info.go podsWithAffinity): attached
@@ -130,16 +183,20 @@ class SchedulerCache:
         # launches: updated per attach / detach beside the aggregates,
         # rebuilt from the attached pods when the node rows or their
         # labels change (affinity_tables()).
-        self._aff = fa.ResidentAffinity(self._attached_pods)
+        self._aff = fa.ResidentAffinity(self._attached_affinity_pods)
         self._dirty_nodes = True
         self.generation = 0
-        # Device-residency protocol: ``tensor_epoch`` bumps whenever row
-        # identity changes (full rebuild, node append — the [N, ...]
-        # shapes or the row->node mapping moved), telling the device
-        # mirror (engine/solver.ResidentCluster) to re-upload everything.
+        # Device-residency protocol: the node axis has a CAPACITY
+        # (``fc.capacity``: whole 128-row tiles with a row to spare), and
+        # a row is live or free.  ``tensor_epoch`` bumps whenever every
+        # row moved at once (the rebuild from the tracked objects; the
+        # growth by whole tiles of a join that found no free row — the
+        # [N, ...] shapes changed), telling the device mirror
+        # (engine/solver.ResidentCluster) to re-upload everything.
         # ``_dirty_rows`` collects the row indices whose CONTENT changed
-        # in place (node updates, pod attach/detach aggregates) since the
-        # mirror last synced; the engine consumes it under self.lock via
+        # in place (a node joined into a free row, left its row free, or
+        # was updated; pod attach/detach aggregates) since the mirror
+        # last synced; the engine consumes it under self.lock via
         # take_dirty_rows().  One device mirror per cache, by design —
         # the same 1:1 engine/cache pairing _compile already assumes.
         self.tensor_epoch = 0
@@ -150,81 +207,112 @@ class SchedulerCache:
         # marks the nodes dirty ends there, ``ensure_topo_key`` too) —
         # and never on a pod event.  The feature build keys what it keeps
         # between launches on it (features/plan.py).  ``_node_list`` is
-        # the ``api.Node`` objects in row order, made once per epoch and
-        # handed out by ``snapshot()`` / ``nodes()``: read-only to callers.
+        # the ``api.Node`` of every ROW (``fc.FREE_NODE`` at a free one),
+        # ``_live_list`` the live ones alone in row order; each made once
+        # per epoch and handed out by ``snapshot()`` / ``nodes()``:
+        # read-only to callers.
         self.node_epoch = 0
         self._node_list: Optional[list[api.Node]] = None
-        # Churn observability: full rebuilds vs incremental row updates.
+        self._live_list: Optional[list[api.Node]] = None
+        # Churn observability: full rebuilds vs incremental row updates
+        # (exported as scheduler_cache_rebuilds_total / _rebuild_seconds_
+        # total; the node events by path in scheduler_cache_node_events_
+        # total).
         self.stats = {"rebuilds": 0, "rebuild_s": 0.0,
                       "incremental_node_updates": 0}
 
     # ---- node lifecycle (cache.go:263-307) ----------------------------
 
-    @_locked
     def add_node(self, node: api.Node) -> None:
-        old = self._nodes.get(node.name)
-        known = old is not None
-        self._nodes[node.name] = node
-        if node.name not in self._node_pods:
-            self._node_pods[node.name] = {}
-        if self._dirty_nodes or self._nt is None:
-            self._mark_nodes_dirty()
-        elif known:
-            # Duplicate ADDED (relist Replace): treat as update in place.
-            idx = self._nt.name_to_idx[node.name]
-            fc.update_node_row(self._nt, idx, node, self.space)
-            if old.labels != node.labels:
-                self._aff.invalidate()
-            self._node_changed(old, node, idx)
-            self._dirty_rows.add(idx)
-            self.stats["incremental_node_updates"] += 1
-            self.generation += 1
-        else:
-            # Incremental append: one new row across the node tensors +
-            # zero aggregates; no 5k-row recompile per joining node.
-            # Capacity growth: the device mirror re-uploads (epoch bump).
-            fc.append_node_row(self._nt, node, self.space)
-            fc.append_aggregate_row(self._agg)
-            self._aff.invalidate()
-            self._node_order.append(node.name)
-            self._node_changed()
-            self.tensor_epoch += 1
-            self.stats["incremental_node_updates"] += 1
-            self.generation += 1
+        self._put_node(node, "added")
 
-    @_locked
     def update_node(self, node: api.Node) -> None:
-        old = self._nodes.get(node.name)
-        self._nodes[node.name] = node
-        if node.name not in self._node_pods:
-            self._node_pods[node.name] = {}
-        idx = None if (self._dirty_nodes or self._nt is None) else \
-            self._nt.name_to_idx.get(node.name)
-        if idx is None:
-            self._mark_nodes_dirty()
-        else:
-            # Incremental UPDATE (Ready flip, capacity change): rewrite the
-            # one row — the node controller's churn must not cost a full
-            # rebuild (nodecontroller.go:70-160 at 5k nodes).  In-place
-            # writes are safe against concurrent solves because every
-            # reader (GenericScheduler._compile) holds self.lock across
-            # snapshot + feature compile + the device transfer; after the
-            # transfer the solver reads device copies, not these arrays.
-            fc.update_node_row(self._nt, idx, node, self.space)
-            if old is None or old.labels != node.labels:
-                self._aff.invalidate()
-            self._node_changed(old, node, idx)
+        self._put_node(node, "updated")
+
+    def _put_node(self, node: api.Node, event: str) -> None:
+        """ADDED and MODIFIED alike: a node that has a row is rewritten
+        in place (a duplicate ADDED is a relist's Replace), one the
+        cache first hears of joins (whichever event brought it)."""
+        with self.lock, _node_event(event) as ev:
+            old = self._nodes.get(node.name)
+            self._nodes[node.name] = node
+            waiting = self._node_pods.setdefault(node.name, {})
+            if self._dirty_nodes or self._nt is None:
+                ev.took("rebuild")
+                self._mark_nodes_dirty()
+            elif node.name in self._nt.name_to_idx:
+                ev.took("row")
+                self._update_row(old, node)
+            else:
+                self._join_row(node, waiting, ev)
+
+    def _join_row(self, node: api.Node, waiting: dict[str, api.Pod],
+                  ev: _node_event) -> None:
+        """A free row becomes the node's: one row written, one dirty row
+        for the scatter — no copy of the tensors, no new shape.  Only
+        when no row is free does the axis grow by whole tiles: every
+        [N, ...] array copied once, one full upload, one new XLA shape
+        (``tensor_epoch``).  ``waiting``: pods bound to the node ahead of
+        the node itself, or left on it when it was removed."""
+        ev.took("row" if self._nt.free else "grow")
+        if not self._nt.free:
+            fc.grow_node_rows(self._nt, self._agg,
+                              fc.capacity(len(self._nodes)))
+            self.tensor_epoch += 1
+        idx = fc.take_node_row(self._nt, node, self.space)
+        if waiting:
+            self._attach_rows(list(waiting.values()), [idx] * len(waiting))
+        self._aff.invalidate()
+        self._node_changed()
+        self._dirty_rows.add(idx)
+        self.stats["incremental_node_updates"] += 1
+        self.generation += 1
+
+    def _update_row(self, old: api.Node, node: api.Node) -> None:
+        """Incremental UPDATE (Ready flip, capacity change): rewrite the
+        one row — the node controller's churn must not cost a full
+        rebuild (nodecontroller.go:70-160 at 5k nodes).  In-place writes
+        are safe against concurrent solves because every reader
+        (GenericScheduler._compile) holds self.lock across snapshot +
+        feature compile + the device transfer; after the transfer the
+        solver reads device copies, not these arrays."""
+        idx = self._nt.name_to_idx[node.name]
+        fc.update_node_row(self._nt, idx, node, self.space)
+        if old.labels != node.labels:
+            self._aff.invalidate()
+        self._node_changed(old, node, idx)
+        self._dirty_rows.add(idx)
+        self.stats["incremental_node_updates"] += 1
+        self.generation += 1
+
+    def remove_node(self, name: str) -> None:
+        with self.lock, _node_event("removed") as ev:
+            if self._nodes.pop(name, None) is None:
+                return
+            if self._dirty_nodes or self._nt is None:
+                ev.took("rebuild")
+                self._mark_nodes_dirty()
+                return
+            # The row is freed in place: it reads as ``fc.FREE_NODE``
+            # (no pod fits, no score counts) until a join takes it.  Pods
+            # on the node stay tracked (the reference keeps them until
+            # their own delete events arrive) but leave the tensors with
+            # the row; a node that comes back under the name finds them
+            # (``add_node``).
+            ev.took("row")
+            idx = fc.free_node_row(self._nt, name, self.space)
+            left = self._node_pods.get(name)
+            if left:
+                for pod in left.values():
+                    self._ep = fc.existing_pods_remove(self._ep, pod.key)
+                fc.clear_aggregate_row(self._agg, idx)
+            else:
+                self._node_pods.pop(name, None)
+            self._aff.invalidate()
+            self._node_changed()
             self._dirty_rows.add(idx)
             self.stats["incremental_node_updates"] += 1
             self.generation += 1
-
-    @_locked
-    def remove_node(self, name: str) -> None:
-        self._nodes.pop(name, None)
-        # Pods on the node stay tracked (the reference keeps them until their
-        # own delete events arrive); their rows rebuild against the new order.
-        # Removal reshapes every [N, ...] tensor: full rebuild (bulk path).
-        self._mark_nodes_dirty()
 
     def _mark_nodes_dirty(self) -> None:
         self._dirty_nodes = True
@@ -234,17 +322,23 @@ class SchedulerCache:
                       node: Optional[api.Node] = None,
                       idx: int = -1) -> None:
         """Move ``node_epoch`` for the node tensors rebuilt, or for row
-        ``idx`` appended or written in place — unless the update changed
-        nothing: ``api.Node`` holds only what the features read (no
-        heartbeat time, no resource version), so an equal object is a
-        status heartbeat, and takes its twin's place in the kept list.
+        ``idx`` taken, freed or written in place — unless the update
+        changed nothing: ``api.Node`` holds only what the features read
+        (no heartbeat time, no resource version), so an equal object is a
+        status heartbeat, and takes its twin's place in the kept lists.
         The SAME object handed in again was mutated by its owner and
         cannot be told from its old self: that moves the epoch."""
         if old is None or old is node or old != node:
             self.node_epoch += 1
-            self._node_list = None
-        elif self._node_list is not None:
-            self._node_list[idx] = node
+            self._node_list = self._live_list = None
+            metrics.CACHE_NODE_ROWS.labels(state="live").set(
+                len(self._nt.name_to_idx))
+            metrics.CACHE_NODE_ROWS.labels(state="free").set(
+                len(self._nt.free))
+        else:
+            if self._node_list is not None:
+                self._node_list[idx] = node
+            self._live_list = None
 
     # ---- pod state machine --------------------------------------------
 
@@ -298,9 +392,7 @@ class SchedulerCache:
             if pod.volumes:
                 self._volume_pods[key] = pod
             idx = self._nt.name_to_idx.get(node_name)
-            if idx is None:
-                self._mark_nodes_dirty()
-            else:
+            if idx is not None:     # else: it waits for its node's join
                 pods.append(pod)
                 idxs.append(idx)
         if not self._dirty_nodes and pods:
@@ -446,19 +538,38 @@ class SchedulerCache:
 
     @_locked
     def node_count(self) -> int:
-        """Nodes tracked, without building the node tensors."""
+        """Nodes tracked (the live rows), without building the node
+        tensors."""
         return len(self._nodes)
 
     @_locked
+    def node_rows(self) -> tuple[int, int]:
+        """(capacity, free rows) of the node axis as it stands — (0, 0)
+        while the tensors are unbuilt; builds nothing."""
+        nt = self._nt
+        return (0, 0) if nt is None else (nt.n, len(nt.free))
+
+    @_locked
     def nodes(self) -> list[api.Node]:
-        """The nodes in row order: one list per ``node_epoch``, shared by
-        every caller until a node event — not to be mutated."""
+        """The live nodes in row order: one list per ``node_epoch``,
+        shared by every caller until a node event — not to be mutated."""
+        live = self._live_list
+        if live is None:
+            live = self._live_list = [nd for nd in self._row_nodes()
+                                      if nd is not fc.FREE_NODE]
+        return live
+
+    def _row_nodes(self) -> list[api.Node]:
+        """The ``api.Node`` of every row, ``fc.FREE_NODE`` at a free one
+        (what a feature builder walks beside the node tensors)."""
         self._ensure_tensors()
-        nodes = self._node_list
-        if nodes is None:
-            nodes = self._node_list = [self._nodes[n]
-                                       for n in self._node_order]
-        return nodes
+        rows = self._node_list
+        if rows is None:
+            nodes = self._nodes
+            rows = self._node_list = [
+                fc.FREE_NODE if name is None else nodes[name]
+                for name in self._nt.names]
+        return rows
 
     @_locked
     def node_pods(self, node_name: str) -> list[api.Pod]:
@@ -524,7 +635,30 @@ class SchedulerCache:
                 for pod in podmap.values():
                     yield pod, idx
 
+    def _attached_affinity_pods(self):
+        """(pod, node row) of the attached pods that declare a term: all
+        a build of the kept planes from nothing has to walk (it starts
+        with no match signature, so a pod without a term moves nothing;
+        a batch registers its match rows off the existing-pod tensors).
+        A node event invalidates the planes, and the walk of EVERY
+        attached pod was 50 ms of the next launch on a fleet of 30,000
+        pods of which none has a term (my chip run, PR 36)."""
+        row_of = self._nt.name_to_idx
+        for pod in self._affinity_pods.values():
+            idx = row_of.get(pod.node_name)
+            if idx is not None:
+                yield pod, idx
+
     # ---- tensor maintenance -------------------------------------------
+
+    def _attach_rows(self, pods: list[api.Pod], idxs: list[int]) -> None:
+        """Pods onto their rows through the BULK paths: the per-pod loop
+        is O(pods x numpy-call overhead) — tens of seconds at 30k
+        attached pods."""
+        self._agg = fc.add_pods_to_aggregates_bulk(
+            self._agg, idxs, pods, self.space)
+        self._ep = fc.existing_pods_add_bulk(
+            self._ep, pods, idxs, self.space)
 
     def _attach(self, pod: api.Pod, node_name: str) -> None:
         if not node_name:
@@ -535,15 +669,16 @@ class SchedulerCache:
         if pod.volumes:
             self._volume_pods[pod.key] = pod
         if not self._dirty_nodes and self._nt is not None:
+            # (bound to a node not, or no longer, here: it is tracked, and
+            # attached when the node joins — ``_join_row``)
             idx = self._nt.name_to_idx.get(node_name)
-            if idx is None:
-                # Pod bound to a node we haven't seen; full rebuild on demand.
-                self._mark_nodes_dirty()
-                return
-            self._agg = fc.add_pod_to_aggregates(self._agg, idx, pod, self.space)
-            self._ep = fc.existing_pods_add(self._ep, pod, idx, self.space)
-            self._aff.add_pod(pod, idx)
-            self._dirty_rows.add(idx)
+            if idx is not None:
+                self._agg = fc.add_pod_to_aggregates(self._agg, idx, pod,
+                                                     self.space)
+                self._ep = fc.existing_pods_add(self._ep, pod, idx,
+                                                self.space)
+                self._aff.add_pod(pod, idx)
+                self._dirty_rows.add(idx)
         self.generation += 1
 
     def _detach(self, pod: api.Pod) -> None:
@@ -568,31 +703,30 @@ class SchedulerCache:
         if not self._dirty_nodes and self._nt is not None:
             return
         t0 = time.perf_counter()
-        self._node_order = list(self._nodes.keys())
-        self._nt = fc.compile_nodes(
-            [self._nodes[n] for n in self._node_order], self.space)
-        self._agg = fc.empty_aggregates(len(self._node_order), self.space)
-        self._ep = fc.empty_existing_pods(self.space)
-        # Re-attach every tracked pod through the BULK paths: the per-pod
-        # loop is O(pods x numpy-call overhead) — tens of seconds at 30k
-        # attached pods, per node event, before this.
-        attached = list(self._attached_pods())
-        if attached:
-            pods = [pod for pod, _ in attached]
-            idxs = [idx for _, idx in attached]
-            self._agg = fc.add_pods_to_aggregates_bulk(
-                self._agg, idxs, pods, self.space)
-            self._ep = fc.existing_pods_add_bulk(
-                self._ep, pods, idxs, self.space)
-        self._aff.invalidate()
-        self._dirty_nodes = False
-        self._node_changed()
-        # Relist/rebuild: row identity moved — the device mirror must
-        # re-upload; any pending per-row deltas are subsumed.
-        self.tensor_epoch += 1
-        self._dirty_rows.clear()
+        with trace.annotation("cache_rebuild", nodes=len(self._nodes)):
+            # The tracked nodes take the first rows, in the order they
+            # were first heard of; the rest of the capacity is free.
+            nodes = list(self._nodes.values())
+            rows = fc.capacity(len(nodes))
+            self._nt = fc.compile_nodes(nodes, self.space, rows=rows)
+            self._agg = fc.empty_aggregates(rows, self.space)
+            self._ep = fc.empty_existing_pods(self.space)
+            attached = list(self._attached_pods())
+            if attached:
+                self._attach_rows([pod for pod, _ in attached],
+                                  [idx for _, idx in attached])
+            self._aff.invalidate()
+            self._dirty_nodes = False
+            self._node_changed()
+            # Relist/rebuild: every row moved — the device mirror must
+            # re-upload; any pending per-row deltas are subsumed.
+            self.tensor_epoch += 1
+            self._dirty_rows.clear()
+        took = time.perf_counter() - t0
         self.stats["rebuilds"] += 1
-        self.stats["rebuild_s"] += time.perf_counter() - t0
+        self.stats["rebuild_s"] += took
+        metrics.CACHE_REBUILDS.inc()
+        metrics.CACHE_REBUILD_SECONDS.inc(took)
 
     # ---- workload-constraint bookkeeping (engine/workloads/) ----------
 
@@ -665,7 +799,7 @@ class SchedulerCache:
 
         from kubernetes_tpu.engine.workloads.preemption import VictimTable
         self._ensure_tensors()
-        n = len(self._node_order)
+        n = self._nt.n
         v = 1 << max(max_victims - 1, 0).bit_length()
         req = np.zeros((n, v, 4), np.int32)
         prio = np.zeros((n, v), np.int32)
@@ -715,7 +849,7 @@ class SchedulerCache:
         with the current row order, WITHOUT touching cache state; the
         verifier diffs them against the live ``_agg`` rows."""
         self._ensure_tensors()
-        agg = fc.empty_aggregates(len(self._node_order), self.space)
+        agg = fc.empty_aggregates(self._nt.n, self.space)
         attached = list(self._attached_pods())
         if attached:
             agg = fc.add_pods_to_aggregates_bulk(
@@ -761,10 +895,12 @@ class SchedulerCache:
     @_locked
     def snapshot(self) -> tuple[fc.NodeTensors, fc.NodeAggregates,
                                 fc.ExistingPodTensors, list[api.Node]]:
-        """Current tensor view (UpdateNodeNameToInfoMap analogue).  The
-        returned aggregates are referenced, not copied — callers must not
-        mutate them."""
+        """Current tensor view (UpdateNodeNameToInfoMap analogue): node
+        tensors, aggregates, existing pods, and the ``api.Node`` of every
+        ROW (``fc.FREE_NODE`` at a free one), all ``nt.n`` rows long.
+        The returned aggregates are referenced, not copied — callers
+        must not mutate them."""
         self._ensure_tensors()
         # Existing-pod label matrix may lag vocab growth from newly seen pods.
         self._ep.labels = fc._grow_cols(self._ep.labels, self.space.pod_labels.capacity)
-        return self._nt, self._agg, self._ep, self.nodes()
+        return self._nt, self._agg, self._ep, self._row_nodes()

@@ -16,6 +16,11 @@ cross-checks
   ``affinity_planes`` — the kept inter-pod affinity planes
   (``features/affinity.py ResidentAffinity``) vs a build from nothing
   out of the same pods;
+* ``node_rows`` — the node axis (its rows have a capacity: live or
+  free): every tracked node has exactly one row and every other row
+  reads as free; a sampled set of live rows vs a fresh ``compile_nodes``
+  of the same ``api.Node`` (the rebuilt-against-incremental comparison
+  of rows that joins, updates and removals wrote in place);
 * ``device_row`` — a sampled row set read back from the device-resident
   tensors vs the host arrays, valid only when the mirror claims to be in
   sync (same epoch + shape signature) and the rows carry no pending
@@ -109,7 +114,7 @@ class Verifier:
                     (np.asarray(live) != np.asarray(ref)).any(axis=-1)
                     if np.asarray(live).ndim > 1 else
                     np.asarray(live) != np.asarray(ref))[0][:8]
-                nodes = [self.cache._node_order[i] for i in bad.tolist()]
+                nodes = [self.cache._nt.names[i] for i in bad.tolist()]
                 out.append(Violation(
                     "aggregates",
                     f"{name} rows diverged from recompute at "
@@ -124,6 +129,61 @@ class Verifier:
                     f"from nothing, e.g. {drift[:3]}"))
         return out
 
+    def _check_node_rows(self) -> list[Violation]:
+        """The node axis' rows against the tracked nodes: the row <->
+        node map, free rows that read as free, and a sample of live rows
+        against ``compile_nodes`` of the node alone.  Skipped while the
+        tensors await a rebuild (nothing incremental to hold to)."""
+        out: list[Violation] = []
+        with self.cache.lock:
+            cache, nt, agg = self.cache, self.cache._nt, self.cache._agg
+            if cache._dirty_nodes or nt is None:
+                return []
+            live = {name: i for i, name in enumerate(nt.names)
+                    if name is not None}
+            if live != nt.name_to_idx or live.keys() != cache._nodes.keys() \
+                    or sorted(nt.free) != [i for i, name in
+                                           enumerate(nt.names)
+                                           if name is None]:
+                out.append(Violation(
+                    "node_rows",
+                    f"row map diverged: {len(live)} named rows, "
+                    f"{len(nt.name_to_idx)} indexed, {len(cache._nodes)} "
+                    f"nodes tracked, {len(nt.free)} of {nt.n} rows free"))
+                return out
+            free = np.asarray(nt.free, np.int64)
+            if free.size and (
+                    nt.schedulable[free].any() or nt.alloc[free].any()
+                    or nt.labels[free].any() or (nt.topo_val[free] >= 0).any()
+                    or agg.requested[free].any() or agg.nonzero[free].any()):
+                out.append(Violation(
+                    "node_rows", "a free row does not read as free"))
+            names = list(live)
+            if names:
+                from kubernetes_tpu.features import compiler as fc
+                k = min(self.sample, len(names))
+                picked = [names[i] for i in
+                          self._rng.choice(len(names), size=k, replace=False)]
+                want = fc.compile_nodes([cache._nodes[nm] for nm in picked],
+                                        cache.space)
+                idx = np.asarray([live[nm] for nm in picked], np.int64)
+                for field in ("alloc", "schedulable", "mem_pressure",
+                              "disk_pressure", "topo_val", "labels",
+                              "taints_nosched", "taints_prefer"):
+                    have = getattr(nt, field)[idx]
+                    fresh = getattr(want, field)
+                    if have.ndim > 1:       # a vocabulary grew since
+                        have = have[:, :fresh.shape[1]]
+                        fresh = fresh[:, :have.shape[1]]
+                    if not np.array_equal(have, fresh):
+                        bad = np.nonzero((have != fresh).reshape(k, -1)
+                                         .any(axis=1))[0][:8]
+                        out.append(Violation(
+                            "node_rows",
+                            f"{field} rows diverged from a fresh compile "
+                            f"at node(s) {[picked[i] for i in bad]}"))
+        return out
+
     def _check_device_rows(self) -> list[Violation]:
         """Sampled device-resident rows vs the host arrays — the
         dirty-row scatter protocol's observable contract.  Rows with
@@ -136,7 +196,7 @@ class Verifier:
         with self.cache.lock:
             self.cache._ensure_tensors()
             nt, agg = self.cache._nt, self.cache._agg
-            n = len(self.cache._node_order)
+            n = nt.n
             if n == 0 or not self.resident.in_sync(
                     nt, self.cache.space, self.cache.tensor_epoch):
                 return []
@@ -158,8 +218,7 @@ class Verifier:
                     continue
                 diff = np.asarray(dev[field]) != host[field]
                 bad = np.nonzero(diff.reshape(k, -1).any(axis=1))[0][:8]
-                nodes = [self.cache._node_order[int(idx[i])]
-                         for i in bad.tolist()]
+                nodes = [nt.names[int(idx[i])] for i in bad.tolist()]
                 out.append(Violation(
                     "device_row",
                     f"resident {field} rows diverged from host at "
@@ -285,6 +344,7 @@ class Verifier:
         """One full pass; counts, logs, and (when ``heal``) self-heals.
         Returns the violations found."""
         violations = (self._check_aggregates() +
+                      self._check_node_rows() +
                       self._check_device_rows() +
                       self._check_apiserver() +
                       self._check_defrag())

@@ -44,6 +44,34 @@ from kubernetes_tpu.utils.trace import Trace, record_stage, stage
 log = get_logger("engine")
 
 
+def _node_names(nt: fc.NodeTensors, rows) -> list[str | None]:
+    """A readback's row indices as node names, None where unschedulable
+    (``-1``).  ``nt`` is the launch's own view (``_compile``): a row
+    reads as the node the scan saw there, whatever joined or left since
+    (a node that left meanwhile is named all the same, as it always was:
+    the assume waits for a join under that name).  A free row fits
+    nothing, so the scan never yields one; a decision that names one is
+    counted as a cache invariant violation and the pod stays pending
+    rather than be bound to no node."""
+    names = nt.names
+    out = [names[c] if c >= 0 else None for c in rows]
+    lost = sum(1 for c, name in zip(rows, out) if c >= 0 and name is None)
+    if lost:
+        metrics.CACHE_INVARIANT_VIOLATIONS.labels(kind="free_row").inc(lost)
+        log.error("%d decision(s) named a free row of the node axis; the "
+                  "pods stay pending", lost)
+    return out
+
+
+def _node_name(nt: fc.NodeTensors, row: int, pod: api.Pod) -> str:
+    """The single-pod decision's row as a node name, by ``_node_names``'
+    rules: a free row is counted and fails the pod."""
+    (name,) = _node_names(nt, [row])
+    if name is None:
+        raise FitError(pod, {})
+    return name
+
+
 class FitError(Exception):
     """No node fits (generic_scheduler.go:39-61). failed_predicates maps
     node name -> list of failing predicate names."""
@@ -227,8 +255,12 @@ class GenericScheduler:
     def _compile(self, pods: list[api.Pod], device: bool = True,
                  host_only: bool = False, live: np.ndarray | None = None
                  ) -> tuple[fb.PodBatch, "sv.PackedBatch | sv.DeviceBatch",
-                            sv.DeviceCluster, list[str]]:
-        """The batch comes back in its wire form on the device
+                            sv.DeviceCluster, fc.NodeTensors]:
+        """The node tensors come back as the launch's own VIEW
+        (``NodeTensors.launch_view``, taken under the cache lock): a row
+        freed and handed to another node while the solve is in flight
+        still reads as the node the scan checked.
+        The batch comes back in its wire form on the device
         (``sv.PackedBatch``, with the tie counter and, where the caller
         padded, the ``live`` mask riding its carrier), or with
         ``device=False`` as the host-numpy DeviceBatch the chunked drain
@@ -258,6 +290,7 @@ class GenericScheduler:
             record_stage("lock_wait", start=t_lock)
             with stage("snapshot", pods=len(pods)):
                 nt, agg, ep, nodes = self.cache.snapshot()
+                view = nt.launch_view()
                 # Tag for the device-aggregate handoff: the snapshot the
                 # solve starts from (assume_pods validates nothing changed
                 # since).
@@ -278,7 +311,7 @@ class GenericScheduler:
                     if has_spread else None
             if host_only:
                 return (batch, sv.host_batch(batch),
-                        sv._host_cluster(nt, agg, self.cache.space), nt)
+                        sv._host_cluster(nt, agg, self.cache.space), view)
             with stage("transfer", device=device):
                 # device=False keeps the batch pytree on host (the chunked
                 # drain slices it in numpy and transfers fixed-shape
@@ -298,7 +331,7 @@ class GenericScheduler:
                 dc = self.resident.sync(nt, agg, self.cache.space,
                                         self.cache.take_dirty_rows(),
                                         self.cache.tensor_epoch)
-        return batch, db, dc, nt
+        return batch, db, dc, view
 
     # -- single-pod path (Schedule, generic_scheduler.go:78) -------------
 
@@ -317,7 +350,7 @@ class GenericScheduler:
 
     def _schedule_device(self, pod: api.Pod) -> str:
         trace = Trace(f"Scheduling {pod.namespace}/{pod.name}")
-        if not self.cache.nodes():
+        if not self.cache.node_count():
             raise FitError(pod, {})
         with devicestats.live_path("single_pod"), \
                 self.guard.watch("single_pod"):
@@ -364,7 +397,7 @@ class GenericScheduler:
             picked = int(choice[0])
         self.last_node_index = np.uint32(new_last)
         trace.log_if_long()
-        return nt.names[picked]
+        return _node_name(nt, picked, pod)
 
     def _schedule_with_extenders(self, pod: api.Pod, nt,
                                  feasible_np: np.ndarray,
@@ -372,8 +405,12 @@ class GenericScheduler:
         """Extender filter after built-in predicates
         (generic_scheduler.go:189-207) and prioritize summed at weight
         (:287-305), then selectHost (:124-141) host-side."""
-        nodes = self.cache.nodes()
-        candidates = [nodes[i] for i in range(len(nodes)) if feasible_np[i]]
+        # Rows are the launch's (``nt`` is its view); a node that left
+        # since is no candidate, one that joined since was not evaluated.
+        row_of = {nt.names[i]: int(i) for i in np.flatnonzero(feasible_np)}
+        candidates = [n for n in self.cache.nodes() if n.name in row_of]
+        if not candidates:
+            raise FitError(pod, {})
         failed_ext: dict[str, list[str]] = {}
         degraded = False
         for ext in self.extenders:
@@ -401,8 +438,7 @@ class GenericScheduler:
                 failed_ext.setdefault(name, []).append(msg or "extender")
             if not candidates:
                 raise FitError(pod, failed_ext)
-        name_to_idx = nt.name_to_idx
-        combined = {n.name: float(scores_np[name_to_idx[n.name]])
+        combined = {n.name: float(scores_np[row_of[n.name]])
                     for n in candidates}
         for ext in self.extenders:
             for host, score in ext.prioritize(pod, candidates).items():
@@ -439,7 +475,7 @@ class GenericScheduler:
         FitError / extender / round-robin contract as the device path."""
         trace = Trace(f"Scheduling {pod.namespace}/{pod.name} "
                       f"(host engine)")
-        if not self.cache.nodes():
+        if not self.cache.node_count():
             raise FitError(pod, {})
         metrics.SOLVE_FALLBACKS.labels(mode="host").inc()
         batch, hb, hc, nt = self._compile_host([pod])
@@ -478,7 +514,7 @@ class GenericScheduler:
         choice = int(np.nonzero(ties)[0][ix])
         self.last_node_index = np.uint32(int(self.last_node_index) + 1)
         trace.log_if_long()
-        return nt.names[choice]
+        return _node_name(nt, choice, pod)
 
     def schedule_batch_host(self, pods: list[api.Pod]) -> list[str | None]:
         """The host fallback drain: ``schedule_batch``'s contract (node
@@ -488,7 +524,7 @@ class GenericScheduler:
         the same guarantees."""
         if not pods:
             return []
-        if not self.cache.nodes():
+        if not self.cache.node_count():
             return [None] * len(pods)
         if self.extenders:
             return self._schedule_batch_via_extenders(pods)
@@ -506,8 +542,7 @@ class GenericScheduler:
                 alloc=nt.alloc, requests=np.asarray(batch.request),
                 keys_fn=lambda: [p.key for p in pods])
         self.last_node_index = np.uint32(counter)
-        names = nt.names
-        return [names[int(c)] if c >= 0 else None for c in choices]
+        return _node_names(nt, choices.tolist())
 
     # -- batched path ----------------------------------------------------
 
@@ -530,7 +565,7 @@ class GenericScheduler:
         pre-warmable)."""
         if not pods:
             return []
-        if not self.cache.nodes():
+        if not self.cache.node_count():
             # Empty cluster: findNodesThatFit over zero nodes fails every
             # pod (no device solve; zero-size tensors don't reduce).
             return [None] * len(pods)
@@ -629,8 +664,7 @@ class GenericScheduler:
                     self._snapshot_generation, placed_sig, nt,
                     host[p + 1:p + 1 + 4 * n].reshape(n, 4),
                     host[p + 1 + 4 * n:].reshape(n, 2))
-        names = nt.names
-        return [names[c] if c >= 0 else None for c in rows]
+        return _node_names(nt, rows)
 
     def plan_report(self) -> dict:
         """What the feature build keeps, for ``/debug/vars``."""
@@ -666,8 +700,7 @@ class GenericScheduler:
         pods = pods[:self.EXPLAIN_CAP]
         if not pods:
             return {}
-        nodes = self.cache.nodes()
-        if not nodes:
+        if not self.cache.node_count():
             return {pod.key: {"message": "no nodes in cluster",
                               "failed_predicates": {}}
                     for pod in pods}
@@ -676,7 +709,9 @@ class GenericScheduler:
         masks = {name: np.asarray(m) for name, m in
                  self.solver.masks(db, dc).items()}
         _, scores = self.solver.evaluate(db, dc, sv.batch_flags(batch))
-        scores = np.asarray(scores)
+        # a free row of the node axis is no node: never among the top
+        scores = np.where([name is not None for name in nt.names],
+                          np.asarray(scores), -np.inf)
         sched = np.asarray(nt.schedulable, dtype=bool)
         n_sched = int(sched.sum())
         out: dict = {}
@@ -727,7 +762,7 @@ class GenericScheduler:
         pods = [p for p in pods if p.effective_priority > 0]
         pods.sort(key=lambda p: (-p.effective_priority, p.key))
         pods = pods[:self.PREEMPT_CAP]
-        if not pods or not self.cache.nodes():
+        if not pods or not self.cache.node_count():
             return []
         padded = fb.pad_pods(pods, self.PREEMPT_CAP)
         batch, db, dc, nt = self._compile(padded)
@@ -825,7 +860,7 @@ class GenericScheduler:
         p = len(pods)
         if p == 0:
             return
-        if not self.cache.nodes():
+        if not self.cache.node_count():
             for start in range(0, p, chunk_size):
                 chunk = pods[start:start + chunk_size]
                 empty = [None] * len(chunk)
@@ -880,9 +915,7 @@ class GenericScheduler:
                     requests=np.asarray(hb.request)[
                         start:start + chunk_size],
                     keys_fn=lambda: [pd.key for pd in chunk_pods])
-            placements = [nt.names[int(c)] if c >= 0 else None
-                          for c in rows[: stop - start]]
-            return chunk_pods, placements
+            return chunk_pods, _node_names(nt, rows[: stop - start].tolist())
 
         for start in range(0, padded, chunk_size):
             # Host-slice (free numpy views), pack the fixed

@@ -696,8 +696,16 @@ class ResidentCluster:
 
     Invariants (the "device-residency protocol", see ARCHITECTURE.md):
 
-    * a FULL re-upload happens when row identity moved (cache
-      ``tensor_epoch`` bump: relist rebuild, node append/remove) or any
+    * the node axis has a CAPACITY (``features.compiler.capacity``:
+      whole 128-row tiles with a row to spare; the signature's first
+      component), so a node that joins into a free row or leaves its row
+      free is a dirty row like any other: no upload of the fleet, no new
+      XLA shape.  Free rows are all zeros and not schedulable, so the
+      fleet-wide dtype proof and ``FULL_FRACTION`` read the same over
+      the capacity as over the live rows;
+    * a FULL re-upload happens when every row moved (cache
+      ``tensor_epoch`` bump: the rebuild of the node tensors, the node
+      axis grown by tiles because a join found no free row) or any
       column capacity grew (vocab interning widened a table — the shape
       signature changed and the resident arrays cannot hold the rows);
     * otherwise the mirror equals ``device_cluster`` of the current host
@@ -736,7 +744,8 @@ class ResidentCluster:
     @staticmethod
     def signature(nt: "NodeTensors", space: "FeatureSpace",
                   policy: Optional[DtypePolicy] = None) -> tuple:
-        """The shape signature a resident copy was uploaded at; any
+        """The shape signature a resident copy was uploaded at — its
+        first component the node axis' CAPACITY, not the node count; any
         component moving — including the narrow dtype policy (a value
         crossing the int16 gate widens the plane) — means the arrays
         cannot be patched in place."""
@@ -1768,7 +1777,11 @@ class Solver:
         # Repair-order key: smallest dominant-resource fraction first (for a
         # sum-of-scores objective with commensurate per-pod scores this
         # maximizes admitted count), regret-tiebroken within a size bucket.
-        dfrac = jnp.max(demand[:, None, :] / free[None, :, :], axis=(1, 2))
+        # (over the nodes a pod could land on: a free row of the node axis
+        # has no room at all and would read as a full node everywhere)
+        dfrac = jnp.max(jnp.where(c.schedulable[None, :, None],
+                                  demand[:, None, :] / free[None, :, :],
+                                  0.0), axis=(1, 2))
         key = -jnp.floor(jnp.minimum(dfrac, 1.0) * 16.0) * \
             (20.0 * score_span) + jnp.where(jnp.isfinite(regret), regret, 0.0)
         return -cost, key

@@ -338,7 +338,9 @@ class ResidentAffinity:
     where the cache maintains its aggregates: ``add_pod`` / ``remove_pod``
     per attached pod, ``invalidate`` when the node rows or their labels
     change (the next launch then builds them again from ``attached()``,
-    the cache's ``(pod, node index)`` of every pod on a known node).
+    the cache's ``(pod, node index)`` of every pod on a known node that
+    declares a term: right after a reset no match signature is
+    registered, so no other pod moves a plane).
     ``compile_affinity(..., resident=self)`` builds a launch's tables
     from these planes and the batch's own incidence rows; it equals the
     from-nothing build to the element.

@@ -21,7 +21,8 @@ Resource vectors are [*, 4] int32 in order (milli_cpu, memory_mib, gpu, pods).
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+import heapq
+from dataclasses import dataclass, field, replace
 from typing import Optional, Sequence
 
 import numpy as np
@@ -89,11 +90,38 @@ class FeatureSpace:
         return out
 
 
+NODE_TILE = 128
+
+
+def capacity(n: int) -> int:
+    """Rows the node axis is allocated at for a fleet of ``n`` nodes: ``n``
+    rounded up to whole 128-row tiles with at least one row free (5,000
+    -> 5,120, 1,000 -> 1,024, 128 -> 256).  A function of the count
+    alone.  Tiles and not a power of two: 5,000 -> 8,192 would add 64 %
+    to every scan of a fleet that never changes."""
+    return (n // NODE_TILE + 1) * NODE_TILE
+
+
+# What the ``nodes`` list of a snapshot holds at a free row: a node no
+# pod fits and no score counts (not Ready, no room, no label, no taint).
+# Writing it with ``_write_node_row`` IS the encoding of a free row, so a
+# feature builder that walks the list needs no case for one.
+FREE_NODE = api.Node(name="", allocatable_pods=0, unschedulable=True)
+
+
 @dataclass
 class NodeTensors:
-    """Static per-node features [N, ...] (rebuilt when nodes change)."""
+    """Static per-node features [N, ...], N a CAPACITY (``capacity()``
+    where the cache builds them): a row is live (``names[i]`` is its
+    node) or free (``names[i]`` is None, the row reads as ``FREE_NODE``:
+    ``schedulable`` False takes it out of every fit, and every
+    normalisation of the priorities spans schedulable rows).  A node
+    event inside the capacity writes one row; only a join that finds no
+    free row grows every tensor, by whole tiles.  The arrays are written
+    in place under the cache lock; ``names`` is replaced at every change
+    (``_rename_row``)."""
 
-    names: list[str]
+    names: list[Optional[str]]
     name_to_idx: dict[str, int]
     alloc: np.ndarray          # [N, 4] int32
     labels: np.ndarray         # [N, V] bool — kv + key-presence membership
@@ -104,10 +132,22 @@ class NodeTensors:
     schedulable: np.ndarray    # [N] bool — getNodeConditionPredicate
     image_kib: np.ndarray      # [N, I] int32
     topo_val: np.ndarray       # [N, K] int32 — domain id per topo key, -1 absent
+    free: list[int] = field(default_factory=list)  # free rows, a min-heap
 
     @property
     def n(self) -> int:
+        """Rows (the capacity), live and free."""
         return len(self.names)
+
+    def launch_view(self) -> "NodeTensors":
+        """What a launch keeps of the node axis once the cache lock is
+        let go, taken under it: row -> node as the scan saw it (the
+        ``names`` list of now, which no later event writes) and copies
+        of the two planes read after the solve — ``alloc`` by the sanity
+        gate, ``schedulable`` by the failure accounts.  A row freed or
+        handed to another node in flight reads here as it was."""
+        return replace(self, alloc=self.alloc.copy(),
+                       schedulable=self.schedulable.copy())
 
 
 @dataclass
@@ -141,12 +181,17 @@ class ExistingPodTensors:
     free_slots: list[int]      # O(1) slot allocation (popped LIFO)
 
 
-def compile_nodes(nodes: Sequence[api.Node], space: FeatureSpace) -> NodeTensors:
+def compile_nodes(nodes: Sequence[api.Node], space: FeatureSpace,
+                  rows: Optional[int] = None) -> NodeTensors:
     """Build static node tensors, interning all label/taint/image tokens.
-    Row encoding is shared with the incremental churn path
-    (update_node_row/append_node_row) via _intern_node/_write_node_row, so
+    ``nodes`` take the first rows in list order; ``rows`` (default: as
+    many as nodes) is the capacity to allocate, the rest free.  Row
+    encoding is shared with the incremental churn path
+    (update_node_row/take_node_row) via _intern_node/_write_node_row, so
     rebuilt rows and incrementally-updated rows cannot diverge."""
-    n = len(nodes)
+    live = len(nodes)
+    n = live if rows is None else rows
+    assert n >= live, (n, live)
     # Intern first so capacities are final before allocation.
     for node in nodes:
         _intern_node(node, space)
@@ -154,8 +199,9 @@ def compile_nodes(nodes: Sequence[api.Node], space: FeatureSpace) -> NodeTensors
     V, T, I, K = (space.labels.capacity, space.taints.capacity,
                   space.images.capacity, space.topo_keys.capacity)
     nt = NodeTensors(
-        names=[nd.name for nd in nodes],
+        names=[nd.name for nd in nodes] + [None] * (n - live),
         name_to_idx={nd.name: i for i, nd in enumerate(nodes)},
+        free=list(range(live, n)),
         alloc=np.zeros((n, 4), np.int32),
         labels=np.zeros((n, V), bool),
         taints_nosched=np.zeros((n, T), bool),
@@ -235,46 +281,68 @@ def update_node_row(nt: NodeTensors, idx: int, node: api.Node,
     _write_node_row(nt, idx, node, space)
 
 
-def append_node_row(nt: NodeTensors, node: api.Node,
-                    space: FeatureSpace) -> int:
-    """Incremental node ADD: append one row to every [N, ...] tensor."""
-    _intern_node(node, space)
-    _grow_node_columns(nt, space)
-    i = len(nt.names)
-    nt.alloc = np.concatenate([nt.alloc, np.zeros((1, 4), np.int32)])
-    nt.labels = np.concatenate(
-        [nt.labels, np.zeros((1, nt.labels.shape[1]), bool)])
-    nt.taints_nosched = np.concatenate(
-        [nt.taints_nosched,
-         np.zeros((1, nt.taints_nosched.shape[1]), bool)])
-    nt.taints_prefer = np.concatenate(
-        [nt.taints_prefer, np.zeros((1, nt.taints_prefer.shape[1]), bool)])
-    nt.mem_pressure = np.concatenate([nt.mem_pressure, np.zeros(1, bool)])
-    nt.disk_pressure = np.concatenate([nt.disk_pressure, np.zeros(1, bool)])
-    nt.schedulable = np.concatenate([nt.schedulable, np.zeros(1, bool)])
-    nt.image_kib = np.concatenate(
-        [nt.image_kib, np.zeros((1, nt.image_kib.shape[1]), np.int32)])
-    nt.topo_val = np.concatenate(
-        [nt.topo_val, np.full((1, nt.topo_val.shape[1]), -1, np.int32)])
-    nt.names.append(node.name)
+def _rename_row(nt: NodeTensors, i: int, name: Optional[str]) -> None:
+    """``names`` is REPLACED, never written in place: a launch keeps the
+    list it took under the cache lock, so a row it decided on still
+    reads as the node the scan saw there, whoever holds the row now."""
+    names = list(nt.names)
+    names[i] = name
+    nt.names = names
+
+
+def take_node_row(nt: NodeTensors, node: api.Node,
+                  space: FeatureSpace) -> int:
+    """Incremental node ADD: the lowest free row becomes ``node``'s,
+    written by ``update_node_row``.  The caller grows the tensors first
+    where no row is free."""
+    i = heapq.heappop(nt.free)
+    _rename_row(nt, i, node.name)
     nt.name_to_idx[node.name] = i
-    _write_node_row(nt, i, node, space)
+    update_node_row(nt, i, node, space)
     return i
 
 
-def append_aggregate_row(agg: NodeAggregates) -> None:
-    """Zero aggregates for a newly appended node row."""
-    agg.requested = np.concatenate(
-        [agg.requested, np.zeros((1, 4), np.int32)])
-    agg.nonzero = np.concatenate([agg.nonzero, np.zeros((1, 2), np.int32)])
-    for field_name in ("ports_used", "vol_any", "vol_rw"):
-        a = getattr(agg, field_name)
-        setattr(agg, field_name,
-                np.concatenate([a, np.zeros((1, a.shape[1]), bool)]))
-    for field_name in ("vol_rw_count", "vol_any_count"):
-        a = getattr(agg, field_name)
-        setattr(agg, field_name,
-                np.concatenate([a, np.zeros((1, a.shape[1]), np.int16)]))
+def free_node_row(nt: NodeTensors, name: str, space: FeatureSpace) -> int:
+    """Incremental node REMOVE: ``name``'s row reads as ``FREE_NODE``
+    from here on and is handed out again by ``take_node_row``."""
+    i = nt.name_to_idx.pop(name)
+    _rename_row(nt, i, None)
+    _write_node_row(nt, i, FREE_NODE, space)
+    heapq.heappush(nt.free, i)
+    return i
+
+
+def _grow_rows(a: np.ndarray, rows: int, fill=0) -> np.ndarray:
+    out = np.full((rows,) + a.shape[1:], fill, a.dtype)
+    out[: a.shape[0]] = a
+    return out
+
+
+_NODE_PLANES = ("alloc", "labels", "taints_nosched", "taints_prefer",
+                "mem_pressure", "disk_pressure", "schedulable", "image_kib")
+_AGG_PLANES = ("requested", "nonzero", "ports_used", "vol_any", "vol_rw",
+               "vol_rw_count", "vol_any_count")
+
+
+def grow_node_rows(nt: NodeTensors, agg: NodeAggregates, rows: int) -> None:
+    """The node axis at ``rows`` rows: every [N, ...] array of the node
+    tensors and the aggregates copied once, the new rows free."""
+    old = nt.n
+    assert rows > old, (rows, old)
+    for name in _NODE_PLANES:
+        setattr(nt, name, _grow_rows(getattr(nt, name), rows))
+    nt.topo_val = _grow_rows(nt.topo_val, rows, fill=-1)
+    for name in _AGG_PLANES:
+        setattr(agg, name, _grow_rows(getattr(agg, name), rows))
+    nt.names = nt.names + [None] * (rows - old)
+    nt.free.extend(range(old, rows))    # past every row in it: still a heap
+
+
+def clear_aggregate_row(agg: NodeAggregates, idx: int) -> None:
+    """Zero aggregates: the row of a node that left with its pods still
+    tracked (their own deletes find no row)."""
+    for name in _AGG_PLANES:
+        getattr(agg, name)[idx] = 0
 
 
 def pod_resource_row(pod: api.Pod) -> np.ndarray:

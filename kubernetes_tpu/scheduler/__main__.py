@@ -283,6 +283,7 @@ def _status_mux(factory: ConfigFactory, configz: dict, port: int,
                 from kubernetes_tpu.utils.metrics import (
                     CACHE_INVARIANT_VIOLATIONS, POST_PREWARM_COMPILES)
                 cache = factory.algorithm.cache
+                node_capacity, node_rows_free = cache.node_rows()
                 queue = factory.daemon.queue
                 self._send(200, json.dumps({
                     "queueDepth": len(queue),
@@ -337,7 +338,11 @@ def _status_mux(factory: ConfigFactory, configz: dict, port: int,
                                  if hasattr(factory.store, "flow_report")
                                  else None),
                     "cachedPods": cache.pod_count(),
-                    "cachedNodes": len(cache.nodes()),
+                    # counts the cache keeps: nothing here builds the
+                    # node tensors (a starting daemon's list is partial)
+                    "cachedNodes": cache.node_count(),
+                    "nodeCapacity": node_capacity,
+                    "nodeRowsFree": node_rows_free,
                     "cacheStats": cache.stats,
                     "generation": cache.generation,
                     # The three counters of the cache (ARCHITECTURE.md)

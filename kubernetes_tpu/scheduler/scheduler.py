@@ -273,8 +273,11 @@ class Scheduler:
         cache = self.config.algorithm.cache
         with self._requeue_cv:
             backoff = {pod.key for _, _, pod in self._requeue_heap}
+        # (a copy is walked: the bind threads pop their keys meanwhile,
+        # and a dict that changes size under the walk kills this thread
+        # — the pending pods' reflector, ISSUE 36's chip run)
         self._first_seen = {
-            k: t for k, t in self._first_seen.items()
+            k: t for k, t in list(self._first_seen.items())
             if k in backoff or k in self.queue or cache.contains(k)}
         if len(self._first_seen) > 65536:
             self._first_seen = prune_first_seen_fair(
@@ -616,11 +619,12 @@ class Scheduler:
         from kubernetes_tpu.utils.featuregate import DEFAULT_FEATURE_GATE
         alg = self.config.algorithm
         if not DEFAULT_FEATURE_GATE.enabled("StreamingDrain") or \
-                alg.extenders or not alg.cache.nodes():
+                alg.extenders or not alg.cache.node_count():
             return []
         ladder = self.effective_ladder()
         return prewarm_plan(
-            ladder, ResidentCluster.scatter_buckets(len(alg.cache.nodes())),
+            ladder,
+            ResidentCluster.scatter_buckets(alg.cache.snapshot()[0].n),
             joint=DEFAULT_FEATURE_GATE.enabled("JointSolver"),
             preempt=DEFAULT_FEATURE_GATE.enabled("Preemption"))
 
@@ -641,7 +645,7 @@ class Scheduler:
         from kubernetes_tpu.utils.featuregate import DEFAULT_FEATURE_GATE
         alg = self.config.algorithm
         if not DEFAULT_FEATURE_GATE.enabled("StreamingDrain") or \
-                alg.extenders or not alg.cache.nodes():
+                alg.extenders or not alg.cache.node_count():
             return {}
         # Prewarm compiles are never "post-prewarm": disarm for the
         # duration so a fresh rig warming up in an already-armed process
@@ -759,7 +763,7 @@ class Scheduler:
         if DEFAULT_FEATURE_GATE.enabled("Preemption"):
             from kubernetes_tpu.engine.workloads import preemption
             t0 = time.perf_counter()
-            preemption.prewarm_shapes(len(alg.cache.nodes()))
+            preemption.prewarm_shapes(alg.cache.snapshot()[0].n)
             timings["preempt"] = time.perf_counter() - t0
         if floor:
             tsc = _json.dumps([{

@@ -212,8 +212,11 @@ class ExtenderCore:
         batch, db, dc, nt = eng._compile([pod])
         from kubernetes_tpu.engine.solver import batch_flags
         feasible, scores = eng.solver.evaluate(db, dc, batch_flags(batch))
-        result = _EvalResult(pod, [n.name for n in eng.cache.nodes()],
-                             np.asarray(feasible[0]), np.asarray(scores[0]),
+        # built from one list: the nodes are the first rows of the axis
+        names = [n.name for n in eng.cache.nodes()]
+        result = _EvalResult(pod, names,
+                             np.asarray(feasible[0])[:len(names)],
+                             np.asarray(scores[0])[:len(names)],
                              eng.solver, db, dc, nt, item_bytes)
         with self._lock:
             memo[tkey] = result

@@ -800,6 +800,40 @@ CACHE_LOCK_CONTENDED = register(Counter(
     "Acquisitions of the scheduler cache's lock that had to block, by "
     "the waiting thread's role",
     labelnames=("role",)))
+# The cache's node axis (cache/scheduler_cache.py): rows with a capacity,
+# so a node event inside it is one row written and one dirty row.
+CACHE_NODE_EVENTS = register(Counter(
+    "scheduler_cache_node_events_total",
+    "Node events the scheduler cache took, by event (added | updated | "
+    "removed) and by the road it went: row (one row of the node tensors "
+    "written in place: a join into a free row, a removal that frees its "
+    "row, an update), grow (a join that found no free row: the node axis "
+    "grew by whole tiles, one full upload and one new XLA shape), rebuild "
+    "(the tensors were unbuilt or already marked: the next snapshot "
+    "rebuilds them, as at the first list or a relist)",
+    labelnames=("event", "path")))
+CACHE_NODE_EVENT_SECONDS = register(Counter(
+    "scheduler_cache_node_event_seconds_total",
+    "Seconds node events held the scheduler cache's lock, by event (the "
+    "wait for it is scheduler_cache_lock_wait_seconds_total; a rebuild "
+    "they mark is paid at the next snapshot: "
+    "scheduler_cache_rebuild_seconds_total)",
+    labelnames=("event",)))
+CACHE_REBUILDS = register(Counter(
+    "scheduler_cache_rebuilds_total",
+    "Rebuilds of every node tensor from the tracked nodes with a bulk "
+    "re-attach of every tracked pod, under the cache lock (first "
+    "snapshot, relist, self-heal, a new topology key); 0 in a steady "
+    "window, node events or not"))
+CACHE_REBUILD_SECONDS = register(Counter(
+    "scheduler_cache_rebuild_seconds_total",
+    "Seconds spent in those rebuilds"))
+CACHE_NODE_ROWS = register(Gauge(
+    "scheduler_cache_node_rows",
+    "Rows of the cache's node axis by state: live (a node's) and free "
+    "(read as a node no pod fits, handed to the next join); their sum "
+    "is the capacity every [N, ...] array and XLA program is shaped to",
+    labelnames=("state",)))
 # The resident side of the inter-pod affinity tables, kept between
 # launches (features/affinity.py ResidentAffinity, owned by the cache).
 AFFINITY_TABLE_REBUILDS = register(Counter(

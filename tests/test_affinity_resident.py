@@ -313,7 +313,10 @@ def _fit_mask(s: GenericScheduler, pod: api.Pod) -> np.ndarray:
     """The program's own fit mask for one pod, off the device engine."""
     batch, db, dc, _nt = s._compile([pod])
     feasible, _scores = s.solver.evaluate(db, dc, s._pinned_flags(batch))
-    return np.asarray(feasible[0])
+    mask = np.asarray(feasible[0])
+    live = s.cache.node_count()       # the fleet's rows; none past them fits
+    assert not mask[live:].any()
+    return mask[:live]
 
 
 @pytest.mark.parametrize("seed", [11, 2147483659])

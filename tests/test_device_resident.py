@@ -103,24 +103,49 @@ class TestResidentCluster:
             pods[0].key) else None
         _assert_resident_matches_fresh(algo)
 
-    def test_node_append_forces_full_resnapshot(self):
+    def test_node_join_inside_the_capacity_is_a_scattered_row(self):
+        """(was: a node append forces a full re-snapshot.)  The node
+        axis has a capacity: the joiner takes a free row, and that row
+        crosses in the scatter like any dirty row."""
         daemon = _rig(n_nodes=10)
         algo = daemon.config.algorithm
         algo.schedule_batch([make_pod("pre", cpu="100m")])
-        before = algo.resident.stats["full_syncs"]
+        before = dict(algo.resident.stats)
+        epoch, sig = algo.cache.tensor_epoch, algo.resident._sig
         algo.cache.add_node(make_node("joiner", milli_cpu=4000))
         algo.schedule_batch([make_pod("post", cpu="100m")])
-        assert algo.resident.stats["full_syncs"] == before + 1
+        assert algo.resident.stats["full_syncs"] == before["full_syncs"]
+        assert algo.resident.stats["row_syncs"] == before["row_syncs"] + 1
+        assert (algo.cache.tensor_epoch, algo.resident._sig) == (epoch, sig)
         _assert_resident_matches_fresh(algo)
+        # the joiner is a node like any other: a pod only it can hold
+        [dest] = algo.schedule_batch([make_pod("only", cpu="100m",
+                                               node_name="joiner")])
+        assert dest == "joiner"
 
-    def test_relist_rebuild_forces_full_resnapshot(self):
+    def test_node_removal_is_a_scattered_row_and_a_relist_a_full_upload(
+            self):
+        """(was: a removal rebuilds and forces a full re-snapshot.)  A
+        removal frees the node's row in place: one dirty row.  The
+        rebuild from the tracked objects (a relist, the verifier's
+        self-heal) is what still re-uploads the fleet."""
         daemon = _rig(n_nodes=10)
         algo = daemon.config.algorithm
         algo.schedule_batch([make_pod("pre2", cpu="100m")])
-        before = algo.resident.stats["full_syncs"]
+        before = dict(algo.resident.stats)
+        epoch = algo.cache.tensor_epoch
         algo.cache.remove_node("rn3")
-        algo.schedule_batch([make_pod("post2", cpu="100m")])
-        assert algo.resident.stats["full_syncs"] == before + 1
+        placed = algo.schedule_batch(
+            [make_pod(f"post2-{i}", cpu="100m") for i in range(12)])
+        assert None not in placed and "rn3" not in placed
+        assert algo.resident.stats["full_syncs"] == before["full_syncs"]
+        assert algo.resident.stats["row_syncs"] == before["row_syncs"] + 1
+        assert algo.cache.tensor_epoch == epoch
+        _assert_resident_matches_fresh(algo)
+        algo.cache.force_resnapshot()
+        algo.schedule_batch([make_pod("post3", cpu="100m")])
+        assert algo.resident.stats["full_syncs"] == before["full_syncs"] + 1
+        assert algo.cache.tensor_epoch == epoch + 1
         _assert_resident_matches_fresh(algo)
 
     def test_column_capacity_growth_forces_full_resnapshot(self):
@@ -142,10 +167,10 @@ class TestResidentCluster:
 
     def test_node_delete_readd_same_name_different_capacity(self):
         """ISSUE 7 satellite: delete a node and re-add it under the SAME
-        name with DIFFERENT capacity between drains.  The shape
-        signature is unchanged (same row count, same column caps), so
-        only the ``tensor_epoch`` bump can force the re-upload — a
-        stale mirror would keep scheduling against the old capacity."""
+        name with DIFFERENT capacity between drains.  The removal frees
+        the row and the join takes it again (ISSUE 36: both are dirty
+        rows, no ``tensor_epoch`` bump, no re-upload) — a stale mirror
+        would keep scheduling against the old capacity."""
         daemon = _rig(n_nodes=3)
         algo = daemon.config.algorithm
         # Fill the tiny fleet so only fresh capacity can take more.
@@ -169,8 +194,8 @@ class TestResidentCluster:
         # resident row (old 1000m) would fail it everywhere.
         [dest] = algo.schedule_batch([make_pod("big", cpu="4")])
         assert dest == "rn1"
-        assert algo.cache.tensor_epoch > epoch_before
-        assert algo.resident.stats["full_syncs"] == fulls_before + 1
+        assert algo.cache.tensor_epoch == epoch_before
+        assert algo.resident.stats["full_syncs"] == fulls_before
         _assert_resident_matches_fresh(algo)
         # And the reverse edge: re-add with SHRUNK capacity — the mirror
         # must not keep placing against the old larger row.
@@ -182,14 +207,15 @@ class TestResidentCluster:
         _assert_resident_matches_fresh(algo)
 
     def test_majority_dirty_falls_back_to_full_upload(self):
-        """Dirtying most of a small cluster re-uploads instead of
-        scattering (the gather would move most of the bytes anyway)."""
-        daemon = _rig(n_nodes=4)
+        """Dirtying over a quarter of the node axis re-uploads instead
+        of scattering (the gather would move most of the bytes anyway)."""
+        daemon = _rig(n_nodes=40)
         algo = daemon.config.algorithm
         algo.schedule_batch([make_pod("sd0", cpu="100m")])
         before = algo.resident.stats["full_syncs"]
-        for name in ("rn0", "rn1", "rn2"):
-            algo.cache.update_node(make_node(name, milli_cpu=8000))
+        assert algo.cache.snapshot()[0].n == 128
+        for i in range(33):                     # 33 x 4 > 128 rows
+            algo.cache.update_node(make_node(f"rn{i}", milli_cpu=8000))
         algo.schedule_batch([make_pod("sd1", cpu="100m")])
         assert algo.resident.stats["full_syncs"] == before + 1
 

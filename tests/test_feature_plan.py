@@ -23,6 +23,7 @@ import pytest
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.engine.generic_scheduler import GenericScheduler, Listers
 from kubernetes_tpu.features import batch as fb
+from kubernetes_tpu.features import compiler as fc
 from kubernetes_tpu.features import plan as fplan
 from kubernetes_tpu.utils import metrics
 
@@ -212,9 +213,13 @@ def test_pod_events_never_move_the_node_epoch():
         s.cache.add_pod(pods[2])
         s.cache.cleanup_expired()
     assert (s.cache.node_epoch, s.cache.tensor_epoch) == (epoch, tensor_epoch)
-    # the node list is made once per epoch and handed out
+    # the node lists are made once per epoch and handed out: the live
+    # nodes, and the node of every row (free rows after the fleet's)
     assert s.cache.nodes() is nodes
-    assert s.cache.snapshot()[3] is nodes
+    rows = s.cache.snapshot()[3]
+    assert s.cache.snapshot()[3] is rows
+    assert rows[:len(nodes)] == nodes and len(rows) == s.cache.snapshot()[0].n
+    assert all(nd is fc.FREE_NODE for nd in rows[len(nodes):])
 
 
 def test_new_template_then_kept():

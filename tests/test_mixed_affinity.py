@@ -316,7 +316,7 @@ def test_an_update_writes_one_cell_a_plane_on_the_zone_key_too():
     for planes in (aff.match, aff.sym):
         (sig,) = [s for s in planes.rows if s.key.endswith("/zone")]
         assert planes.cnt[planes.rows[sig]].tolist() == [0, 0, 1, 0]
-        assert aff.node_row(planes, sig).tolist() == [0, 0, 1] * 4
+        assert aff.node_row(planes, sig)[:12].tolist() == [0, 0, 1] * 4
 
 
 def test_launch_counters_follow_the_pinned_flag_and_the_tables():

@@ -152,9 +152,11 @@ def test_node_label_rows_cross_the_wire():
     eng = _rig()
     _batch, hb, _hc, _nt = eng._compile(_pods("plain", 2), host_only=True)
     vs = sv.unpack_batch(sv.pack_batch(hb)).volsvc
-    assert np.asarray(vs.nl_pred_row).all()          # every node has a rack
+    # (the fleet's rows; the free rows of the node axis carry no label)
+    assert np.asarray(vs.nl_pred_row)[:N_NODES].all()   # every node: a rack
+    assert not np.asarray(vs.nl_pred_row)[N_NODES:].any()
     assert np.asarray(vs.nl_prio_rows).sum() == N_NODES // 4   # ssd nodes
-    assert np.asarray(vs.saa_labeled).all()
+    assert np.asarray(vs.saa_labeled)[..., :N_NODES].all()
 
 
 @pytest.mark.parametrize("start,stop,real", [(0, 8, 8), (8, 16, 8),

@@ -33,7 +33,9 @@ def masks_for(pods, nodes, existing=None, predicates=None):
     solver = sv.Solver(policy or default_provider())
     db = sv.device_batch(batch)
     dc = sv.device_cluster(nt, agg, cache.space)
-    return {k: np.asarray(v) for k, v in solver.masks(db, dc).items()}
+    # the fleet's rows are the first of the node axis' capacity
+    return {k: np.asarray(v)[:, :len(nodes)]
+            for k, v in solver.masks(db, dc).items()}
 
 
 class TestPodFitsResources:

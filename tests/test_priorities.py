@@ -33,7 +33,8 @@ def scores_for(pods, nodes, priority, existing=None, listers=None, weight=1):
     db = sv.device_batch(batch)
     dc = sv.device_cluster(nt, agg, cache.space)
     _, scores = solver.evaluate(db, dc)
-    return np.asarray(scores)
+    # the fleet's rows are the first of the node axis' capacity
+    return np.asarray(scores)[:, :len(nodes)]
 
 
 class TestLeastRequested:

@@ -323,7 +323,8 @@ class TestVerifier:
         else:
             algo.resident.dc = dc._replace(
                 requested=dc.requested.at[row, 0].add(999))
-        v = Verifier(algo.cache, resident=algo.resident, sample=16)
+        # (every row of the node axis sampled: 6 live of 128)
+        v = Verifier(algo.cache, resident=algo.resident, sample=128)
         viol = v.verify_once()
         assert any(x.kind == "device_row" for x in viol)
         assert algo.resident.dc is None  # heal invalidated the mirror
@@ -335,7 +336,8 @@ class TestVerifier:
         must be skipped, not flagged."""
         algo = self._engine()
         algo.schedule_batch([make_pod("vo0", cpu="50m")])
-        algo.cache.add_node(make_node("joiner"))  # epoch bump pending
+        algo.cache.add_node(make_node("joiner"))  # a dirty row pending
+        algo.cache.force_resnapshot()             # an epoch bump pending
         v = Verifier(algo.cache, resident=algo.resident)
         assert v.verify_once() == []
 
