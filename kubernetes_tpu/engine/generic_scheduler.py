@@ -223,6 +223,13 @@ class GenericScheduler:
         if self.solver.scores_affinity(flags):
             metrics.AFFINITY_PRIORITY_PODS.inc(pods)
 
+    @staticmethod
+    def _count_steps(live: np.ndarray | None, rows: int) -> None:
+        """One ``_solve_scan`` dispatch of ``rows`` rows with this live
+        mask goes to the device."""
+        metrics.SCAN_STEPS.labels(kind="bucket").inc(rows)
+        metrics.SCAN_STEPS.labels(kind="run").inc(sv.scan_steps(live, rows))
+
     # -- compilation helpers --------------------------------------------
 
     def _features(self, pods: list[api.Pod], nt: fc.NodeTensors,
@@ -631,6 +638,7 @@ class GenericScheduler:
                 host_dev = self.solver.solve_sequential_packed(
                     db, dc, None, flags,
                     extra_mask=extra_mask, score_bias=score_bias)
+                self._count_steps(live_np, p)
                 # Block here so the solve stage measures device compute
                 # and readback measures only the D2H copy.
                 with stage("device_wait"):
@@ -945,6 +953,7 @@ class GenericScheduler:
                     stage("solve", chunk_at=start, mode="stream"):
                 choices_k, counter, carry = self.solver._solve_scan(
                     db_k, dc, counter, None, flags, carry)
+                self._count_steps(live_np[start:stop], chunk_size)
             pending.append((start, choices_k))
             if len(pending) > 1:
                 s_k, c_k = pending.pop(0)

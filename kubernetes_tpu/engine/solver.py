@@ -10,13 +10,15 @@ Two entry points:
     extender Filter/Prioritize verbs and as the building block of the solvers.
 
 ``solve_sequential``
-    Greedy sequential assignment under ``lax.scan``: pods are placed in queue
-    order and every placement updates device-resident aggregates (requested
-    resources, host ports, volume mounts, spreading counts) before the next
-    pod is scored — bit-for-bit the visibility the reference's scheduler gets
-    through its assumed-pod cache (scheduler.go:116-120, cache.go:107).  The
-    expensive O(P*N*V) contractions are hoisted out of the scan (they are
-    placement-invariant); only O(N) resource math recomputes per step.
+    Greedy sequential assignment as one on-device loop over the batch's rows
+    (``run_live_steps``: a scan that stops at its last live row): pods are
+    placed in queue order and every placement updates device-resident
+    aggregates (requested resources, host ports, volume mounts, spreading
+    counts) before the next pod is scored — bit-for-bit the visibility the
+    reference's scheduler gets through its assumed-pod cache
+    (scheduler.go:116-120, cache.go:107).  The expensive O(P*N*V)
+    contractions are hoisted out of the scan (they are placement-invariant);
+    only O(N) resource math recomputes per step.
 
 Both are pure jit-compatible functions of arrays; the node axis may be
 sharded across a mesh (see kubernetes_tpu.parallel).
@@ -70,12 +72,79 @@ DYNAMIC_PRIORITIES = ("LeastRequestedPriority", "MostRequestedPriority",
                       "ServiceAntiAffinityPriority")
 PASSTHROUGH_PRIORITIES = ()
 
-# lax.scan unroll for the sequential solve: unrolling amortizes loop
-# control and xs slicing over several steps; compile time grows with the
+# Steps per iteration of the sequential solve's loop: running several
+# amortizes loop control and xs slicing; compile time grows with the
 # factor.
 SCAN_UNROLL = 4
 # Cap on distinct nonzero-request templates factored out of the scan.
 DYN_TEMPLATE_CAP = knobs.get_int("KT_DYN_TEMPLATES")
+
+
+def scan_unroll(p: int) -> int:
+    """Steps one iteration of the scan's loop runs over a ``p``-row batch:
+    ``SCAN_UNROLL`` where it divides ``p`` (every ladder bucket), else 1."""
+    return SCAN_UNROLL if p % SCAN_UNROLL == 0 else 1
+
+
+def scan_steps(live: np.ndarray | None, p: int) -> int:
+    """Steps ``run_live_steps`` runs over a ``p``-row batch with this live
+    mask — the host's mirror of the bound the device reads, for the
+    account (``scheduler_scan_steps_total``): the last live row + 1,
+    rounded up to whole iterations; every row where there is no mask."""
+    if live is None:
+        return p
+    rows = np.flatnonzero(live)
+    unroll = scan_unroll(p)
+    return (-(-(int(rows[-1]) + 1) // unroll) * unroll) if rows.size else 0
+
+
+def run_live_steps(step: Any, init: dict, xs: dict, p: int,
+                   live: jnp.ndarray | None
+                   ) -> tuple[dict, jnp.ndarray]:
+    """``lax.scan(step, init, xs)`` over the rows that can place a pod:
+    the loop stops after the last live row, its bound read on the device
+    from the mask the launch already carries, so a launch of 30 pods in
+    a 256-row bucket runs 32 steps.  Rows past the bound are never
+    stepped — a dead row returns the state it got and chooses -1
+    (``combine.select_host`` on an all-infeasible row), which is what
+    the preallocated output holds — and dead rows before it (a mask
+    with a hole, the round-up to whole iterations) run as the inert
+    steps they are, so choices, counter and final state are bit-equal
+    to the full-length scan's for every mask.  ``live=None`` steps all
+    ``p`` rows through the same loop.  Returns (final state, choices
+    [p] int32)."""
+    unroll = scan_unroll(p)
+    if live is None:
+        n_rows = jnp.int32(p)
+    else:
+        n_rows = jnp.max(jnp.where(
+            live, jnp.arange(1, p + 1, dtype=jnp.int32), 0), initial=0)
+    n_iters = (n_rows + (unroll - 1)) // unroll
+    # The body is lax.scan's own for unroll steps an iteration: every xs
+    # leaf as [iterations, unroll, ...] indexed on its leading axis (one
+    # aligned block an iteration, no negative-index fix-up), the block's
+    # steps a fully unrolled lax.scan (no inner loop), the choices written
+    # back a block at a time.  lax.scan evaluates step as one closed jaxpr
+    # a step, which XLA fuses as it did under the fixed-length scan; with
+    # step traced inline four times a filled bucket cost 7-60 % more
+    # (PERF.md section 6, PR 37).
+    blocks = jax.tree_util.tree_map(
+        lambda x: x.reshape(p // unroll, unroll, *x.shape[1:]), xs)
+
+    def body(val):
+        i, state, choices = val
+        block = jax.tree_util.tree_map(
+            lambda x: jax.lax.dynamic_index_in_dim(
+                x, i, keepdims=False, allow_negative_indices=False), blocks)
+        state, picked = jax.lax.scan(step, state, block, unroll=True)
+        return i + 1, state, jax.lax.dynamic_update_index_in_dim(
+            choices, picked, i, 0, allow_negative_indices=False)
+
+    _, final, choices = jax.lax.while_loop(
+        lambda val: val[0] < n_iters, body,
+        (jnp.int32(0), init,
+         jnp.full((p // unroll, unroll), -1, jnp.int32)))
+    return final, choices.reshape(p)
 
 
 class DeviceAffinity(NamedTuple):
@@ -1330,6 +1399,14 @@ class Solver:
         planes in its carrier; pass None for what rides there.
         Returns (choices [P], counter, final state dict).
 
+        The loop's trip count follows the launch's live rows
+        (``run_live_steps``): it stops after the last row ``live`` marks,
+        read on the device from the mask the launch already carries —
+        a 30-pod launch in the 256-row bucket runs 32 steps — and rows
+        it never steps read -1, as a dead row's step would have chosen;
+        ``live=None`` steps every row.  The programs keep their shapes:
+        one per (bucket, flags, carry) as before.
+
         The step is built for per-step cost:
 
         * the hoisted mask/score planes merge into ONE encoded plane
@@ -1361,7 +1438,9 @@ class Solver:
                 static_mask &= _predicate_mask(name, b, c, n, self.extra)
         if live is not None:
             # Chunk padding: dead rows are infeasible everywhere, place
-            # nothing, and bump no counter (hoisted — zero per-step cost).
+            # nothing, and bump no counter (hoisted).  The loop never
+            # reaches those past the last live row; this keeps the ones
+            # before it (a hole, the round-up to whole iterations) inert.
             static_mask &= live[:, None]
         if extra_mask is not None:
             # Workload-constraint hard plane (batch-start topology spread):
@@ -1681,7 +1760,7 @@ class Solver:
             xs["pd_extra_gce"] = b.volsvc.pd_extra_gce
         if carry is not None:
             init.update({k: v for k, v in carry.items() if k in init})
-        final, choices = jax.lax.scan(step, init, xs, unroll=SCAN_UNROLL)
+        final, choices = run_live_steps(step, init, xs, p, live)
         return choices, final["counter"], final
 
     # Dynamic priorities whose pod-dependence is ONLY the nonzero-request

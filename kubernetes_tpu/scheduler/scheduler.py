@@ -346,10 +346,14 @@ class Scheduler:
     # instead of one per queue length.
     _PAD_LIMIT = 4096
 
-    # Floor on the small-drain bucket: pad rows are numerically inert, so
-    # padding a 3-pod drain to 256 costs dead scan rows (microseconds),
-    # while every distinct bucket below the floor costs an XLA compile
-    # (seconds).  Measured on the 500-node kubemark rig: the arrival race
+    # Floor on the small-drain bucket: pad rows are numerically inert and,
+    # since PR 37, never stepped — the scan's loop stops at its last live
+    # row (engine/solver.py run_live_steps), so padding a 3-pod drain to
+    # 256 costs the hoisted planes' 256 rows and 4 steps, not 256 (until
+    # then a dead row cost a full step: 17-46 us on 5,000 nodes, ~5-9 ms
+    # a launch) — while every distinct bucket below the floor costs an
+    # XLA compile (seconds).  Measured on the 500-node kubemark rig: the
+    # arrival race
     # produces drains of 1..700 pods, and the 1,2,4,...,128 ladder minted
     # ~8 scan compiles (~4-8 s each on a small host) before the fleet
     # settled; with the floor the ladder is {256, 512, 1024, 2048}.

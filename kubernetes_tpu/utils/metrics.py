@@ -624,6 +624,17 @@ FEATURE_PLAN = register(Counter(
     "volume / service tables are built per launch).  A steady window "
     "reads hits only",
     labelnames=("result", "cause")))
+SCAN_STEPS = register(Counter(
+    "scheduler_scan_steps_total",
+    "Steps of the sequential scan by kind, one increment of each a "
+    "dispatch (streamed chunk or one-shot launch; the joint solver's "
+    "repair scan orders its rows on the device and is not counted): "
+    "kind=bucket the rows of the batch dispatched, kind=run the steps "
+    "its loop runs — the last live row + 1 rounded up to whole "
+    "iterations (engine/solver.py scan_steps, the host's mirror of the "
+    "bound the device reads from the live mask; no sync).  run / bucket "
+    "is the share of a bucket's steps a launch pays for",
+    labelnames=("kind",)))
 DEVICE_HBM_LIVE_BYTES = register(Gauge(
     "scheduler_device_hbm_live_bytes",
     "Device memory held by live arrays (device.memory_stats when the "

@@ -141,6 +141,141 @@ def test_chunked_carry_matches_oneshot(chunk):
     assert np.array_equal(np.concatenate(outs), one)
 
 
+# -- the loop's bound follows the live rows (PR 37) ----------------------
+
+def _full_length(step, init, xs, p, live):
+    """The reference: the fixed-length scan the program ran before the
+    loop's bound followed the live rows — every row of the bucket is
+    stepped, dead or not."""
+    return jax.lax.scan(step, init, xs, unroll=sv.SCAN_UNROLL)
+
+
+def _reference_scan(self, *args):
+    """``Solver._solve_scan``'s own trace — the same hoisted planes, the
+    same ``step``, the same init and xs — under the full-length
+    reference loop."""
+    mine = sv.run_live_steps
+    sv.run_live_steps = _full_length
+    try:
+        return sv.Solver._solve_scan.__wrapped__(self, *args)
+    finally:
+        sv.run_live_steps = mine
+
+
+_reference_scan = jax.jit(_reference_scan, static_argnums=(0, 5))
+
+
+def _prefix(p: int, live: int) -> np.ndarray:
+    mask = np.zeros(p, bool)
+    mask[:live] = True
+    return mask
+
+
+def _hole(p: int) -> np.ndarray:
+    """live, dead, live: the bound is the LAST live row, the dead rows
+    before it run as the inert steps they are."""
+    mask = np.zeros(p, bool)
+    mask[:9] = True
+    mask[40:53] = True
+    return mask
+
+
+def _bits(x) -> tuple:
+    x = np.asarray(x)
+    return x.dtype, x.shape, x.tobytes()
+
+
+# (profile, rows of the batch, live mask or None, chunk or None, family)
+LOOP_CASES = {
+    **{f"prefix-{k}-of-256": ("mixed", 256, _prefix(256, k), None, None)
+       for k in (0, 1, 3, 4, 5, 30, 256)},
+    "hole-live-dead-live": ("mixed", 256, _hole(256), None, None),
+    "live-none": ("mixed", 256, None, None, None),
+    "rows-not-a-multiple-of-the-unroll": (
+        "mixed", 70, _prefix(70, 33), None, None),
+    "rows-not-a-multiple-live-none": ("mixed", 70, None, None, None),
+    "two-chunks-second-mostly-padding": (
+        "mixed", 128, _prefix(128, 70), 64, None),
+    "affinity-families-in-the-carry": (
+        "rich", 96, _prefix(96, 41), None, "track_affinity"),
+    "spread-families-in-the-carry": (
+        "mixed", 96, _prefix(96, 41), None, "track_spread"),
+}
+
+
+@pytest.mark.parametrize("case", list(LOOP_CASES))
+def test_live_bounded_loop_is_bit_equal_to_the_full_length_scan(case):
+    """For every live mask the loop that stops at its last live row
+    returns what the full-length scan of the same ``step`` returns:
+    choices, the tie counter and every key of the final state bit for
+    bit; dead rows read -1."""
+    profile, p, live, chunk, family = LOOP_CASES[case]
+    eng = _rig(profile)
+    pods = synth.make_pods(p, profile=profile, n_services=4)
+    batch, _db, dc, _nt = eng._compile(pods)
+    flags = sv.batch_flags(batch)
+    if family is not None:
+        assert getattr(eng.solver._scan_families(flags), family)
+    hb = sv.host_batch(batch)
+    chunk = chunk or p
+    counters = {"new": jnp.uint32(COUNTER), "ref": jnp.uint32(COUNTER)}
+    carries = {"new": None, "ref": None}
+    placed = 0
+    for start in range(0, p, chunk):
+        db_k = jax.device_put(sv.slice_pod_axis(hb, start, start + chunk))
+        live_k = None if live is None else live[start:start + chunk]
+        out = {}
+        # the reference first: the program's own call donates its carry
+        for side, scan in (("ref", _reference_scan),
+                           ("new", sv.Solver._solve_scan)):
+            ch, counters[side], carries[side] = scan(
+                eng.solver, db_k, dc, counters[side], None, flags,
+                carries[side],
+                None if live_k is None else jnp.asarray(live_k), None)
+            out[side] = (np.asarray(ch), int(counters[side]),
+                         {k: _bits(v) for k, v in carries[side].items()})
+        assert out["new"][0].dtype == np.int32
+        assert np.array_equal(out["new"][0], out["ref"][0])
+        assert out["new"][1] == out["ref"][1]
+        assert out["new"][2].keys() == out["ref"][2].keys()
+        for key in out["ref"][2]:
+            assert out["new"][2][key] == out["ref"][2][key], key
+        if live_k is not None:
+            assert (out["new"][0][~live_k] == -1).all()
+        placed += int((out["new"][0] >= 0).sum())
+    assert placed > 0 or (live is not None and not live.any())
+
+
+@pytest.mark.parametrize("p,live", [
+    (256, _prefix(256, 0)), (256, _prefix(256, 1)), (256, _prefix(256, 4)),
+    (256, _prefix(256, 5)), (256, _prefix(256, 30)),
+    (256, _prefix(256, 256)), (256, _hole(256)), (256, None),
+    (70, _prefix(70, 33)), (70, None)],
+    ids=["prefix-0", "prefix-1", "prefix-4", "prefix-5", "prefix-30",
+         "full", "hole", "live-none", "odd-rows", "odd-rows-live-none"])
+def test_loop_steps_the_rows_to_the_last_live_one_and_no_more(p, live):
+    """``run_live_steps`` over a step that counts itself: the steps run
+    are the last live row + 1 rounded up to whole iterations (4 where 4
+    divides the rows, else 1) — what ``scan_steps`` tells the account —
+    and the rows never stepped read -1."""
+    def step(state, xs):
+        return {"steps": state["steps"] + 1}, xs["row"]
+
+    final, choices = jax.jit(
+        lambda live: sv.run_live_steps(
+            step, {"steps": jnp.int32(0)},
+            {"row": jnp.arange(p, dtype=jnp.int32)}, p, live))(
+        None if live is None else jnp.asarray(live))
+    last = p if live is None else \
+        (int(np.flatnonzero(live)[-1]) + 1 if live.any() else 0)
+    unroll = 4 if p % 4 == 0 else 1
+    ran = -(-last // unroll) * unroll
+    assert int(final["steps"]) == ran == sv.scan_steps(live, p)
+    assert np.array_equal(
+        np.asarray(choices),
+        np.where(np.arange(p) < ran, np.arange(p), -1))
+
+
 def test_scan_matches_host_engine_drain():
     """The NumPy fallback engine and the device drain assign the same
     nodes for the same queue (the guard's breaker swap must not move

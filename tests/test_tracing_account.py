@@ -237,6 +237,33 @@ def test_pod_wait_is_counted_once_per_pod_at_the_hand_off():
     assert metrics.POD_QUEUE_WAIT_MAX.value >= 2.0
 
 
+def _scan_steps(kind: str) -> float:
+    child = metrics.SCAN_STEPS.children().get((kind,))
+    return child.value if child is not None else 0
+
+
+@pytest.mark.parametrize("pods,chunk,run,bucket", [
+    (5, 64, 8, 64),             # rows 0-4 live: 5 -> 8, one dispatch
+    (70, 64, 64 + 8, 2 * 64),   # a full chunk, then 6 live rows of 64
+    (64, 64, 64, 64)],          # a bucket filled: the full-length loop
+    ids=["few-pods", "two-chunks", "filled"])
+def test_scan_steps_count_the_loop_and_the_bucket(pods, chunk, run, bucket):
+    """A streamed launch through the engine: ``kind=bucket`` grows by the
+    bucket's rows a dispatch, ``kind=run`` by the last live row + 1
+    rounded up to 4 — under the bucket unless the launch fills it."""
+    from kubernetes_tpu.engine.generic_scheduler import GenericScheduler
+    eng = GenericScheduler()
+    for i in range(8):
+        eng.cache.add_node(make_node(f"sn{i}"))
+    before = {kind: _scan_steps(kind) for kind in ("run", "bucket")}
+    chunks = list(eng.schedule_batch_stream(
+        [make_pod(f"sp{i}") for i in range(pods)], chunk_size=chunk))
+    assert sum(len(placed) for _pods, placed in chunks) == pods
+    assert _scan_steps("bucket") - before["bucket"] == bucket
+    assert _scan_steps("run") - before["run"] == run
+    assert (run < bucket) == (pods % chunk != 0)
+
+
 class _CountingAnnotation:
     built = 0
 
