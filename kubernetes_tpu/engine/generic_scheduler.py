@@ -584,22 +584,24 @@ class GenericScheduler:
         real_p = len(pods)
         live_np = None
         if pad_to > real_p:
-            pods = fb.pad_pods(pods, pad_to)
-            live_np = np.zeros(len(pods), bool)
-            live_np[:real_p] = True
+            with stage("pad", pods=real_p):
+                pods = fb.pad_pods(pods, pad_to)
+                live_np = np.zeros(len(pods), bool)
+                live_np[:real_p] = True
         # The tie counter and the live mask ride db's carrier: the solve
         # calls below pass None for both.
         with self.guard.watch("oneshot" if not joint else "joint",
                               inject=False):
             batch, db, dc, nt = self._compile(pods, live=live_np)
-        flags = self._pinned_flags(batch)
-        if not joint:
-            self._count_scored(flags, real_p)
-        extra_mask = score_bias = None
-        if self._topo_terms is not None:
-            from kubernetes_tpu.engine.workloads import topology
-            extra_mask, score_bias = topology.spread_planes(
-                self._topo_terms, dc.topo_dom)
+        with stage("scan_inputs"):
+            flags = self._pinned_flags(batch)
+            if not joint:
+                self._count_scored(flags, real_p)
+            extra_mask = score_bias = None
+            if self._topo_terms is not None:
+                from kubernetes_tpu.engine.workloads import topology
+                extra_mask, score_bias = topology.spread_planes(
+                    self._topo_terms, dc.topo_dom)
         if log.isEnabledFor(10):
             log.debug("schedule_batch: %d pods (%d templates) x %d nodes, "
                       "joint=%s flags=%s", len(pods),
@@ -714,33 +716,36 @@ class GenericScheduler:
                     for pod in pods}
         padded = fb.pad_pods(pods, self.EXPLAIN_CAP)
         batch, db, dc, nt = self._compile(padded)
-        masks = {name: np.asarray(m) for name, m in
-                 self.solver.masks(db, dc).items()}
-        _, scores = self.solver.evaluate(db, dc, sv.batch_flags(batch))
-        # a free row of the node axis is no node: never among the top
-        scores = np.where([name is not None for name in nt.names],
-                          np.asarray(scores), -np.inf)
-        sched = np.asarray(nt.schedulable, dtype=bool)
-        n_sched = int(sched.sum())
-        out: dict = {}
-        for i, pod in enumerate(pods):
-            counts = {}
-            for name, m in masks.items():
-                failing = int(np.count_nonzero(sched & ~m[i]))
-                if failing:
-                    counts[name] = failing
-            top_idx = np.argsort(-scores[i])[:5]
-            out[pod.key] = {
-                "message": f"pod ({pod.name}) failed to fit in any node"
-                if counts else
-                f"pod ({pod.name}) fit no node in this batch (in-batch "
-                f"contention; predicates pass against the current "
-                f"snapshot)",
-                "nodes_considered": n_sched,
-                "failed_predicates": counts,
-                "top_scores": [{"node": nt.names[int(j)],
-                                "score": float(scores[i][int(j)])}
-                               for j in top_idx]}
+        # The feature build above counts as the launch's own stages; the
+        # evaluation and the walk over its masks are stage ``explain``.
+        with stage("explain", pods=len(pods)):
+            masks = {name: np.asarray(m) for name, m in
+                     self.solver.masks(db, dc).items()}
+            _, scores = self.solver.evaluate(db, dc, sv.batch_flags(batch))
+            # a free row of the node axis is no node: never among the top
+            scores = np.where([name is not None for name in nt.names],
+                              np.asarray(scores), -np.inf)
+            sched = np.asarray(nt.schedulable, dtype=bool)
+            n_sched = int(sched.sum())
+            out: dict = {}
+            for i, pod in enumerate(pods):
+                counts = {}
+                for name, m in masks.items():
+                    failing = int(np.count_nonzero(sched & ~m[i]))
+                    if failing:
+                        counts[name] = failing
+                top_idx = np.argsort(-scores[i])[:5]
+                out[pod.key] = {
+                    "message": f"pod ({pod.name}) failed to fit in any node"
+                    if counts else
+                    f"pod ({pod.name}) fit no node in this batch (in-batch "
+                    f"contention; predicates pass against the current "
+                    f"snapshot)",
+                    "nodes_considered": n_sched,
+                    "failed_predicates": counts,
+                    "top_scores": [{"node": nt.names[int(j)],
+                                    "score": float(scores[i][int(j)])}
+                                   for j in top_idx]}
         return out
 
     # Preemption decisions computed per drain: the masks pass pads to
@@ -774,36 +779,40 @@ class GenericScheduler:
             return []
         padded = fb.pad_pods(pods, self.PREEMPT_CAP)
         batch, db, dc, nt = self._compile(padded)
-        # Non-resource predicate rows: victims free resources, nothing
-        # else — a node that only becomes selector/taint-feasible after
-        # eviction is never nominated (conservative).
-        masks = {name: np.asarray(m) for name, m in
-                 self.solver.masks(db, dc).items()}
-        base = np.broadcast_to(np.asarray(nt.schedulable, bool),
-                               (len(padded), nt.alloc.shape[0])).copy()
-        for name, m in masks.items():
-            if name not in ("PodFitsResources",):
-                base &= m
-        if self._topo_terms is not None:
-            from kubernetes_tpu.engine.workloads import topology
-            tmask, _ = topology.spread_planes(self._topo_terms,
-                                              dc.topo_dom)
-            if tmask is not None:
-                base &= np.asarray(tmask)
-        with self.cache.lock:
-            _, agg, _, _ = self.cache.snapshot()
-            vt = self.cache.victim_table(pre.MAX_VICTIMS,
-                                         exclude=protected)
-            requested = agg.requested.copy()
-        alloc = nt.alloc
-        vic_req, vic_prio, vic_valid = (vt.req.copy(), vt.prio.copy(),
-                                        vt.valid.copy())
-        vic_keys = [list(k) for k in vt.keys]
-        decisions = []
-        with devicestats.live_path("victim"), self.guard.watch("victim"):
-            self._find_preemptions_inner(
-                pods, alloc, requested, base, vic_req, vic_prio,
-                vic_valid, vic_keys, nt, decisions)
+        # As explain_failures: the feature build counts as the launch's
+        # own stages, the search after it is stage ``victims``.
+        with stage("victims", pods=len(pods)):
+            # Non-resource predicate rows: victims free resources,
+            # nothing else — a node that only becomes selector/taint-
+            # feasible after eviction is never nominated (conservative).
+            masks = {name: np.asarray(m) for name, m in
+                     self.solver.masks(db, dc).items()}
+            base = np.broadcast_to(np.asarray(nt.schedulable, bool),
+                                   (len(padded), nt.alloc.shape[0])).copy()
+            for name, m in masks.items():
+                if name not in ("PodFitsResources",):
+                    base &= m
+            if self._topo_terms is not None:
+                from kubernetes_tpu.engine.workloads import topology
+                tmask, _ = topology.spread_planes(self._topo_terms,
+                                                  dc.topo_dom)
+                if tmask is not None:
+                    base &= np.asarray(tmask)
+            with self.cache.lock:
+                _, agg, _, _ = self.cache.snapshot()
+                vt = self.cache.victim_table(pre.MAX_VICTIMS,
+                                             exclude=protected)
+                requested = agg.requested.copy()
+            alloc = nt.alloc
+            vic_req, vic_prio, vic_valid = (vt.req.copy(), vt.prio.copy(),
+                                            vt.valid.copy())
+            vic_keys = [list(k) for k in vt.keys]
+            decisions = []
+            with devicestats.live_path("victim"), \
+                    self.guard.watch("victim"):
+                self._find_preemptions_inner(
+                    pods, alloc, requested, base, vic_req, vic_prio,
+                    vic_valid, vic_keys, nt, decisions)
         return decisions
 
     def _find_preemptions_inner(self, pods, alloc, requested, base,
@@ -877,28 +886,31 @@ class GenericScheduler:
             return
         n_chunks = (p + chunk_size - 1) // chunk_size
         padded = n_chunks * chunk_size
-        all_pods = fb.pad_pods(pods, padded)
+        with stage("pad", pods=p):
+            all_pods = fb.pad_pods(pods, padded)
         with self.guard.watch("stream", inject=False):
             batch, hb, dc, nt = self._compile(all_pods, device=False)
-        flags = self._pinned_flags(batch)
-        self._count_scored(flags, p)
-        # Spread-constraint planes, host-resident like the batch: each
-        # chunk's fixed-shape row slice rides the chunk's packed carrier
-        # (pad rows carry no constraints, so their mask rows are
-        # all-pass).
-        topo_mask_np = topo_score_np = None
-        if self._topo_terms is not None:
-            from kubernetes_tpu.engine.workloads import topology
-            tmask, tscore = topology.spread_planes(self._topo_terms,
-                                                   dc.topo_dom)
-            topo_mask_np = None if tmask is None else np.asarray(tmask)
-            topo_score_np = None if tscore is None else np.asarray(tscore)
-        n = sv.cluster_nodes(dc)
+        with stage("scan_inputs"):
+            flags = self._pinned_flags(batch)
+            self._count_scored(flags, p)
+            # Spread-constraint planes, host-resident like the batch:
+            # each chunk's fixed-shape row slice rides the chunk's packed
+            # carrier (pad rows carry no constraints, so their mask rows
+            # are all-pass).
+            topo_mask_np = topo_score_np = None
+            if self._topo_terms is not None:
+                from kubernetes_tpu.engine.workloads import topology
+                tmask, tscore = topology.spread_planes(self._topo_terms,
+                                                       dc.topo_dom)
+                topo_mask_np = None if tmask is None else np.asarray(tmask)
+                topo_score_np = None if tscore is None \
+                    else np.asarray(tscore)
+            n = sv.cluster_nodes(dc)
+            live_np = np.zeros(padded, bool)
+            live_np[:p] = True
         # The first chunk's tie counter rides its packed batch; from the
         # second on it is the device scalar the previous scan returned.
         counter = carry = None
-        live_np = np.zeros(padded, bool)
-        live_np[:p] = True
         pending: list[tuple[int, jnp.ndarray]] = []
 
         def emit(start: int, choices) -> tuple[list, list]:
@@ -968,7 +980,11 @@ class GenericScheduler:
                        functools.partial(emit, start, choices_k))
             else:
                 yield emit(start, choices_k)
-        self.last_node_index = np.uint32(counter)
+        # A read of the scan's device scalar: on the overlapped drain it
+        # waits beside the commit worker's readback, off the launch's
+        # critical path (a ``commit.`` stage).
+        with stage("commit.counter"):
+            self.last_node_index = np.uint32(counter)
 
     def _schedule_batch_via_extenders(self, pods: list[api.Pod]
                                       ) -> list[str | None]:

@@ -43,7 +43,6 @@ from kubernetes_tpu.scheduler.batchformer import (BatchFormer, FormedBatch,
 from kubernetes_tpu.utils import metrics as metrics_mod
 from kubernetes_tpu.utils import trace as trace_mod
 from kubernetes_tpu.utils.logging import get_logger
-from kubernetes_tpu.utils.trace import Trace
 
 log = get_logger("pipeline")
 
@@ -143,14 +142,11 @@ class DrainPipeline:
                                pods=len(pods))
         daemon.config.metrics.batch_size.set(len(pods))
         _count_pod_waits(pods)
-        tr = Trace(f"Scheduling batch of {len(pods)} pods")
-        tr.start = batch.t_wait
-        tr.step("Queue drained")
         try:
             # One launch is one host interval of a profiler's trace (the
             # root span is backdated, so it cannot be one itself).
             with trace_mod.annotation("launch", pods=len(pods)):
-                return self._solve(batch, tr=tr, trace_id=root.trace_id)
+                return self._solve(batch, trace_id=root.trace_id)
         except Exception:  # noqa: BLE001 — HandleCrash analogue
             # The pods were already popped: requeue each through the
             # backoff path (condition + event + delayed retry) so a
@@ -184,15 +180,10 @@ class DrainPipeline:
                 "launch_total",
                 (time.perf_counter() - batch.t_wait) * 1e6,
                 root.trace_id or None)
-            # The reference's 20 ms slow-log (generic_scheduler.go:79-85),
-            # now fed by the batched drain too; a slow batch also records
-            # as a span with the step breakdown.
-            tr.log_if_long()
 
     # -- mode routing + the device-fault recovery ladder -------------------
 
-    def _solve(self, batch: FormedBatch, tr: Optional[Trace] = None,
-               trace_id: str = "") -> int:
+    def _solve(self, batch: FormedBatch, trace_id: str = "") -> int:
         """Route the batch to a solve mode under the device guard's
         recovery ladder: a classified ``DeviceFault`` re-dispatches the
         still-uncommitted pods per the guard's decision — unchanged
@@ -205,10 +196,10 @@ class DrainPipeline:
         daemon = self.daemon
         pods = batch.pods
         if getattr(daemon, "tenancy_service", None) is not None:
-            return self._solve_tenants(pods, tr, trace_id)
+            return self._solve_tenants(pods, trace_id)
         guard = getattr(daemon.config.algorithm, "guard", None)
         if guard is None or not guard.enabled:
-            return self._dispatch(pods, tr, trace_id)
+            return self._dispatch(pods, trace_id)
         total = len(pods)
         remaining = pods
         fault: Optional[DeviceFault] = None
@@ -216,9 +207,9 @@ class DrainPipeline:
             mode = guard.solve_mode()
             try:
                 if mode == "host":
-                    self._dispatch(remaining, tr, trace_id, host=True)
+                    self._dispatch(remaining, trace_id, host=True)
                 else:
-                    self._dispatch(remaining, tr, trace_id)
+                    self._dispatch(remaining, trace_id)
                     guard.note_success(probe=(mode == "probe"))
                 return total
             except DeviceFault as f:
@@ -249,8 +240,7 @@ class DrainPipeline:
                 and p.key not in handled
                 and p.key not in daemon.queue]
 
-    def _solve_tenants(self, pods: list, tr: Optional[Trace],
-                       trace_id: str) -> int:
+    def _solve_tenants(self, pods: list, trace_id: str) -> int:
         """The multi-tenant solve path: per-tenant breaker routing,
         mixed-batch fault ATTRIBUTION by per-tenant split, and
         per-tenant accounting — one tenant's poison batch degrades that
@@ -272,11 +262,11 @@ class DrainPipeline:
         if gmode == "host":
             # Whole-device outage (global breaker open, no probe due):
             # every tenant decides on the host engine this drain.
-            self._dispatch(pods, tr, trace_id, host=True)
+            self._dispatch(pods, trace_id, host=True)
             return total
         device_pods, host_pods, probing = svc.partition(pods)
         if host_pods:
-            self._dispatch(host_pods, tr, trace_id, host=True)
+            self._dispatch(host_pods, trace_id, host=True)
             for t, n in svc.count_tenants(host_pods).items():
                 svc.note_host_fallback(t, n)
         if device_pods:
@@ -285,12 +275,11 @@ class DrainPipeline:
             # must not race GenericScheduler's solve state.
             with svc.engine_lock:
                 self._solve_tenant_groups(
-                    device_pods, probing, gmode, tr, trace_id)
+                    device_pods, probing, gmode, trace_id)
         return total
 
     def _solve_tenant_groups(self, device_pods: list, probing: set,
-                             gmode: str, tr: Optional[Trace],
-                             trace_id: str) -> None:
+                             gmode: str, trace_id: str) -> None:
         """The device section of a tenant drain (caller holds the
         service's engine lock): dispatch, attribution splits, and the
         per-tenant breaker routing."""
@@ -316,7 +305,7 @@ class DrainPipeline:
             tenants_g = svc.tenants_of(group)
             try:
                 with chaos_device.tenant_context(tenants_g):
-                    self._dispatch(group, tr, trace_id)
+                    self._dispatch(group, trace_id)
                 if guard_on:
                     guard.note_success(probe=(gmode == "probe"))
                 for t in tenants_g:
@@ -348,7 +337,7 @@ class DrainPipeline:
                         f, can_bisect=self._can_bisect(remaining))
                     to_host = to_host or action == ACT_HOST
                 if to_host:
-                    self._dispatch(remaining, tr, trace_id, host=True)
+                    self._dispatch(remaining, trace_id, host=True)
                     svc.note_host_fallback(tenant, len(remaining))
                 else:
                     groups.append(remaining)
@@ -374,8 +363,8 @@ class DrainPipeline:
             return False
         return bool(daemon.effective_ladder())
 
-    def _dispatch(self, pods: list, tr: Optional[Trace] = None,
-                  trace_id: str = "", host: bool = False) -> int:
+    def _dispatch(self, pods: list, trace_id: str = "",
+                  host: bool = False) -> int:
         from kubernetes_tpu.engine.workloads import gang as gang_mod
         from kubernetes_tpu.utils.featuregate import DEFAULT_FEATURE_GATE
         daemon = self.daemon
@@ -391,8 +380,7 @@ class DrainPipeline:
             # (sequential NumPy — chunking and buckets are meaningless
             # there; gang reduction still applies to its output).
             return self._solve_oneshot(pods, joint=False, gangs=gangs,
-                                       tr=tr, trace_id=trace_id,
-                                       host=True)
+                                       trace_id=trace_id, host=True)
         # The joint solve needs the whole queue at once (prices couple
         # every pod); it supersedes the streaming split.
         streaming = DEFAULT_FEATURE_GATE.enabled("StreamingDrain") \
@@ -419,13 +407,12 @@ class DrainPipeline:
             return self._solve_stream(pods, chunk_size=bucket,
                                       trace_id=trace_id)
         return self._solve_oneshot(pods, joint=joint, gangs=gangs,
-                                   tr=tr, trace_id=trace_id)
+                                   trace_id=trace_id)
 
     # -- one-shot / joint / gang / host solve ------------------------------
 
     def _solve_oneshot(self, pods: list, joint: bool, gangs: bool,
-                       tr: Optional[Trace], trace_id: str,
-                       host: bool = False) -> int:
+                       trace_id: str, host: bool = False) -> int:
         from kubernetes_tpu.engine.workloads import gang as gang_mod
         daemon = self.daemon
         start = time.perf_counter()
@@ -459,8 +446,6 @@ class DrainPipeline:
             for _ in admitted:
                 metrics_mod.GANG_ADMISSIONS.labels(
                     result="admitted").inc()
-        if tr is not None:
-            tr.step("Computed placements")
         algo_us = (time.perf_counter() - start) * 1e6 / len(pods)
         daemon.config.metrics.scheduling_algorithm_latency.observe_many(
             algo_us, len(pods))
@@ -472,8 +457,6 @@ class DrainPipeline:
                                        time.perf_counter() - start)
         daemon._assume_and_bind_batch(pods, placements, start,
                                       failure_info=failure_info)
-        if tr is not None:
-            tr.step("Assumed and dispatched binds")
         return len(pods)
 
     # -- streamed solve with the overlapped commit worker ------------------
@@ -511,9 +494,11 @@ class DrainPipeline:
                     max_workers=1, thread_name_prefix="chunk-commit")
             sem = threading.BoundedSemaphore(window)
             ctx = trace_mod.current_context()
-            # A mutable cell: the commit worker stamps when each chunk's
-            # readback landed; the last stamp bounds algorithm latency.
-            solve_done_cell = [start]
+            # Stamps the commit worker writes: when each chunk's readback
+            # landed (the last bounds algorithm latency) and when its
+            # commit ended (the next chunk's hand-off and the drain
+            # thread's wake are timed from it).
+            stamps = [start, start]
             futures = []
             err = None
             try:
@@ -522,27 +507,39 @@ class DrainPipeline:
                             pods, chunk_size=chunk, defer_readback=True):
                     # Bounded in-flight window: block the drain thread
                     # (and with it further device launches) until an
-                    # outstanding chunk commits.
-                    sem.acquire()
-                    futures.append(self._commit_pool.submit(
-                        self._commit_chunk, resolve, start, trace_id,
-                        sem, ctx, solve_done_cell))
+                    # outstanding chunk commits — time the worker's
+                    # commit fills, so a ``commit.`` stage.  The worker
+                    # times the hand-off from here to its pick-up.
+                    t_wait = time.perf_counter()
+                    with trace_mod.annotation("commit.window_wait"):
+                        sem.acquire()
+                    t_handoff = time.perf_counter()
+                    trace_mod.record_stage("commit.window_wait",
+                                           start=t_wait, end=t_handoff)
+                    with trace_mod.annotation("handoff"):
+                        futures.append(self._commit_pool.submit(
+                            self._commit_chunk, resolve, start, trace_id,
+                            sem, ctx, stamps, t_handoff))
             finally:
                 # Join EVERY submitted commit before surfacing anything:
                 # drain()'s crash handler requeues pods not yet assumed,
                 # and a still-running commit assuming them concurrently
                 # would double-track the pod.
-                for fut in futures:
-                    try:
-                        fut.result()
-                    except Exception as exc:  # noqa: BLE001 — requeue
-                        err = err or exc
+                with trace_mod.annotation("commit.join"):
+                    for fut in futures:
+                        try:
+                            fut.result()
+                        except Exception as exc:  # noqa: BLE001 — requeue
+                            err = err or exc
+            if futures and err is None:
+                # From the last commit's end to this thread's return.
+                trace_mod.record_stage("wake", start=stamps[1])
             if err is not None:
                 # Surface the first commit failure to drain()'s crash
                 # handler, which requeues every pod the completed
                 # commits didn't assume.
                 raise err
-            solve_done = solve_done_cell[0]
+            solve_done = stamps[0]
         # Algorithm latency spans until the LAST chunk's results landed
         # (interleaved assume/bind of earlier chunks overlaps the device
         # and is deliberately excluded, matching the one-shot path).
@@ -552,21 +549,26 @@ class DrainPipeline:
         return len(pods)
 
     def _commit_chunk(self, resolve, start: float, trace_id: str, sem,
-                      trace_ctx, solve_done_cell: list) -> None:
+                      trace_ctx, stamps: list, t_handoff: float) -> None:
         """One chunk's commit on the pipeline worker: blocking readback,
         flight-recorder feed, bulk assume, bind dispatch."""
         daemon = self.daemon
         try:
             with trace_mod.use_context(trace_ctx):
+                # From the hand-off, or from this worker's end of the
+                # previous chunk where that came later: the wait for this
+                # thread, never the previous chunk's commit again.
+                trace_mod.record_stage("handoff",
+                                       start=max(t_handoff, stamps[1]))
                 chunk_pods, placements = resolve()
-                solve_done_cell[0] = time.perf_counter()
+                stamps[0] = time.perf_counter()
                 daemon._record_batch_decisions(
-                    chunk_pods, placements, trace_id,
-                    solve_done_cell[0] - start)
+                    chunk_pods, placements, trace_id, stamps[0] - start)
                 daemon._assume_and_bind_batch(chunk_pods, placements,
                                               start)
         finally:
             sem.release()
+            stamps[1] = time.perf_counter()
 
     # -- lifecycle --------------------------------------------------------
 

@@ -401,13 +401,14 @@ class Scheduler:
                     self._explain_ts = {
                         k: t for k, t in self._explain_ts.items()
                         if t > cutoff}
-        recorder.record_batch(pods, placements, trace_id=trace_id,
-                              duration_s=duration_s,
-                              failure_detail=detail,
-                              tenants=(self.tenancy_service
-                                       .count_tenants(pods)
-                                       if self.tenancy_service is not None
-                                       else None))
+        # (the explain pass above is its own stages: a feature build and
+        # ``explain``)
+        with stage("decisions", pods=len(pods)):
+            recorder.record_batch(
+                pods, placements, trace_id=trace_id,
+                duration_s=duration_s, failure_detail=detail,
+                tenants=(self.tenancy_service.count_tenants(pods)
+                         if self.tenancy_service is not None else None))
 
     def _assume_and_bind_batch(self, pods: list[api.Pod],
                                placements: list, start: float,
@@ -471,25 +472,29 @@ class Scheduler:
             placed += [(pod, dest) for pod, dest in newly
                        if pod.key not in skipped2]
             placements = filled
-        for pod, dest in zip(pods, placements):
-            if dest is None:
-                msg, result = failure_info.get(
-                    pod.key,
-                    (f"pod ({pod.name}) failed to fit in any node",
-                     "unschedulable"))
-                self._handle_failure(pod, "FailedScheduling", msg,
-                                     result=result)
+        failed = [pod for pod, dest in zip(pods, placements)
+                  if dest is None]
+        if failed:
+            with stage("failures", pods=len(failed)):
+                for pod in failed:
+                    msg, result = failure_info.get(
+                        pod.key,
+                        (f"pod ({pod.name}) failed to fit in any node",
+                         "unschedulable"))
+                    self._handle_failure(pod, "FailedScheduling", msg,
+                                         result=result)
         if self.config.async_bind:
-            t = threadreg.spawn(self._bind_assumed_batch,
-                                args=(placed, start,
-                                      trace_mod.current_context()),
-                                name="bind-batch", transient=True)
-            # Prune finished binders on append: a long-running daemon
-            # drains every ~50 ms and must not accumulate dead Thread
-            # objects without bound.
-            self._bind_threads = [x for x in self._bind_threads
-                                  if x.is_alive()]
-            self._bind_threads.append(t)
+            with stage("bind_spawn"):
+                t = threadreg.spawn(self._bind_assumed_batch,
+                                    args=(placed, start,
+                                          trace_mod.current_context()),
+                                    name="bind-batch", transient=True)
+                # Prune finished binders on append: a long-running
+                # daemon drains every ~50 ms and must not accumulate
+                # dead Thread objects without bound.
+                self._bind_threads = [x for x in self._bind_threads
+                                      if x.is_alive()]
+                self._bind_threads.append(t)
         else:
             self._bind_assumed_batch(placed, start)
 
@@ -512,21 +517,23 @@ class Scheduler:
         if not cands:
             return placements
         try:
+            # (a feature build and ``victims``: stages of their own)
             decisions = self.config.algorithm.find_preemptions(
                 cands, protected=protected)
         except Exception:  # noqa: BLE001 — preemption is best-effort
             log.exception("preemption pass crashed; pods requeue with "
                           "backoff instead")
             decisions = []
-        executed = {}
-        for dec in decisions:
-            if self._execute_preemption(dec):
-                executed[dec.pod_key] = dec
-        decided = {d.pod_key for d in decisions}
-        for pod in cands:
-            if pod.key not in decided:
-                metrics_mod.PREEMPTIONS.labels(
-                    result="no_candidate").inc()
+        with stage("preempt", pods=len(cands)):
+            executed = {}
+            for dec in decisions:
+                if self._execute_preemption(dec):
+                    executed[dec.pod_key] = dec
+            decided = {d.pod_key for d in decisions}
+            for pod in cands:
+                if pod.key not in decided:
+                    metrics_mod.PREEMPTIONS.labels(
+                        result="no_candidate").inc()
         if not executed:
             return placements
         out = []

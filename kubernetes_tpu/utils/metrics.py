@@ -956,6 +956,17 @@ STAGE_LATENCY = register(Histogram(
     "gate/assume/bind, their parts transfer.batch|rows|scatter|full, "
     "device_wait, assume.lock_wait, and launch_total = the batch root)",
     exponential_buckets(100, 2, 18), labelnames=("stage",)))
+# The thread's CPU seconds between a trace.stage()'s same two clock reads:
+# wall minus CPU is the stage's time off the CPU.  A backdated wait
+# (record_stage) and the whole (launch_total) count none; with KT_TRACE=0
+# nothing is counted (the CPU clock is a system call).
+STAGE_CPU_SECONDS = register(Counter(
+    "scheduler_batch_stage_cpu_seconds_total",
+    "Thread CPU seconds of each trace.stage() of the batched pipeline, "
+    "read at the same two points as its wall time in "
+    "scheduler_batch_stage_latency_microseconds while tracing is on "
+    "(backdated waits count none)",
+    labelnames=("stage",)))
 
 # Apiserver request latency by verb/resource/code (the reference's
 # apiserver_request_latencies, pkg/apiserver/metrics).  Recorded by the

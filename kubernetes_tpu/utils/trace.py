@@ -16,7 +16,13 @@ full span tracer for the batched control plane:
   server records its request span under the caller's trace id.
 * ``stage(name)`` is a span *and* a labeled histogram observation
   (``scheduler_batch_stage_latency_microseconds{stage=...}``) — the hot
-  loop's named stages feed both the trace view and /metrics.
+  loop's named stages feed both the trace view and /metrics.  While
+  tracing is on, the same two clock reads also read the thread's CPU
+  clock into ``scheduler_batch_stage_cpu_seconds_total{stage=...}``: wall
+  minus CPU is the stage's time off the CPU (the interpreter's lock, a
+  blocking call, the OS).  A backdated ``record_stage`` is a wait and
+  counts none.  The CPU clock is a system call, so ``KT_TRACE=0`` reads
+  it nowhere.
 * Spans opened where they happen (``span``/``begin_span`` without a
   backdate, ``stage``) are ALSO host events ``kt.<name>`` in a live
   ``jax.profiler`` session (``jax.profiler.TraceAnnotation``): the
@@ -31,9 +37,10 @@ full span tracer for the batched control plane:
   ``KT_TRACE_SAMPLE`` (0.0-1.0) samples at trace granularity — the
   decision is made once at the root span and children follow it.
 
-``Trace`` (the original step logger) remains API-compatible and now also
-records slow traces as spans: a batch that crosses the 20 ms threshold both
-logs its step breakdown and lands in the ring with the steps as attributes.
+``Trace`` (the original step logger) remains API-compatible for the
+serial ``schedule()`` route and now also records slow traces as spans: a
+call that crosses the 20 ms threshold both logs its step breakdown and
+lands in the ring with the steps as attributes.
 """
 
 from __future__ import annotations
@@ -56,7 +63,7 @@ TRACE_THRESHOLD_S = 0.020
 # Ring capacity in spans.  A batch emits ~10 spans, so the default holds
 # the last several hundred batches; the buffer is allocated only when the
 # first span is recorded (a tracing-disabled daemon never pays for it).
-from kubernetes_tpu.utils import knobs
+from kubernetes_tpu.utils import knobs, metrics
 
 RING_CAPACITY = knobs.get_int("KT_TRACE_RING")
 
@@ -339,32 +346,55 @@ def record_server_span(name: str, traceparent_header: str,
 
 # -- hot-loop stages -------------------------------------------------------
 
+# Label children bound once a stage name: the hot path reads one dict
+# entry, never the families' label lookup.  A backdated wait or a whole
+# (``launch_total``) binds a histogram child alone, so its CPU row never
+# appears.
+_walls: dict = {}
+_cpus: dict = {}
+
+
+def _bound(children: dict, family, name: str):
+    child = children.get(name)
+    if child is None:
+        child = children[name] = family.labels(stage=name)
+    return child
+
+
 @contextlib.contextmanager
 def stage(name: str, **attrs: object) -> Iterator[object]:
-    """A named pipeline stage: a span (when tracing is on) AND an
-    observation in the per-stage labeled histogram (always — metrics are
-    the cheap, always-on layer; spans are the sampled, detailed one).
-    The span's trace id rides the observation as an OpenMetrics
-    exemplar, so a slow histogram bucket links to its trace."""
-    t0 = time.perf_counter()
+    """A named pipeline stage: an observation in the per-stage labeled
+    histogram (always — metrics are the cheap, always-on layer) and,
+    when tracing is on, a span and the thread's CPU seconds between the
+    same two clock reads (the CPU clock is a system call: the detailed
+    layer pays for it).  The span's trace id rides the observation as an
+    OpenMetrics exemplar, so a slow histogram bucket links to its
+    trace."""
+    wall = _bound(_walls, metrics.STAGE_LATENCY, name)
     if _enabled:
+        cpu = _bound(_cpus, metrics.STAGE_CPU_SECONDS, name)
+        t0 = time.perf_counter()
+        c0 = time.thread_time()
         h = begin_span(name, **attrs)
         try:
             yield h
         finally:
             h.end()
-            observe_stage(name, (time.perf_counter() - t0) * 1e6,
-                           h.trace_id or None)
+            cpu.inc(time.thread_time() - c0)
+            wall.observe((time.perf_counter() - t0) * 1e6,
+                         exemplar=h.trace_id or None)
     else:
+        t0 = time.perf_counter()
         yield _NOOP
-        observe_stage(name, (time.perf_counter() - t0) * 1e6, None)
+        wall.observe((time.perf_counter() - t0) * 1e6)
 
 
 def record_stage(name: str, start: float, end: float | None = None,
                  **attrs) -> None:
     """Record a stage whose interval was measured by the caller
     (``start``/``end`` are ``time.perf_counter()`` readings) — for stages
-    that begin before their span parent exists (queue wait)."""
+    that begin before their span parent exists (queue wait), or whose two
+    ends lie on two threads (a hand-off).  A wait: it counts no CPU."""
     end = time.perf_counter() if end is None else end
     tid = None
     if _enabled:
@@ -378,9 +408,8 @@ def observe_stage(name: str, us: float, trace_id: str | None = None
                   ) -> None:
     """One observation of the per-stage histogram with no span: for a
     whole that is already a span (``launch_total`` = the batch root)."""
-    from kubernetes_tpu.utils import metrics
-    metrics.STAGE_LATENCY.labels(stage=name).observe(us,
-                                                     exemplar=trace_id)
+    _bound(_walls, metrics.STAGE_LATENCY, name).observe(
+        us, exemplar=trace_id)
 
 
 # -- export ----------------------------------------------------------------

@@ -1,8 +1,8 @@
-"""The daemon's own tracing, where the work happens (ISSUE 27): the stage
-spans as host events of a profiler session, the launch's account, the
-cache lock's contention by role, the collector's own counters, a pod's
-wait for a launch, and the windowed trace endpoint.  Counts and
-structure only — never a timing."""
+"""The daemon's own tracing, where the work happens: the stage spans as
+host events of a profiler session, the launch's account (wall, and the
+thread's CPU at the same two clock reads), the cache lock's contention by
+role, the collector's own counters, a pod's wait for a launch, and the
+windowed trace endpoint.  Counts and structure only — never a timing."""
 
 from __future__ import annotations
 
@@ -28,11 +28,43 @@ from kubernetes_tpu.utils import metrics, threadreg, trace
 LEAVES = ("queue_wait", "lock_wait", "snapshot", "compile",
           "transfer.batch", "transfer.rows", "transfer.scatter",
           "transfer.full", "solve", "readback", "gate", "assume")
+# The stages on a launch's critical path: the drain thread up to the
+# hand-off, the commit worker from its pick-up to its end, the drain
+# thread's wake; launch.unnamed_ms_mean subtracts them
+# (benchmarks/metrics/launch.unnamed_ms_mean.json).
+SUMMED = ("queue_wait", "lock_wait", "snapshot", "compile", "transfer",
+          "pad", "scan_inputs", "solve", "handoff", "readback", "gate",
+          "explain", "decisions", "assume", "victims", "preempt",
+          "failures", "bind_spawn", "wake")
+# Backdated: a wait measured across a gap, which counts no CPU.
+WAITS = ("queue_wait", "lock_wait", "assume.lock_wait", "handoff", "wake",
+         "commit.window_wait", "launch_total")
 
 
 def _stage_sums() -> dict:
     return {key[0]: child.sum
             for key, child in metrics.STAGE_LATENCY.children().items()}
+
+
+def _stage_cpu() -> dict:
+    return {key[0]: child.value
+            for key, child in metrics.STAGE_CPU_SECONDS.children().items()}
+
+
+def _grew(after: dict, before: dict) -> dict:
+    return {name: after[name] - before.get(name, 0.0) for name in after}
+
+
+def _launches(spans: list) -> list:
+    """Per launch (a ``schedule_batch`` root span of the ring): its
+    duration, and the summed stages' spans of its trace, from both
+    threads."""
+    by_trace: dict = {}
+    for sp in spans:
+        by_trace.setdefault(sp["trace_id"], []).append(sp)
+    return [(root["dur_us"], [sp for sp in by_trace[root["trace_id"]]
+                              if sp["name"] in SUMMED])
+            for root in spans if root["name"] == "schedule_batch"]
 
 
 def _wait_bound(store, names, timeout=60.0) -> bool:
@@ -80,7 +112,8 @@ def traced_launch(tmp_path_factory):
             store.create("pods", pod_to_json(make_pod(f"warm{i}")))
         assert _wait_bound(store, [f"warm{i}" for i in range(4)])
         factory.daemon.wait_for_binds()
-        before = _stage_sums()
+        before, cpu_before = _stage_sums(), _stage_cpu()
+        trace.reset()
         jax.profiler.start_trace(profile_dir)
         try:
             # two waves: the former's wait between them begins and ends
@@ -93,15 +126,15 @@ def traced_launch(tmp_path_factory):
                 factory.daemon.wait_for_binds()
         finally:
             jax.profiler.stop_trace()
-        after = _stage_sums()
+        after, cpu_after = _stage_sums(), _stage_cpu()
     finally:
         factory.stop()
-    grew = {name: after[name] - before.get(name, 0.0) for name in after}
-    return _kt_events(profile_dir), grew
+    return (_kt_events(profile_dir), _grew(after, before),
+            _grew(cpu_after, cpu_before), trace.snapshot())
 
 
 def test_profiler_session_holds_the_launch_and_its_stages(traced_launch):
-    events, _grew = traced_launch
+    events, _grew, _cpu, _spans = traced_launch
     launches = [e for e in events if e[0] == "kt.launch"]
     assert launches, sorted({e[0] for e in events})
 
@@ -122,7 +155,7 @@ def test_profiler_session_holds_the_launch_and_its_stages(traced_launch):
 
 
 def test_leaf_stages_sum_to_no_more_than_launch_total(traced_launch):
-    _events, grew = traced_launch
+    _events, grew, _cpu, _spans = traced_launch
     assert grew.get("launch_total", 0.0) > 0.0
     for name in ("lock_wait", "snapshot", "compile", "transfer.batch",
                  "solve", "readback", "device_wait", "gate", "assume"):
@@ -135,6 +168,238 @@ def test_leaf_stages_sum_to_no_more_than_launch_total(traced_launch):
                for part in ("batch", "rows", "scatter", "full")) \
         <= grew["transfer"]
     assert grew.get("assume.lock_wait", 0.0) <= grew["assume"]
+
+
+def _inside_a_launch(events: list, name: str) -> bool:
+    """Some ``name`` event lies inside a ``kt.launch``: on the drain
+    thread, or on the commit worker while the drain waits for it."""
+    launches = [(lo, hi) for n, lo, hi in events if n == "kt.launch"]
+    return any(lo <= start and end <= hi for lo, hi in launches
+               for n, start, end in events if n == name)
+
+
+def _account_closes(grew: dict, cpu: dict, spans: list) -> None:
+    """Every summed stage's wall inside the launches' whole, in sum and
+    launch by launch (the spans of each launch's trace); every stage's
+    CPU inside its wall; no CPU row for a wait."""
+    assert grew.get("launch_total", 0.0) > 0.0
+    assert sum(grew.get(name, 0.0) for name in SUMMED) \
+        <= grew["launch_total"]
+    launches = _launches(spans)
+    assert launches
+    for whole, parts in launches:
+        assert sum(sp["dur_us"] for sp in parts) <= whole + 1.0, \
+            sorted((sp["name"], round(sp["dur_us"])) for sp in parts)
+    for name, seconds in cpu.items():
+        assert seconds <= grew[name] * 1e-6 + 1e-3, name
+    for name in WAITS:
+        assert name not in cpu, name
+
+
+def test_new_stages_are_host_events_of_the_launch(traced_launch):
+    events, _grew, _cpu, _spans = traced_launch
+    for name in ("kt.pad", "kt.scan_inputs", "kt.handoff", "kt.decisions",
+                 "kt.bind_spawn", "kt.commit.counter", "kt.commit.join"):
+        assert _inside_a_launch(events, name), f"{name} is in no kt.launch"
+
+
+def test_summed_stages_close_the_launch_account(traced_launch):
+    _events, grew, cpu, spans = traced_launch
+    for name in ("pad", "scan_inputs", "handoff", "decisions",
+                 "bind_spawn", "wake", "commit.counter"):
+        assert grew.get(name, 0.0) > 0.0, f"stage {name} was never observed"
+    _account_closes(grew, cpu, spans)
+    # the worker's stages read the CPU clock; the hand-offs read none
+    assert cpu["decisions"] > 0.0 and cpu["bind_spawn"] > 0.0
+
+
+@pytest.mark.parametrize("enabled", [True, False],
+                         ids=["KT_TRACE=1", "KT_TRACE=0"])
+def test_stage_cpu_is_read_at_the_wall_clock_reads(monkeypatch, enabled):
+    """A stage around a sleep: its wall holds the sleep, its CPU does
+    not; a backdated wait adds to the histogram and to no CPU row.  With
+    tracing off the CPU clock is read nowhere."""
+    monkeypatch.setattr(trace, "_enabled", enabled)
+    wall0, cpu0 = _stage_sums(), _stage_cpu()
+    with trace.stage("test.sleep"):
+        time.sleep(0.05)
+    t0 = time.perf_counter()
+    time.sleep(0.01)
+    trace.record_stage("test.wait", start=t0)
+    wall, cpu = _grew(_stage_sums(), wall0), _grew(_stage_cpu(), cpu0)
+    assert wall["test.sleep"] >= 50e3
+    if enabled:
+        assert 0.0 <= cpu["test.sleep"] < 5e-3
+    else:
+        assert cpu.get("test.sleep", 0.0) == 0.0
+    assert wall["test.wait"] >= 10e3
+    assert "test.wait" not in _stage_cpu()
+
+
+@pytest.fixture(scope="module")
+def traced_chunks():
+    """Launches of the real daemon loop streamed in chunks of 4 (the
+    in-flight window of 2 lets the drain thread hand chunk 2 over while
+    the worker still commits chunk 1), until one launch ran two chunks:
+    its spans, and what the launches added to each stage."""
+    from kubernetes_tpu.apiserver.memstore import MemStore
+    from kubernetes_tpu.scheduler.factory import ConfigFactory
+    store = MemStore()
+    for i in range(8):
+        store.create("nodes", node_to_json(make_node(f"cn{i}")))
+    factory = ConfigFactory(store)
+    daemon = factory.daemon
+    daemon.STREAM_THRESHOLD = 4
+    daemon.stream_chunk = 4
+    daemon.pipeline_window = 2
+    factory.run()
+    try:
+        warm = [f"cwarm{i}" for i in range(8)]
+        for name in warm:
+            store.create("pods", pod_to_json(make_pod(name)))
+        assert _wait_bound(store, warm)
+        daemon.wait_for_binds()
+        before, cpu_before = _stage_sums(), _stage_cpu()
+        trace.reset()
+        for wave in range(20):
+            names = [f"chunk{wave}-{i}" for i in range(8)]
+            for name in names:
+                store.create("pods", pod_to_json(make_pod(name, cpu="10m")))
+            assert _wait_bound(store, names)
+            daemon.wait_for_binds()
+            spans = trace.snapshot()
+            if any(_chunks(parts) >= 2 for _whole, parts in
+                   _launches(spans)):
+                break
+        after, cpu_after = _stage_sums(), _stage_cpu()
+    finally:
+        factory.stop()
+    return _grew(after, before), _grew(cpu_after, cpu_before), spans
+
+
+def _chunks(parts: list) -> int:
+    return sum(sp["name"] == "handoff" for sp in parts)
+
+
+def test_a_chunk_hand_off_waits_for_the_worker_not_its_last_commit(
+        traced_chunks):
+    """In a launch of two chunks the worker's summed stages follow each
+    other: chunk 2's hand-off starts where chunk 1's commit ended (or
+    later), never before it, so no commit is counted twice; each thread's
+    summed stages stay inside the launch; the wait for the in-flight
+    window is ``commit.window_wait``, summed nowhere, counting no CPU."""
+    grew, cpu, spans = traced_chunks
+    two = [(whole, parts) for whole, parts in _launches(spans)
+           if _chunks(parts) >= 2]
+    assert two, "no launch ran two chunks"
+    for whole, parts in two:
+        handoffs = [sp for sp in parts if sp["name"] == "handoff"]
+        worker = handoffs[0]["thread"]
+        assert {sp["thread"] for sp in handoffs} == {worker}
+        mine = sorted((sp for sp in parts if sp["thread"] == worker),
+                      key=lambda sp: sp["ts_us"])
+        assert any(sp["name"] == "decisions" for sp in mine)
+        for prev, nxt in zip(mine, mine[1:]):
+            assert nxt["ts_us"] >= prev["ts_us"] + prev["dur_us"] - 1.0, \
+                (prev["name"], nxt["name"])
+        by_thread: dict = {}
+        for sp in parts:
+            by_thread[sp["thread"]] = \
+                by_thread.get(sp["thread"], 0.0) + sp["dur_us"]
+        for total in by_thread.values():
+            assert total <= whole + 1.0
+    assert grew.get("commit.window_wait", 0.0) >= 0.0
+    assert "commit.window_wait" in grew
+    assert "commit.window_wait" not in cpu
+
+
+@pytest.fixture(scope="module")
+def traced_failure(tmp_path_factory):
+    """A streamed launch whose one pod fits no node, inside a profiler
+    session: a priority pod asking for more CPU than a node has, so the
+    commit worker explains it, searches victims, and requeues it (a
+    first such pod, outside the session, compiles the two passes)."""
+    import jax
+    import logging
+    from kubernetes_tpu.apiserver.memstore import MemStore
+    from kubernetes_tpu.scheduler.factory import ConfigFactory
+    profile_dir = str(tmp_path_factory.mktemp("profile-failure"))
+    store = MemStore()
+    for i in range(8):
+        store.create("nodes", node_to_json(make_node(f"fn{i}")))
+    errors: list = []
+
+    class Errors(logging.Handler):
+        def emit(self, record):
+            errors.append(record.getMessage())
+
+    handler = Errors(level=logging.ERROR)
+    logging.getLogger("kubernetes_tpu").addHandler(handler)
+    factory = ConfigFactory(store).run()
+    daemon = factory.daemon
+
+    def requeued(key: str) -> bool:
+        with daemon._requeue_cv:
+            if any(p.key == key for _, _, p in daemon._requeue_heap):
+                return True
+        return key in daemon.queue
+
+    def fail_one(name: str) -> None:
+        pod = make_pod(name, cpu="100")
+        pod.priority = 100
+        store.create("pods", pod_to_json(pod))
+        deadline = time.time() + 120.0
+        while not requeued(f"default/{name}"):
+            assert time.time() < deadline, f"{name} was never requeued"
+            time.sleep(0.02)
+        daemon.wait_for_binds()
+
+    try:
+        store.create("pods", pod_to_json(make_pod("fits")))
+        assert _wait_bound(store, ["fits"])
+        fail_one("too-big-warm")
+        before, cpu_before = _stage_sums(), _stage_cpu()
+        trace.reset()
+        jax.profiler.start_trace(profile_dir)
+        try:
+            fail_one("too-big")
+            # the launch that explained the pod, once its drain returned
+            # (the ring keeps the last few hundred launches: the pods
+            # that fit nowhere are tried again and again)
+            deadline = time.time() + 30.0
+            while True:
+                spans = trace.snapshot()
+                if any(any(sp["name"] == "explain" for sp in parts)
+                       for _whole, parts in _launches(spans)):
+                    break
+                assert time.time() < deadline, "no launch explained it"
+                time.sleep(0.01)
+        finally:
+            jax.profiler.stop_trace()
+        after, cpu_after = _stage_sums(), _stage_cpu()
+        explained = daemon.config.flight_recorder.explain("default/too-big")
+    finally:
+        factory.stop()
+        logging.getLogger("kubernetes_tpu").removeHandler(handler)
+    return (_kt_events(profile_dir), _grew(after, before),
+            _grew(cpu_after, cpu_before), spans, explained, errors)
+
+
+def test_failure_path_is_traced_and_its_account_closes(traced_failure):
+    events, grew, cpu, spans, explained, errors = traced_failure
+    # nothing raised: the explain and the victim passes log an exception
+    # they swallow, a crashed drain logs one before it requeues
+    assert errors == []
+    # the pod went back to the queue through the failure handler (the
+    # fixture waited for it there)
+    assert explained["result"] == "unschedulable"
+    for name in ("explain", "victims", "preempt", "failures", "decisions"):
+        assert grew.get(name, 0.0) > 0.0, f"stage {name} was never observed"
+        assert _inside_a_launch(events, f"kt.{name}"), name
+    # the launch that explained the pod closes its account on its own
+    assert any(any(sp["name"] == "explain" for sp in parts)
+               for _whole, parts in _launches(spans))
+    _account_closes(grew, cpu, spans)
 
 
 def _contended(role: str) -> float:
