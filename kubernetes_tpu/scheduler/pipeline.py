@@ -15,8 +15,9 @@ former) and instrumented once.
 
 The three modes that remain are SOLVE strategies, not control flows:
 
-* ``stream``  — fixed-shape chunks through ``schedule_batch_stream``,
-  with the commit worker overlapping chunk N's device scan against
+* ``stream``  — fixed-shape chunks through ``schedule_batch_stream``.
+  A drain of one chunk commits on the drain thread; a drain of two or
+  more has the commit worker overlap chunk N's device scan against
   chunk N-1's readback/assume/bind (``pipeline_window`` in flight).
 * ``oneshot`` — one ``schedule_batch`` solve; gang batches take this
   path padded to a warm bucket (all-or-nothing needs one assignment
@@ -84,7 +85,8 @@ class DrainPipeline:
             chunk_fn=daemon.stream_chunk_size,
             cap_fn=self._former_cap)
         # The overlapped commit worker (one thread: chunks commit in
-        # solve order); created lazily on the first windowed drain.
+        # solve order); created lazily on the first drain of two or
+        # more chunks.
         self._commit_pool = None
         # The device guard bisects OOM'd batches down the daemon's
         # pre-warmed bucket ladder — it must read the SAME ladder the
@@ -459,87 +461,41 @@ class DrainPipeline:
                                       failure_info=failure_info)
         return len(pods)
 
-    # -- streamed solve with the overlapped commit worker ------------------
+    # -- streamed solve: inline commit, or the overlapped commit worker ----
 
     def _solve_stream(self, pods: list, chunk_size: Optional[int] = None,
                       trace_id: str = "") -> int:
-        """The overlapped drain: while the device scans chunk N, chunk
-        N-1's readback/assume/bind runs on a single commit worker, with
-        at most ``pipeline_window`` chunks in flight uncommitted.  The
-        one worker keeps chunks committing in solve order, and within a
-        chunk assume completes before its bind fan-out dispatches — the
-        per-pod assume-before-bind ordering of the one-shot path.
-        Commits are joined before returning, so the caller-observable
-        state machine (every popped pod assumed-or-failed by return) is
-        unchanged."""
+        """The streamed drain.  A drain of one chunk (every launch of a
+        steady arrival stream) commits on this thread: the worker below
+        overlaps chunk N's scan with chunk N-1's commit, and one chunk
+        has nothing to overlap — handing it over would only add a
+        hand-off and a wake to the launch.  A drain of two or more
+        chunks commits on a single commit worker, with at most
+        ``pipeline_window`` chunks in flight uncommitted (0 = inline
+        here too).  The one worker keeps chunks committing in solve
+        order, and within a chunk assume completes before its bind
+        dispatches — the per-pod assume-before-bind ordering of the
+        one-shot path.  Either way every popped pod is assumed or failed
+        by return, and a commit failure reaches ``drain()``'s crash
+        handler."""
         daemon = self.daemon
         start = time.perf_counter()
         window = max(daemon.pipeline_window, 0)
         chunk = chunk_size or daemon.stream_chunk_size()
-        if window == 0:
+        inline = window == 0 or len(pods) <= chunk
+        metrics_mod.COMMIT_LAUNCHES.labels(
+            path="inline" if inline else "worker").inc()
+        if inline:
             solve_done = start
             for chunk_pods, placements in \
                     daemon.config.algorithm.schedule_batch_stream(
                         pods, chunk_size=chunk):
                 solve_done = time.perf_counter()
-                daemon._record_batch_decisions(chunk_pods, placements,
-                                               trace_id,
-                                               solve_done - start)
-                daemon._assume_and_bind_batch(chunk_pods, placements,
-                                              start)
+                self._commit(chunk_pods, placements, start, solve_done,
+                             trace_id)
         else:
-            if self._commit_pool is None:
-                from concurrent.futures import ThreadPoolExecutor
-                self._commit_pool = ThreadPoolExecutor(
-                    max_workers=1, thread_name_prefix="chunk-commit")
-            sem = threading.BoundedSemaphore(window)
-            ctx = trace_mod.current_context()
-            # Stamps the commit worker writes: when each chunk's readback
-            # landed (the last bounds algorithm latency) and when its
-            # commit ended (the next chunk's hand-off and the drain
-            # thread's wake are timed from it).
-            stamps = [start, start]
-            futures = []
-            err = None
-            try:
-                for _, resolve in \
-                        daemon.config.algorithm.schedule_batch_stream(
-                            pods, chunk_size=chunk, defer_readback=True):
-                    # Bounded in-flight window: block the drain thread
-                    # (and with it further device launches) until an
-                    # outstanding chunk commits — time the worker's
-                    # commit fills, so a ``commit.`` stage.  The worker
-                    # times the hand-off from here to its pick-up.
-                    t_wait = time.perf_counter()
-                    with trace_mod.annotation("commit.window_wait"):
-                        sem.acquire()
-                    t_handoff = time.perf_counter()
-                    trace_mod.record_stage("commit.window_wait",
-                                           start=t_wait, end=t_handoff)
-                    with trace_mod.annotation("handoff"):
-                        futures.append(self._commit_pool.submit(
-                            self._commit_chunk, resolve, start, trace_id,
-                            sem, ctx, stamps, t_handoff))
-            finally:
-                # Join EVERY submitted commit before surfacing anything:
-                # drain()'s crash handler requeues pods not yet assumed,
-                # and a still-running commit assuming them concurrently
-                # would double-track the pod.
-                with trace_mod.annotation("commit.join"):
-                    for fut in futures:
-                        try:
-                            fut.result()
-                        except Exception as exc:  # noqa: BLE001 — requeue
-                            err = err or exc
-            if futures and err is None:
-                # From the last commit's end to this thread's return.
-                trace_mod.record_stage("wake", start=stamps[1])
-            if err is not None:
-                # Surface the first commit failure to drain()'s crash
-                # handler, which requeues every pod the completed
-                # commits didn't assume.
-                raise err
-            solve_done = stamps[0]
+            solve_done = self._commit_on_worker(pods, chunk, window,
+                                                start, trace_id)
         # Algorithm latency spans until the LAST chunk's results landed
         # (interleaved assume/bind of earlier chunks overlaps the device
         # and is deliberately excluded, matching the one-shot path).
@@ -548,11 +504,76 @@ class DrainPipeline:
             algo_us, len(pods))
         return len(pods)
 
+    def _commit(self, chunk_pods: list, placements: list, start: float,
+                landed: float, trace_id: str) -> None:
+        """One chunk's commit: flight-recorder feed, bulk assume, bind
+        dispatch."""
+        self.daemon._record_batch_decisions(chunk_pods, placements,
+                                            trace_id, landed - start)
+        self.daemon._assume_and_bind_batch(chunk_pods, placements, start)
+
+    def _commit_on_worker(self, pods: list, chunk: int, window: int,
+                          start: float, trace_id: str) -> float:
+        """The overlapped drain of several chunks: each chunk's readback
+        and commit runs on the commit worker while this thread launches
+        the next.  Returns when the last chunk's results landed."""
+        if self._commit_pool is None:
+            from concurrent.futures import ThreadPoolExecutor
+            self._commit_pool = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="chunk-commit")
+        sem = threading.BoundedSemaphore(window)
+        ctx = trace_mod.current_context()
+        # Stamps the commit worker writes: when each chunk's readback
+        # landed (the last bounds algorithm latency) and when its commit
+        # ended (the next chunk's hand-off and the drain thread's wake
+        # are timed from it).
+        stamps = [start, start]
+        futures = []
+        err = None
+        try:
+            for _, resolve in \
+                    self.daemon.config.algorithm.schedule_batch_stream(
+                        pods, chunk_size=chunk, defer_readback=True):
+                # Bounded in-flight window: block the drain thread (and
+                # with it further device launches) until an outstanding
+                # chunk commits — time the worker's commit fills, so a
+                # ``commit.`` stage.  The worker times the hand-off from
+                # here to its pick-up.
+                t_wait = time.perf_counter()
+                with trace_mod.annotation("commit.window_wait"):
+                    sem.acquire()
+                t_handoff = time.perf_counter()
+                trace_mod.record_stage("commit.window_wait",
+                                       start=t_wait, end=t_handoff)
+                with trace_mod.annotation("handoff"):
+                    futures.append(self._commit_pool.submit(
+                        self._commit_chunk, resolve, start, trace_id,
+                        sem, ctx, stamps, t_handoff))
+        finally:
+            # Join EVERY submitted commit before surfacing anything:
+            # drain()'s crash handler requeues pods not yet assumed, and
+            # a still-running commit assuming them concurrently would
+            # double-track the pod.
+            with trace_mod.annotation("commit.join"):
+                for fut in futures:
+                    try:
+                        fut.result()
+                    except Exception as exc:  # noqa: BLE001 — requeue
+                        err = err or exc
+        if err is not None:
+            # Surface the first commit failure to drain()'s crash
+            # handler, which requeues every pod the completed commits
+            # didn't assume.
+            raise err
+        if futures:
+            # From the last commit's end to this thread's return.
+            trace_mod.record_stage("wake", start=stamps[1])
+        return stamps[0]
+
     def _commit_chunk(self, resolve, start: float, trace_id: str, sem,
                       trace_ctx, stamps: list, t_handoff: float) -> None:
-        """One chunk's commit on the pipeline worker: blocking readback,
-        flight-recorder feed, bulk assume, bind dispatch."""
-        daemon = self.daemon
+        """One chunk's commit on the pipeline worker: the blocking
+        readback, then the commit."""
         try:
             with trace_mod.use_context(trace_ctx):
                 # From the hand-off, or from this worker's end of the
@@ -562,10 +583,8 @@ class DrainPipeline:
                                        start=max(t_handoff, stamps[1]))
                 chunk_pods, placements = resolve()
                 stamps[0] = time.perf_counter()
-                daemon._record_batch_decisions(
-                    chunk_pods, placements, trace_id, stamps[0] - start)
-                daemon._assume_and_bind_batch(chunk_pods, placements,
-                                              start)
+                self._commit(chunk_pods, placements, start, stamps[0],
+                             trace_id)
         finally:
             sem.release()
             stamps[1] = time.perf_counter()

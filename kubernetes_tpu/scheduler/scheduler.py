@@ -23,6 +23,7 @@ import heapq
 import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor, wait
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -201,7 +202,14 @@ class Scheduler:
         # engine, byte-for-byte the pre-tenancy behavior.
         self.tenancy_service = None
         self._stop = threading.Event()
-        self._bind_threads: list[threading.Thread] = []
+        # The standing binder: every launch's binds (and the single-pod
+        # path's) run on these long-lived workers.  A thread started per
+        # launch cost the drain thread a Thread.start() each launch and
+        # the bind a fresh keep-alive connection.
+        self._bind_pool = ThreadPoolExecutor(
+            max_workers=self.BIND_WORKERS, thread_name_prefix="bind-batch")
+        self._binds: set = set()
+        self._binds_lock = threading.Lock()
         # Single requeue worker over a timer heap (a thread per failed pod
         # would explode on a large unschedulable batch).
         self._requeue_heap: list[tuple[float, int, api.Pod]] = []
@@ -363,6 +371,12 @@ class Scheduler:
     # unwarmed shapes mid-run.
     STREAM_MIN_BUCKET = 256
 
+    # Workers of the standing binder.  A launch's binds (~6 ms on the
+    # served path) rarely overlap the next launch's (~16 ms apart); the
+    # spare workers keep a stalled bind (a collector pause, a slow
+    # apiserver) from queueing the binds of the launches behind it.
+    BIND_WORKERS = 4
+
     def schedule_pending(self, wait_first: bool = True,
                          timeout: Optional[float] = None) -> int:
         """Drain the queue through the pipeline (form -> solve ->
@@ -485,18 +499,31 @@ class Scheduler:
                                          result=result)
         if self.config.async_bind:
             with stage("bind_spawn"):
-                t = threadreg.spawn(self._bind_assumed_batch,
-                                    args=(placed, start,
-                                          trace_mod.current_context()),
-                                    name="bind-batch", transient=True)
-                # Prune finished binders on append: a long-running
-                # daemon drains every ~50 ms and must not accumulate
-                # dead Thread objects without bound.
-                self._bind_threads = [x for x in self._bind_threads
-                                      if x.is_alive()]
-                self._bind_threads.append(t)
+                self._submit_bind(self._bind_assumed_batch, placed, start,
+                                  trace_mod.current_context())
         else:
             self._bind_assumed_batch(placed, start)
+
+    def _submit_bind(self, fn: Callable, *args) -> None:
+        """Hand a bind to the standing binder."""
+        try:
+            fut = self._bind_pool.submit(fn, *args)
+        except RuntimeError:
+            # Shut down: stop() and abandon() do not wait for the run
+            # loop, so a drain can outlive the binder; it binds here, as
+            # a thread of its own would have.
+            fn(*args)
+            return
+        with self._binds_lock:
+            self._binds.add(fut)
+        fut.add_done_callback(self._bind_done)
+
+    def _bind_done(self, fut) -> None:
+        with self._binds_lock:
+            self._binds.discard(fut)
+        if not fut.cancelled() and fut.exception() is not None:
+            # A bind error requeues inside the bind; this is a crash.
+            log.error("bind worker crashed", exc_info=fut.exception())
 
     def _preempt_failures(self, pods: list, placements: list,
                           failure_info: dict,
@@ -823,8 +850,7 @@ class Scheduler:
         self._stop.set()
         self.queue.close()
         self.pipeline.shutdown(wait=True)
-        for t in self._bind_threads:
-            t.join(timeout=5)
+        self._bind_pool.shutdown(wait=True)
         # Graceful shutdown persists the decision ring (KT_FLIGHT_DIR) so
         # `kubectl explain pod` keeps answering across a scheduler bounce.
         recorder = self.config.flight_recorder
@@ -848,11 +874,13 @@ class Scheduler:
         self._stop.set()
         self.queue.close()
         self.pipeline.shutdown(cancel=True)
+        self._bind_pool.shutdown(wait=False, cancel_futures=True)
 
     def wait_for_binds(self) -> None:
-        for t in list(self._bind_threads):
-            t.join()
-        self._bind_threads = [t for t in self._bind_threads if t.is_alive()]
+        """Return once every bind handed to the binder so far landed."""
+        with self._binds_lock:
+            pending = list(self._binds)
+        wait(pending)
 
     # -- internals --------------------------------------------------------
 
@@ -894,10 +922,7 @@ class Scheduler:
                 self._bind_assumed(pod, dest, start, assumed=assumed)
 
         if self.config.async_bind:
-            t = threadreg.spawn(bind, name="bind-one", transient=True)
-            self._bind_threads = [x for x in self._bind_threads
-                                  if x.is_alive()]
-            self._bind_threads.append(t)
+            self._submit_bind(bind)
         else:
             bind()
 

@@ -635,6 +635,14 @@ SCAN_STEPS = register(Counter(
     "bound the device reads from the live mask; no sync).  run / bucket "
     "is the share of a bucket's steps a launch pays for",
     labelnames=("kind",)))
+COMMIT_LAUNCHES = register(Counter(
+    "scheduler_commit_launches_total",
+    "Streamed drains by the thread that commits them (scheduler/"
+    "pipeline.py _solve_stream), one increment a drain: path=inline on "
+    "the drain thread (a drain of one chunk, or KT_PIPELINE_WINDOW=0), "
+    "path=worker on the overlapped commit worker (two or more chunks), "
+    "the only launches with handoff / wake / commit.window_wait stages",
+    labelnames=("path",)))
 DEVICE_HBM_LIVE_BYTES = register(Gauge(
     "scheduler_device_hbm_live_bytes",
     "Device memory held by live arrays (device.memory_stats when the "

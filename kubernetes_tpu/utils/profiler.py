@@ -108,6 +108,8 @@ _FN_RULES: tuple[tuple[str, dict[str, str]], ...] = (
     # The drain pipeline hosts BOTH halves of a batch: the solve pump
     # (dispatch + readback waits) and the post-solve commit chunk.
     ("scheduler/pipeline.py", {"_commit_chunk": "commit_bind",
+                               "_commit": "commit_bind",
+                               "_commit_on_worker": "solve_host",
                                "_solve": "solve_host",
                                "_solve_oneshot": "solve_host",
                                "_solve_stream": "solve_host",

@@ -3,7 +3,7 @@ through one chokepoint, and auditable.
 
 Twelve PRs accumulated 5+ factory-started background threads (commit
 worker, SLO tick, verifier, telemetry sampler, shard tick, reflector
-pumps) plus per-batch transients (async binds).  A raw
+pumps) plus transients (watch stream pumps).  A raw
 ``threading.Thread(...)`` in daemon code is invisible to any stop/join
 audit — ktlint's C03 rule flags them; daemon modules start threads
 through :func:`spawn` instead, which registers long-lived threads here
@@ -11,10 +11,10 @@ so :func:`audit` can answer "what is still running and who started it"
 (tests pin that a stopped ConfigFactory leaves no registered live
 threads behind).
 
-``transient=True`` marks bounded-lifetime workers (per-batch bind
-fan-out): they get the name + daemon-flag discipline but skip the
-registry — thousands of entries per drain would be churn, and their
-joins are owned by the spawning batch.
+``transient=True`` marks bounded-lifetime workers (a watch stream's
+pump): they get the name + daemon-flag discipline but skip the
+registry — an entry per stream would be churn, and their joins are
+owned by whoever spawned them.
 """
 
 from __future__ import annotations

@@ -587,14 +587,14 @@ class TestDeferredReadbackFaults:
                     pods, chunk_size=chunk_size, defer_readback=True):
                 chunk_no[0] += 1
                 if chunk_no[0] == 2:
-                    def bad_resolve(_resolve=resolve):
+                    def resolve(_resolve=resolve):
                         raise DeviceFault(
                             "oom", "stream",
                             RuntimeError("RESOURCE_EXHAUSTED: injected "
                                          "at readback"))
-                    yield chunk_pods, bad_resolve
-                else:
-                    yield chunk_pods, resolve
+                # a drain of one chunk reads back inline
+                yield (chunk_pods, resolve) if defer_readback \
+                    else resolve()
 
         algo.schedule_batch_stream = faulting_stream
 

@@ -28,9 +28,9 @@ from kubernetes_tpu.utils import metrics, threadreg, trace
 LEAVES = ("queue_wait", "lock_wait", "snapshot", "compile",
           "transfer.batch", "transfer.rows", "transfer.scatter",
           "transfer.full", "solve", "readback", "gate", "assume")
-# The stages on a launch's critical path: the drain thread up to the
-# hand-off, the commit worker from its pick-up to its end, the drain
-# thread's wake; launch.unnamed_ms_mean subtracts them
+# The stages on a launch's critical path: the drain thread's, and on a
+# drain of two or more chunks the commit worker's from its pick-up to its
+# end and the drain thread's wake; launch.unnamed_ms_mean subtracts them
 # (benchmarks/metrics/launch.unnamed_ms_mean.json).
 SUMMED = ("queue_wait", "lock_wait", "snapshot", "compile", "transfer",
           "pad", "scan_inputs", "solve", "handoff", "readback", "gate",
@@ -39,6 +39,9 @@ SUMMED = ("queue_wait", "lock_wait", "snapshot", "compile", "transfer",
 # Backdated: a wait measured across a gap, which counts no CPU.
 WAITS = ("queue_wait", "lock_wait", "assume.lock_wait", "handoff", "wake",
          "commit.window_wait", "launch_total")
+# The stages only a commit on the worker has: a drain of one chunk
+# commits on the drain thread and crosses no thread.
+WORKER_STAGES = ("handoff", "wake", "commit.window_wait")
 
 
 def _stage_sums() -> dict:
@@ -196,21 +199,41 @@ def _account_closes(grew: dict, cpu: dict, spans: list) -> None:
         assert name not in cpu, name
 
 
-def test_new_stages_are_host_events_of_the_launch(traced_launch):
+def test_new_stages_are_host_events_of_the_launch(traced_launch,
+                                                  traced_chunks):
+    """The one-chunk launches commit on the drain thread: their commit's
+    stages are host events of the launch, and nothing is handed over or
+    joined.  The hand-off and the join are a launch of two chunks'."""
     events, _grew, _cpu, _spans = traced_launch
-    for name in ("kt.pad", "kt.scan_inputs", "kt.handoff", "kt.decisions",
-                 "kt.bind_spawn", "kt.commit.counter", "kt.commit.join"):
+    for name in ("kt.pad", "kt.scan_inputs", "kt.decisions",
+                 "kt.bind_spawn", "kt.commit.counter"):
         assert _inside_a_launch(events, name), f"{name} is in no kt.launch"
+    for name in ("kt.handoff", "kt.commit.join", "kt.commit.window_wait"):
+        assert not any(n == name for n, _s, _e in events), name
+    chunk_events = traced_chunks[3]
+    for name in ("kt.handoff", "kt.commit.join", "kt.commit.window_wait"):
+        assert _inside_a_launch(chunk_events, name), \
+            f"{name} is in no kt.launch"
 
 
-def test_summed_stages_close_the_launch_account(traced_launch):
+def test_summed_stages_close_the_launch_account(traced_launch,
+                                                traced_chunks):
     _events, grew, cpu, spans = traced_launch
-    for name in ("pad", "scan_inputs", "handoff", "decisions",
-                 "bind_spawn", "wake", "commit.counter"):
+    for name in ("pad", "scan_inputs", "decisions", "bind_spawn",
+                 "commit.counter"):
         assert grew.get(name, 0.0) > 0.0, f"stage {name} was never observed"
+    for name in WORKER_STAGES:
+        assert grew.get(name, 0.0) == 0.0, \
+            f"a one-chunk launch recorded {name}"
     _account_closes(grew, cpu, spans)
-    # the worker's stages read the CPU clock; the hand-offs read none
+    # the commit's stages read the CPU clock
     assert cpu["decisions"] > 0.0 and cpu["bind_spawn"] > 0.0
+    # the thread hops are a launch of two chunks': walls, and no CPU
+    chunk_grew, chunk_cpu = traced_chunks[0], traced_chunks[1]
+    for name in ("handoff", "wake"):
+        assert chunk_grew.get(name, 0.0) > 0.0, \
+            f"stage {name} was never observed"
+        assert name not in chunk_cpu, name
 
 
 @pytest.mark.parametrize("enabled", [True, False],
@@ -237,13 +260,17 @@ def test_stage_cpu_is_read_at_the_wall_clock_reads(monkeypatch, enabled):
 
 
 @pytest.fixture(scope="module")
-def traced_chunks():
+def traced_chunks(tmp_path_factory):
     """Launches of the real daemon loop streamed in chunks of 4 (the
     in-flight window of 2 lets the drain thread hand chunk 2 over while
-    the worker still commits chunk 1), until one launch ran two chunks:
-    its spans, and what the launches added to each stage."""
+    the worker still commits chunk 1), until one launch ran two chunks,
+    inside a profiler session: what the launches added to each stage and
+    to each path of the commit, their spans, and the session's ``kt.*``
+    events."""
+    import jax
     from kubernetes_tpu.apiserver.memstore import MemStore
     from kubernetes_tpu.scheduler.factory import ConfigFactory
+    profile_dir = str(tmp_path_factory.mktemp("profile-chunks"))
     store = MemStore()
     for i in range(8):
         store.create("nodes", node_to_json(make_node(f"cn{i}")))
@@ -260,21 +287,34 @@ def traced_chunks():
         assert _wait_bound(store, warm)
         daemon.wait_for_binds()
         before, cpu_before = _stage_sums(), _stage_cpu()
+        paths_before = _commit_paths()
         trace.reset()
-        for wave in range(20):
-            names = [f"chunk{wave}-{i}" for i in range(8)]
-            for name in names:
-                store.create("pods", pod_to_json(make_pod(name, cpu="10m")))
-            assert _wait_bound(store, names)
-            daemon.wait_for_binds()
-            spans = trace.snapshot()
-            if any(_chunks(parts) >= 2 for _whole, parts in
-                   _launches(spans)):
-                break
+        jax.profiler.start_trace(profile_dir)
+        try:
+            for wave in range(20):
+                names = [f"chunk{wave}-{i}" for i in range(8)]
+                for name in names:
+                    store.create("pods",
+                                 pod_to_json(make_pod(name, cpu="10m")))
+                assert _wait_bound(store, names)
+                daemon.wait_for_binds()
+                spans = trace.snapshot()
+                if any(_chunks(parts) >= 2 for _whole, parts in
+                       _launches(spans)):
+                    break
+        finally:
+            jax.profiler.stop_trace()
         after, cpu_after = _stage_sums(), _stage_cpu()
+        paths = _grew(_commit_paths(), paths_before)
     finally:
         factory.stop()
-    return _grew(after, before), _grew(cpu_after, cpu_before), spans
+    return (_grew(after, before), _grew(cpu_after, cpu_before), spans,
+            _kt_events(profile_dir), paths)
+
+
+def _commit_paths() -> dict:
+    return {key[0]: child.value
+            for key, child in metrics.COMMIT_LAUNCHES.children().items()}
 
 
 def _chunks(parts: list) -> int:
@@ -288,10 +328,12 @@ def test_a_chunk_hand_off_waits_for_the_worker_not_its_last_commit(
     later), never before it, so no commit is counted twice; each thread's
     summed stages stay inside the launch; the wait for the in-flight
     window is ``commit.window_wait``, summed nowhere, counting no CPU."""
-    grew, cpu, spans = traced_chunks
+    grew, cpu, spans, _events, paths = traced_chunks
     two = [(whole, parts) for whole, parts in _launches(spans)
            if _chunks(parts) >= 2]
     assert two, "no launch ran two chunks"
+    # a launch of two chunks commits on the worker
+    assert paths.get("worker", 0) >= 1
     for whole, parts in two:
         handoffs = [sp for sp in parts if sp["name"] == "handoff"]
         worker = handoffs[0]["thread"]
